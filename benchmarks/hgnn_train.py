@@ -1,7 +1,7 @@
 """HGNN training benchmark — the mesh-scale launcher end to end.
 
 Runs a short HAN and R-GAT trajectory through ``launch.hgnn_train``'s
-``run_training`` (interpret kernel backend so it executes anywhere) and
+``run_training`` (the fused kernel in interpret mode, on the CPU) and
 reports the measured step time plus the loss trajectory — the regression
 baseline for the training path (BENCH_hgnn_train.json).  Also emits the
 lane-vs-model mesh-split autotune sweep (``lanes.sweep_mesh_split``) so
@@ -23,7 +23,7 @@ def run(report):
             model_name=model_name,
             steps=_STEPS,
             lanes=1,
-            backend="kernel",  # resolves to the interpreter on CPU hosts
+            backend="kernel_interpret",
             hidden=8,
             heads=2,
             scale=0.06,

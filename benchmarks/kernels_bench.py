@@ -40,7 +40,7 @@ def run(report):
         )
     # Pallas kernel body, interpret mode (correctness-path timing only)
     fn = jax.jit(
-        lambda a, b, c: neighbor_aggregate(batch, a, b, c, backend=NABackend.KERNEL_INTERPRET)
+        lambda a, b, c: neighbor_aggregate(batch, a, b, c, backend=NABackend.MULTIGRAPH_INTERPRET)
     )
     t = timeit(fn, ths, thd, hs, warmup=1, iters=1)
     report("kernel/na/pallas_interpret", t, "interpret-mode (not a TPU projection)")

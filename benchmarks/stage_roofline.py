@@ -18,7 +18,6 @@ import numpy as np
 
 from repro.core import NABackend, batch_semantic_graph, stages
 from repro.core.fusion import FusedFPInputs, neighbor_aggregate_multi
-from repro.launch.hlostats import normalize_cost_analysis
 from repro.graphs import build_semantic_graph, synthetic_hetgraph, to_padded_edges
 
 from .common import timeit
@@ -32,7 +31,7 @@ def _ai(fn, *args):
     backend omits "bytes accessed" — a fabricated default would silently
     misclassify the bound."""
     c = jax.jit(fn).lower(*args).compile()
-    cost = normalize_cost_analysis(c.cost_analysis())
+    cost = c.cost_analysis()
     fl = float(cost.get("flops", 0.0))
     by = cost.get("bytes accessed")
     if by is None:

@@ -8,9 +8,11 @@ the model is widened (hidden 128 × 8 heads, att_dim 256, full-scale ACM
 features) to ~100M parameters, trained full-batch (transductive node
 classification, as HAN trains) through the consolidated multilane NA path
 with the fault-tolerant train_loop — atomic checkpoints, counter-based
-data state, elastic lane restarts.  Add ``--lanes 2`` (or set
-``XLA_FLAGS=--xla_force_host_platform_device_count=4``) to shard the NA
-work units over a lane mesh; the loss trajectory does not change.
+data state, elastic lane restarts.  Add ``--lanes 2`` to shard the NA
+work units over a lane mesh; the loss trajectory does not change.  The
+default ``--backend kernel`` needs a TPU; on a CPU host pass
+``--backend kernel_interpret`` (and, for lanes, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 """
 import argparse
 

@@ -8,10 +8,10 @@ from .fusion import (
     SemanticGraphBatch,
     batch_semantic_graph,
     build_unit_tables,
-    cpu_fallback,
     mean_aggregate,
     neighbor_aggregate,
     neighbor_aggregate_multi,
+    require_tpu,
 )
 from .reuse import FPTraffic, ReuseCounters, count_reuse, fp_buffer_traffic
 from .scheduling import (
@@ -31,10 +31,10 @@ __all__ = [
     "SemanticGraphBatch",
     "batch_semantic_graph",
     "build_unit_tables",
-    "cpu_fallback",
     "mean_aggregate",
     "neighbor_aggregate",
     "neighbor_aggregate_multi",
+    "require_tpu",
     "FPTraffic",
     "ReuseCounters",
     "count_reuse",

@@ -1,6 +1,6 @@
 """Bound-aware stage fusion — execution paths for the NA stage (paper §4.1).
 
-Three interchangeable NA backends with identical semantics:
+Interchangeable NA backends with identical semantics:
 
 * ``SEGMENT``  — two-pass segment softmax over a padded edge list.  This is
   the *staged baseline*: it mirrors the GPU framework's SpMM-style pass
@@ -8,9 +8,16 @@ Three interchangeable NA backends with identical semantics:
   sum, weighted SpMM).
 * ``BLOCK``    — pure-jnp block-CSR online softmax (numerator/denominator
   accumulated simultaneously — the paper's softmax decomposition, Fig. 6).
-* ``KERNEL``   — the Pallas TPU kernel (kernels/seg_gat_agg): the fused
-  FP->theta->NA->LSF hardware datapath expressed as VMEM-tiled MXU work.
-  ``KERNEL_INTERPRET`` runs the same kernel body in interpret mode (CPU).
+* ``MULTIGRAPH`` — the Pallas TPU kernel
+  (kernels/seg_gat_agg_multigraph): the fused online-softmax NA datapath
+  expressed as VMEM-tiled MXU work, all semantic graphs of a layer in one
+  launch (one graph is the G=1 case).
+* ``FUSED_FP`` — the same launch with the FP stage pulled inside.
+
+A compiled Pallas backend runs only on a TPU; its ``*_INTERPRET`` twin
+runs the same kernel body under the Pallas interpreter (CPU validation).
+Nothing swaps one for the other: :func:`require_tpu` refuses a compiled
+backend on a host without a TPU.
 
 Stage fusion proper — running FP, theta, NA, LSF inside *one* compiled
 program instead of one program per stage — is expressed at the model level
@@ -36,8 +43,6 @@ from . import stages
 class NABackend(enum.Enum):
     SEGMENT = "segment"
     BLOCK = "block"
-    KERNEL = "kernel"
-    KERNEL_INTERPRET = "kernel_interpret"
     # fused multigraph kernel (kernels/seg_gat_agg_multigraph): ALL semantic
     # graphs of a layer in one Pallas launch — the paper's multi-lane
     # datapath.  Differentiable (custom VJP with a fused backward launch).
@@ -62,26 +67,29 @@ _FUSED_TO_MULTIGRAPH = {
     NABackend.FUSED_FP_INTERPRET: NABackend.MULTIGRAPH_INTERPRET,
 }
 
-# Compiled Pallas backends need a TPU; each maps to the interpreter variant
-# of the SAME kernel body (same numbers) for CPU-only hosts.
-_CPU_FALLBACK = {
-    NABackend.KERNEL: NABackend.KERNEL_INTERPRET,
-    NABackend.MULTIGRAPH: NABackend.MULTIGRAPH_INTERPRET,
-    NABackend.FUSED_FP: NABackend.FUSED_FP_INTERPRET,
+# compiled Pallas backend name (NABackend value or multilane backend
+# string) -> the interpret-mode name that runs the same kernel body
+_INTERPRET_TWIN = {
+    "multigraph": "multigraph_interpret",
+    "fused_fp": "fused_fp_interpret",
+    "kernel": "kernel_interpret",
 }
 
 
-def cpu_fallback(backend: NABackend) -> NABackend:
-    """Degrade a compiled Pallas backend to its interpret twin on CPU hosts.
+def require_tpu(backend: str) -> None:
+    """Refuse a compiled Pallas backend on a host whose JAX has no TPU.
 
-    The launchers (serve, train) and tests all need the same policy: ask
-    for the TPU kernel, validate the identical kernel body under the
-    interpreter when no TPU is attached.  No-op for non-kernel backends
-    and on TPU hosts.
+    Interpret mode runs only when the caller names it; a compiled backend
+    never degrades to it, so a run that reports a kernel backend ran the
+    kernel on the chip.
     """
-    if backend in _CPU_FALLBACK and jax.default_backend() == "cpu":
-        return _CPU_FALLBACK[backend]
-    return backend
+    twin = _INTERPRET_TWIN.get(backend)
+    if twin is not None and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"NA backend {backend!r} compiles a Pallas TPU kernel, but JAX "
+            f"found no TPU (default backend: {jax.default_backend()!r}); "
+            f"ask for {twin!r} to run the same kernel in interpret mode"
+        )
 
 
 @dataclasses.dataclass
@@ -99,7 +107,7 @@ class SemanticGraphBatch:
     src: jnp.ndarray | None = None
     dst: jnp.ndarray | None = None
     valid: jnp.ndarray | None = None
-    # block CSR (BLOCK / KERNEL backends)
+    # block CSR (BLOCK / MULTIGRAPH / FUSED_FP backends)
     col_index: jnp.ndarray | None = None
     masks: jnp.ndarray | None = None
     block: int = 128
@@ -240,25 +248,14 @@ def neighbor_aggregate(
             batch.num_dst, leaky_slope=leaky_slope, edge_bias=edge_bias,
         )
 
+    assert backend is NABackend.BLOCK, f"{backend} needs neighbor_aggregate_multi"
     assert batch.col_index is not None, "batch built without block CSR"
     ns_pad = ((batch.num_src + batch.block - 1) // batch.block) * batch.block
-    th_s = _pad_rows(theta_src, ns_pad)
-    hs = _pad_rows(h_src, ns_pad)
-    th_d = _pad_rows(theta_dst, batch.num_dst_pad)
-
-    if backend is NABackend.BLOCK:
-        out = stages.block_softmax_aggregate(
-            batch.col_index, batch.masks, th_s, th_d, hs,
-            leaky_slope=leaky_slope, edge_bias=edge_bias,
-        )
-    else:
-        from ..kernels import ops as kops
-
-        out = kops.seg_gat_agg(
-            batch.col_index, batch.masks, th_s, th_d, hs,
-            leaky_slope=leaky_slope, edge_bias=edge_bias,
-            interpret=backend is NABackend.KERNEL_INTERPRET,
-        )
+    out = stages.block_softmax_aggregate(
+        batch.col_index, batch.masks, _pad_rows(theta_src, ns_pad),
+        _pad_rows(theta_dst, batch.num_dst_pad), _pad_rows(h_src, ns_pad),
+        leaky_slope=leaky_slope, edge_bias=edge_bias,
+    )
     return out[: batch.num_dst]
 
 
@@ -330,6 +327,7 @@ def neighbor_aggregate_multi(
     eager callers (the serving engine, obs.characterize) get real timing
     via the sync boundary.
     """
+    require_tpu(backend.value)
     if backend in _FUSED_FP_BACKENDS:
         if fp is None:
             raise ValueError(

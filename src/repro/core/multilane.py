@@ -21,11 +21,10 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..obs.trace import trace_span
-from .fusion import FusedFPInputs, SemanticGraphBatch
+from .fusion import FusedFPInputs, SemanticGraphBatch, require_tpu
 from .scheduling import LanePlan, lane_assignment, naive_lane_assignment
 
 NEG_INF = -1e30
@@ -184,18 +183,6 @@ def _unit_na(
 
 MULTILANE_BACKENDS = ("reference", "kernel", "kernel_interpret", "fused_fp", "fused_fp_interpret")
 
-# string-backend twin of fusion.cpu_fallback: compiled Pallas lowering
-# needs a TPU; the interpreter runs the identical kernel body on CPU.
-_CPU_BACKEND_FALLBACK = {"kernel": "kernel_interpret", "fused_fp": "fused_fp_interpret"}
-
-
-def resolve_multilane_backend(backend: str) -> str:
-    """Degrade a compiled multilane backend string to its interpret twin on
-    CPU-only hosts (same kernel, same numbers)."""
-    if backend in _CPU_BACKEND_FALLBACK and jax.default_backend() == "cpu":
-        return _CPU_BACKEND_FALLBACK[backend]
-    return backend
-
 
 def multilane_na(
     plan: MultiLanePlan,
@@ -218,7 +205,8 @@ def multilane_na(
         (kernels/seg_gat_agg_multigraph): the paper's mixed-graph lane
         datapath as a single TPU kernel;
       * ``"kernel_interpret"`` — same kernel under the Pallas interpreter
-        (CPU validation / CI);
+        (CPU validation / CI); ``"kernel"`` on a host without a TPU
+        raises instead (``fusion.require_tpu``);
       * ``"fused_fp"`` / ``"fused_fp_interpret"`` — the stage-fusion
         megakernel (kernels/seg_gat_agg_fused_fp): pass
         ``fp=FusedFPInputs`` (raw features padded to [N_pad, Din] +
@@ -228,6 +216,7 @@ def multilane_na(
     """
     if backend not in MULTILANE_BACKENDS:
         raise ValueError(f"backend={backend!r}, expected one of {MULTILANE_BACKENDS}")
+    require_tpu(backend)
     fused_fp = backend in ("fused_fp", "fused_fp_interpret")
     if fused_fp:
         if fp is None:
@@ -290,6 +279,34 @@ def multilane_na(
         return sp.sync(out.reshape(g_n, plan.n_dst_blocks * plan.block, h_dim, dh))
 
 
+def _plan_specs(plan: MultiLanePlan, lane_axes: tuple[str, ...]) -> MultiLanePlan:
+    """PartitionSpecs that split every plan array on its leading lane dim."""
+    lane_part = lane_axes[0] if len(lane_axes) == 1 else tuple(lane_axes)
+    lane_spec = lambda ndim: PartitionSpec(lane_part, *([None] * (ndim - 1)))
+    return MultiLanePlan(
+        col_index=lane_spec(3),
+        masks=lane_spec(5),
+        graph_id=lane_spec(2),
+        dst_row=lane_spec(2),
+        valid=lane_spec(2),
+        block=plan.block,
+        num_graphs=plan.num_graphs,
+        n_dst_blocks=plan.n_dst_blocks,
+        lane_plan=None,
+    )
+
+
+def place_plan(plan: MultiLanePlan, mesh, lane_axes: tuple[str, ...]) -> MultiLanePlan:
+    """Put each lane shard of the plan on the device of ``mesh`` that runs
+    it under ``multilane_na_sharded`` (host scheduling metadata kept)."""
+    specs = _plan_specs(plan, lane_axes)
+    placed = {
+        f: jax.device_put(getattr(plan, f), NamedSharding(mesh, getattr(specs, f)))
+        for f in ("col_index", "masks", "graph_id", "dst_row", "valid")
+    }
+    return dataclasses.replace(plan, **placed)
+
+
 def multilane_na_sharded(
     plan: MultiLanePlan,
     theta_src: jnp.ndarray | None,  # [G, Ns_pad, H]   (None with fused_fp)
@@ -330,19 +347,7 @@ def multilane_na_sharded(
     if edge_bias is None:
         edge_bias = jnp.zeros((g_n, h_dim), bias_dtype)
 
-    lane_part = lane_axes[0] if len(lane_axes) == 1 else tuple(lane_axes)
-    lane_spec = lambda ndim: PartitionSpec(lane_part, *([None] * (ndim - 1)))
-    plan_specs = MultiLanePlan(
-        col_index=lane_spec(3),
-        masks=lane_spec(5),
-        graph_id=lane_spec(2),
-        dst_row=lane_spec(2),
-        valid=lane_spec(2),
-        block=plan.block,
-        num_graphs=plan.num_graphs,
-        n_dst_blocks=plan.n_dst_blocks,
-        lane_plan=None,
-    )
+    plan_specs = _plan_specs(plan, lane_axes)
     rep = PartitionSpec()
 
     if fused_fp:
@@ -358,12 +363,12 @@ def multilane_na_sharded(
             )
             return jax.lax.psum(partial, lane_axes)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fp,
             mesh=mesh,
             in_specs=(plan_specs, fp_specs, rep),
             out_specs=rep,
-            check_rep=False,
+            check_vma=False,
         )
         with trace_span(
             "na/multilane_sharded", stage="NA", backend=backend,
@@ -380,12 +385,12 @@ def multilane_na_sharded(
         )
         return jax.lax.psum(partial, lane_axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(plan_specs, rep, rep, rep, rep),
         out_specs=rep,
-        check_rep=False,
+        check_vma=False,
     )
     with trace_span(
         "na/multilane_sharded", stage="NA", backend=backend,

@@ -102,7 +102,7 @@ def block_softmax_aggregate(
 ) -> jnp.ndarray:
     """Block-CSR *online-softmax* NA — the paper's softmax decomposition
     (numerator and denominator accumulated simultaneously, Fig. 6), in the
-    block-densified TPU layout.  Pure-jnp oracle for kernels/seg_gat_agg.
+    block-densified TPU layout.  Pure-jnp oracle for kernels/seg_gat_agg_multigraph.
 
     Returns [Nd_pad, H, Dh].
     """

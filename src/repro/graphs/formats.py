@@ -13,7 +13,7 @@ Two executable layouts for a SemanticGraph:
   through MSHR-backed SRAM buffers; on TPU the same irregular NA stage is
   *block-densified* so it runs as masked dense MXU/VPU work from VMEM tiles
   (see DESIGN.md §2).  The per-row block lists are what the fused
-  online-softmax kernel (kernels/seg_gat_agg.py) iterates over.
+  online-softmax kernel (kernels/seg_gat_agg_multigraph.py) iterates over.
 """
 from __future__ import annotations
 

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(
     x_ref,      # [BN, BK]
@@ -115,7 +113,7 @@ def fused_fp_coeff(
             jax.ShapeDtypeStruct((n, heads), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bn, hdh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

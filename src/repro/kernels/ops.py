@@ -9,12 +9,10 @@ from __future__ import annotations
 from .flash_attention import flash_attention
 from .fused_fp_coeff import fused_fp_coeff
 from .ref import ref_flash_attention, ref_fused_fp_coeff, ref_seg_gat_agg
-from .seg_gat_agg import seg_gat_agg
 
 __all__ = [
     "flash_attention",
     "fused_fp_coeff",
-    "seg_gat_agg",
     "ref_flash_attention",
     "ref_fused_fp_coeff",
     "ref_seg_gat_agg",

@@ -54,8 +54,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -71,7 +69,7 @@ def _fwd_kernel(
     xd_ref,     # [B, Din]      raw dst tile (row_ref-indexed)
     xs_ref,     # [B, Din]      raw src tile (col-indexed)
     w_ref,      # [1, Din, HDh] weight table of the unit's graph
-    b_ref,      # [1, HDh]
+    b_ref,      # [1, 1, HDh]
     asrc_ref,   # [1, H, Dh]
     adst_ref,   # [1, H, Dh]
     # outputs
@@ -92,7 +90,7 @@ def _fwd_kernel(
     nw = pl.num_programs(1)
 
     wmat = w_ref[0].astype(jnp.float32)  # [Din, HDh]
-    bvec = b_ref[0].astype(jnp.float32)  # [HDh]
+    bvec = b_ref[0].astype(jnp.float32)  # [1, HDh]
 
     @pl.when(w == 0)
     def _init():
@@ -264,7 +262,7 @@ def _common_maps():
         return (wsel[gid[u]], 0, 0)
 
     def b_map(u, w, col, gid, row, wsel, bias):
-        return (wsel[gid[u]], 0)
+        return (wsel[gid[u]], 0, 0)
 
     def a_map(u, w, col, gid, row, wsel, bias):
         return (gid[u], 0, 0)
@@ -279,7 +277,9 @@ def _in_specs(B, din, hdh, heads, head_dim):
         pl.BlockSpec((B, din), xd_map),
         pl.BlockSpec((B, din), xs_map),
         pl.BlockSpec((1, din, hdh), w_map),
-        pl.BlockSpec((1, hdh), b_map),
+        # [T, 1, HDh]: a (1, HDh) block of a [T, HDh] table is not a legal
+        # TPU tile for T > 1 (second-minor dim neither 8-aligned nor whole)
+        pl.BlockSpec((1, 1, hdh), b_map),
         pl.BlockSpec((1, heads, head_dim), a_map),
         pl.BlockSpec((1, heads, head_dim), a_map),
     ]
@@ -320,12 +320,13 @@ def _fwd_call(col_index, graph_id, dst_row, wsel, masks, x, w, b,
             jax.ShapeDtypeStruct((U * B, hdh), x.dtype),
             jax.ShapeDtypeStruct((U * B, heads), jnp.float32),
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="seg_gat_agg_fused_fp",
-    )(col_index, graph_id, dst_row, wsel, edge_bias, masks, x, x, w, b, a_src, a_dst)
+    )(col_index, graph_id, dst_row, wsel, edge_bias, masks, x, x, w,
+      b[:, None, :], a_src, a_dst)
 
 
 def _bwd_call(col_index, graph_id, dst_row, wsel, masks, x, w, b, a_src,
@@ -379,13 +380,13 @@ def _bwd_call(col_index, graph_id, dst_row, wsel, masks, x, w, b, a_src,
             jax.ShapeDtypeStruct((U, heads, head_dim), jnp.float32),
             jax.ShapeDtypeStruct((U, heads, head_dim), jnp.float32),
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="seg_gat_agg_fused_fp_bwd",
-    )(col_index, graph_id, dst_row, wsel, edge_bias, masks, x, x, w, b,
-      a_src, a_dst, g_out, lse, delta)
+    )(col_index, graph_id, dst_row, wsel, edge_bias, masks, x, x, w,
+      b[:, None, :], a_src, a_dst, g_out, lse, delta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(11, 12))
@@ -522,8 +523,7 @@ def fused_fp_na_reference(
     edge_bias=None, *, leaky_slope: float = 0.2,
 ) -> jnp.ndarray:
     """Pure-jnp oracle for the fused kernel (materialize-then-NA, exact
-    softmax).  Differentiable by plain autodiff — the gradcheck target —
-    and the CPU fallback path when Pallas is unavailable."""
+    softmax).  Differentiable by plain autodiff — the gradcheck target."""
     U, W = col_index.shape
     B = masks.shape[-1]
     G, heads, head_dim = a_src.shape

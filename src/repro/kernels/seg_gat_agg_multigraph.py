@@ -9,10 +9,11 @@ attention coefficients — the RAB-cached values) and the shared h_src
 stream in without any host-side regrouping: the hardware analogue of the
 Local Scheduler dispatching mixed-graph workloads onto one lane.
 
-Grid: (H, U, W) — U work units, W block slots per unit; scratch
-(m, l, acc) carries across W (online softmax, Fig. 6).  The forward
-additionally emits the per-row log-sum-exp (lse = m + log l), the only
-residual the backward needs beyond the inputs.
+Grid: (U, W) — U work units, W block slots per unit; all heads of a
+unit run inside one grid step.  Scratch (m, l, acc) carries across W
+(online softmax, Fig. 6).  The forward additionally emits the per-row
+log-sum-exp (lse = m + log l), the only residual the backward needs
+beyond the inputs.
 
 The backward is itself one fused multigraph launch (the
 kernel-consolidation result of arXiv 2408.08490 applied to training):
@@ -21,7 +22,7 @@ it *recomputes* the attention probabilities online from lse
 probability tensor is ever materialized) and produces
 
   * d_theta_dst  — accumulated across the W axis in VMEM scratch,
-    written once per (unit, head);
+    written once per unit;
   * per-(unit, slot) d_theta_src / d_h_src block partials — the GSF-like
     scatter-add onto the shared src vertex space happens outside the
     kernel with segment sums (Pallas TPU cannot safely revisit output
@@ -41,9 +42,24 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 NEG_INF = -1e30
+
+# TPU block layout.  Mosaic tiles the last two dims of every block by
+# (8, 128) unless a block dim spans its whole array dim, so no block may
+# carry a single head: every block keeps the head axis whole and the
+# kernel bodies loop over heads.  theta_src is fed head-major
+# ([G, H, Ns_pad]) so a src tile's coefficients arrive as lane-dense
+# [H, B] rows; h_src / outputs are fed flat as [N, H*Dh].
+
+
+def _logits(thd_ref, ths_ref, bias, hh, leaky_slope):
+    """Pre-activation and LeakyReLU logits [B(dst), B(src)] of head hh."""
+    pre = (
+        thd_ref[0, :, hh : hh + 1].astype(jnp.float32)    # [B, 1]
+        + ths_ref[0, hh : hh + 1, :].astype(jnp.float32)  # [1, B]
+        + bias
+    )
+    return pre, jnp.where(pre >= 0, pre, leaky_slope * pre)
 
 
 def _fwd_kernel(
@@ -54,21 +70,24 @@ def _fwd_kernel(
     bias_ref,   # f32   [G, H]
     # inputs
     mask_ref,   # bool [1, 1, B, B]
-    thd_ref,    # f32  [1, B, 1]   (graph-indexed dst coefficients)
-    ths_ref,    # f32  [1, B, 1]   (graph-indexed src coefficients)
-    hs_ref,     # f32  [B, 1, Dh]  (shared source features)
+    thd_ref,    # [1, B, H]  dst coefficients of the unit's graph
+    ths_ref,    # [1, H, B]  src coefficients of the slot's block
+    hs_ref,     # [B, H*Dh]  shared source features
     # outputs
-    out_ref,    # [B, 1, Dh]
-    lse_ref,    # f32 [B, 1]
+    out_ref,    # [B, H*Dh]
+    lse_ref,    # f32 [B, H]
     # scratch
-    acc_ref, m_ref, l_ref,
+    acc_ref,    # f32 [B, H*Dh]
+    m_ref,      # f32 [B, H]
+    l_ref,      # f32 [B, H]
     *,
+    heads: int,
+    head_dim: int,
     leaky_slope: float,
 ):
-    h = pl.program_id(0)
-    u = pl.program_id(1)
-    w = pl.program_id(2)
-    nw = pl.num_programs(2)
+    u = pl.program_id(0)
+    w = pl.program_id(1)
+    nw = pl.num_programs(1)
 
     @pl.when(w == 0)
     def _init():
@@ -76,33 +95,33 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    col = col_ref[u, w]
-    live = jnp.logical_and(mask_ref[0, 0], col >= 0)
-    thd = thd_ref[0, :, 0].astype(jnp.float32)
-    ths = ths_ref[0, :, 0].astype(jnp.float32)
-    logits = thd[:, None] + ths[None, :] + bias_ref[gid_ref[u], h]
-    logits = jnp.where(logits >= 0, logits, leaky_slope * logits)
-    logits = jnp.where(live, logits, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
-    scale = jnp.exp(m_prev - m_new)
-    p = jnp.where(live, jnp.exp(logits - m_new[:, None]), 0.0)
-    l_ref[...] = l_ref[...] * scale + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * scale[:, None] + jnp.dot(
-        p, hs_ref[:, 0, :].astype(jnp.float32), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    live = jnp.logical_and(mask_ref[0, 0], col_ref[u, w] >= 0)
+    for hh in range(heads):
+        sl = slice(hh * head_dim, (hh + 1) * head_dim)
+        _, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
+        logits = jnp.where(live, logits, NEG_INF)
+        m_prev = m_ref[:, hh : hh + 1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
+        scale = jnp.exp(m_prev - m_new)
+        p = jnp.where(live, jnp.exp(logits - m_new), 0.0)
+        l_ref[:, hh : hh + 1] = l_ref[:, hh : hh + 1] * scale + jnp.sum(
+            p, axis=1, keepdims=True
+        )
+        acc_ref[:, sl] = acc_ref[:, sl] * scale + jnp.dot(
+            p, hs_ref[:, sl].astype(jnp.float32), preferred_element_type=jnp.float32
+        )
+        m_ref[:, hh : hh + 1] = m_new
 
     @pl.when(w == nw - 1)
     def _finalize():
-        l_fin = l_ref[...]
-        out_ref[:, 0, :] = (
-            acc_ref[...] / jnp.maximum(l_fin, 1e-9)[:, None]
-        ).astype(out_ref.dtype)
+        for hh in range(heads):
+            sl = slice(hh * head_dim, (hh + 1) * head_dim)
+            out_ref[:, sl] = (
+                acc_ref[:, sl] / jnp.maximum(l_ref[:, hh : hh + 1], 1e-9)
+            ).astype(out_ref.dtype)
         # lse of a fully-masked row degenerates to ~NEG_INF; the backward
         # masks those positions with `live` before any use.
-        lse_ref[:, 0] = m_ref[...] + jnp.log(jnp.maximum(l_fin, 1e-30))
+        lse_ref[...] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
 def _bwd_kernel(
@@ -113,68 +132,82 @@ def _bwd_kernel(
     bias_ref,   # f32   [G, H]
     # inputs
     mask_ref,   # bool [1, 1, B, B]
-    thd_ref,    # [1, B, 1]
-    ths_ref,    # [1, B, 1]
-    hs_ref,     # [B, 1, Dh]
-    gout_ref,   # [B, 1, Dh]  cotangent of the per-unit output
-    lse_ref,    # f32 [B, 1]  forward log-sum-exp residual
-    delta_ref,  # f32 [B, 1]  sum_f g_out * out (flash-attention delta)
+    thd_ref,    # [1, B, H]
+    ths_ref,    # [1, H, B]
+    hs_ref,     # [B, H*Dh]
+    gout_ref,   # [B, H*Dh]  cotangent of the per-unit output
+    lse_ref,    # f32 [B, H]  forward log-sum-exp residual
+    delta_ref,  # f32 [B, H]  sum_f g_out * out (flash-attention delta)
     # outputs
-    dths_ref,   # f32 [1, 1, B, 1]      per-(unit, slot) src-coeff partial
-    dhs_ref,    # f32 [1, 1, B, 1, Dh]  per-(unit, slot) src-feature partial
-    dthd_ref,   # f32 [B, 1]            per-unit dst-coeff gradient
+    dths_ref,   # f32 [1, 1, H, B]     per-(unit, slot) src-coeff partial
+    dhs_ref,    # f32 [1, 1, B, H*Dh]  per-(unit, slot) src-feature partial
+    dthd_ref,   # f32 [B, H]           per-unit dst-coeff gradient
     # scratch
-    dthd_acc_ref,  # f32 [B]
+    dthd_acc_ref,  # f32 [B, H]
     *,
+    heads: int,
+    head_dim: int,
     leaky_slope: float,
 ):
-    h = pl.program_id(0)
-    u = pl.program_id(1)
-    w = pl.program_id(2)
-    nw = pl.num_programs(2)
+    u = pl.program_id(0)
+    w = pl.program_id(1)
+    nw = pl.num_programs(1)
 
     @pl.when(w == 0)
     def _init():
         dthd_acc_ref[...] = jnp.zeros_like(dthd_acc_ref)
 
-    col = col_ref[u, w]
-    live = jnp.logical_and(mask_ref[0, 0], col >= 0)  # [B(dst), B(src)]
-    thd = thd_ref[0, :, 0].astype(jnp.float32)
-    ths = ths_ref[0, :, 0].astype(jnp.float32)
-    pre = thd[:, None] + ths[None, :] + bias_ref[gid_ref[u], h]
-    logits = jnp.where(pre >= 0, pre, leaky_slope * pre)  # LeakyReLU
-    # recompute-p: attention probabilities from the lse residual
-    p = jnp.where(live, jnp.exp(logits - lse_ref[:, 0][:, None]), 0.0)
-
-    g_out = gout_ref[:, 0, :].astype(jnp.float32)  # [B, Dh]
-    hs = hs_ref[:, 0, :].astype(jnp.float32)       # [B, Dh]
-    dp = jnp.dot(g_out, hs.T, preferred_element_type=jnp.float32)  # [Bd, Bs]
-    dlogit = p * (dp - delta_ref[:, 0][:, None])   # softmax backward
-    dpre = jnp.where(pre >= 0, dlogit, leaky_slope * dlogit)
-
-    dths_ref[0, 0, :, 0] = jnp.sum(dpre, axis=0)
-    dhs_ref[0, 0, :, 0, :] = jnp.dot(p.T, g_out, preferred_element_type=jnp.float32)
-    dthd_acc_ref[...] += jnp.sum(dpre, axis=1)
+    live = jnp.logical_and(mask_ref[0, 0], col_ref[u, w] >= 0)  # [B(dst), B(src)]
+    for hh in range(heads):
+        sl = slice(hh * head_dim, (hh + 1) * head_dim)
+        pre, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
+        # recompute-p: attention probabilities from the lse residual
+        p = jnp.where(live, jnp.exp(logits - lse_ref[:, hh : hh + 1]), 0.0)
+        g_out = gout_ref[:, sl].astype(jnp.float32)  # [B, Dh]
+        hs = hs_ref[:, sl].astype(jnp.float32)       # [B, Dh]
+        dp = jax.lax.dot_general(                    # g_out @ hs.T  [Bd, Bs]
+            g_out, hs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dlogit = p * (dp - delta_ref[:, hh : hh + 1])  # softmax backward
+        dpre = jnp.where(pre >= 0, dlogit, leaky_slope * dlogit)
+        dths_ref[0, 0, hh : hh + 1, :] = jnp.sum(dpre, axis=0, keepdims=True)
+        dhs_ref[0, 0, :, sl] = jax.lax.dot_general(  # p.T @ g_out  [Bs, Dh]
+            p, g_out, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dthd_acc_ref[:, hh : hh + 1] += jnp.sum(dpre, axis=1, keepdims=True)
 
     @pl.when(w == nw - 1)
     def _finalize():
-        dthd_ref[:, 0] = dthd_acc_ref[...]
+        dthd_ref[...] = dthd_acc_ref[...]
 
 
 def _common_maps():
-    def mask_map(h, u, w, col, gid, row, bias):
+    def mask_map(u, w, col, gid, row, bias):
         return (u, w, 0, 0)
 
-    def thd_map(h, u, w, col, gid, row, bias):
-        return (gid[u], row[u], h)
+    def thd_map(u, w, col, gid, row, bias):
+        return (gid[u], row[u], 0)
 
-    def ths_map(h, u, w, col, gid, row, bias):
-        return (gid[u], jnp.maximum(col[u, w], 0), h)
+    def ths_map(u, w, col, gid, row, bias):
+        return (gid[u], 0, jnp.maximum(col[u, w], 0))
 
-    def hs_map(h, u, w, col, gid, row, bias):
-        return (jnp.maximum(col[u, w], 0), h, 0)
+    def hs_map(u, w, col, gid, row, bias):
+        return (jnp.maximum(col[u, w], 0), 0)
 
-    return mask_map, thd_map, ths_map, hs_map
+    def unit_map(u, w, col, gid, row, bias):
+        return (u, 0)
+
+    return mask_map, thd_map, ths_map, hs_map, unit_map
+
+
+def _in_specs(B, H, hdh):
+    mask_map, thd_map, ths_map, hs_map, _ = _common_maps()
+    return [
+        pl.BlockSpec((1, 1, B, B), mask_map),
+        pl.BlockSpec((1, B, H), thd_map),
+        pl.BlockSpec((1, H, B), ths_map),
+        pl.BlockSpec((B, hdh), hs_map),
+    ]
 
 
 def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
@@ -183,46 +216,40 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
     B = masks.shape[-1]
     G, ns_pad, H = theta_src.shape
     Dh = h_src.shape[-1]
-    mask_map, thd_map, ths_map, hs_map = _common_maps()
-
-    def out_map(h, u, w, col, gid, row, bias):
-        return (u, h, 0)
-
-    def lse_map(h, u, w, col, gid, row, bias):
-        return (u, h)
+    hdh = H * Dh
+    unit_map = _common_maps()[-1]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(H, U, W),
-        in_specs=[
-            pl.BlockSpec((1, 1, B, B), mask_map),
-            pl.BlockSpec((1, B, 1), thd_map),
-            pl.BlockSpec((1, B, 1), ths_map),
-            pl.BlockSpec((B, 1, Dh), hs_map),
-        ],
+        grid=(U, W),
+        in_specs=_in_specs(B, H, hdh),
         out_specs=[
-            pl.BlockSpec((B, 1, Dh), out_map),
-            pl.BlockSpec((B, 1), lse_map),
+            pl.BlockSpec((B, hdh), unit_map),
+            pl.BlockSpec((B, H), unit_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((B, Dh), jnp.float32),
-            pltpu.VMEM((B,), jnp.float32),
-            pltpu.VMEM((B,), jnp.float32),
+            pltpu.VMEM((B, hdh), jnp.float32),
+            pltpu.VMEM((B, H), jnp.float32),
+            pltpu.VMEM((B, H), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, leaky_slope=leaky_slope),
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, heads=H, head_dim=Dh, leaky_slope=leaky_slope
+        ),
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((U * B, H, Dh), h_src.dtype),
+            jax.ShapeDtypeStruct((U * B, hdh), h_src.dtype),
             jax.ShapeDtypeStruct((U * B, H), jnp.float32),
         ),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="seg_gat_agg_multigraph",
-    )(col_index, graph_id, dst_row, edge_bias, masks, theta_dst, theta_src, h_src)
+    )(col_index, graph_id, dst_row, edge_bias, masks, theta_dst,
+      theta_src.swapaxes(1, 2), h_src.reshape(ns_pad, hdh))
+    return out.reshape(U * B, H, Dh), lse
 
 
 def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
@@ -231,54 +258,46 @@ def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
     B = masks.shape[-1]
     G, ns_pad, H = theta_src.shape
     Dh = h_src.shape[-1]
-    mask_map, thd_map, ths_map, hs_map = _common_maps()
+    hdh = H * Dh
+    unit_map = _common_maps()[-1]
 
-    def gout_map(h, u, w, col, gid, row, bias):
-        return (u, h, 0)
-
-    def unit_vec_map(h, u, w, col, gid, row, bias):
-        return (u, h)
-
-    def dths_map(h, u, w, col, gid, row, bias):
-        return (u, w, 0, h)
-
-    def dhs_map(h, u, w, col, gid, row, bias):
-        return (u, w, 0, h, 0)
+    def slot_map(u, w, col, gid, row, bias):
+        return (u, w, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(H, U, W),
-        in_specs=[
-            pl.BlockSpec((1, 1, B, B), mask_map),
-            pl.BlockSpec((1, B, 1), thd_map),
-            pl.BlockSpec((1, B, 1), ths_map),
-            pl.BlockSpec((B, 1, Dh), hs_map),
-            pl.BlockSpec((B, 1, Dh), gout_map),
-            pl.BlockSpec((B, 1), unit_vec_map),
-            pl.BlockSpec((B, 1), unit_vec_map),
+        grid=(U, W),
+        in_specs=_in_specs(B, H, hdh) + [
+            pl.BlockSpec((B, hdh), unit_map),
+            pl.BlockSpec((B, H), unit_map),
+            pl.BlockSpec((B, H), unit_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, B, 1), dths_map),
-            pl.BlockSpec((1, 1, B, 1, Dh), dhs_map),
-            pl.BlockSpec((B, 1), unit_vec_map),
+            pl.BlockSpec((1, 1, H, B), slot_map),
+            pl.BlockSpec((1, 1, B, hdh), slot_map),
+            pl.BlockSpec((B, H), unit_map),
         ],
-        scratch_shapes=[pltpu.VMEM((B,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, leaky_slope=leaky_slope),
+    dths, dhs, dthd = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, heads=H, head_dim=Dh, leaky_slope=leaky_slope
+        ),
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((U, W, B, H), jnp.float32),
-            jax.ShapeDtypeStruct((U, W, B, H, Dh), jnp.float32),
+            jax.ShapeDtypeStruct((U, W, H, B), jnp.float32),
+            jax.ShapeDtypeStruct((U, W, B, hdh), jnp.float32),
             jax.ShapeDtypeStruct((U * B, H), jnp.float32),
         ),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="seg_gat_agg_multigraph_bwd",
-    )(col_index, graph_id, dst_row, edge_bias, masks, theta_dst, theta_src,
-      h_src, g_out, lse, delta)
+    )(col_index, graph_id, dst_row, edge_bias, masks, theta_dst,
+      theta_src.swapaxes(1, 2), h_src.reshape(ns_pad, hdh),
+      g_out.reshape(U * B, hdh), lse, delta)
+    return dths.swapaxes(2, 3), dhs.reshape(U, W, B, H, Dh), dthd
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))

@@ -27,7 +27,7 @@ from ..models.lm.config import LMConfig
 from ..optim import AdamWConfig
 from ..serve.engine import ServeState, init_serve_state, make_serve_step
 from ..train.step import init_train_state, make_train_step, train_state_axes
-from .hlostats import analyze, normalize_cost_analysis
+from .hlostats import analyze
 from .mesh import make_production_mesh
 
 # TPU v5e hardware constants (roofline denominators)
@@ -261,7 +261,7 @@ def lower_cell(
         return result
 
     mem = compiled.memory_analysis()
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     stats = analyze(hlo)
     if save_hlo:

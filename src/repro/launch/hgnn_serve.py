@@ -13,8 +13,9 @@ validates the same kernel on CPU; ``block`` is the pure-jnp fallback.
 ``--na-backend fused-fp`` runs the stage-fusion megakernel: on a cache
 miss the target type's FP happens inside the NA launch (DESIGN.md §10);
 on a full-table cache hit the engine dispatches the projected multigraph
-path instead.  Compiled Pallas backends degrade to their interpret
-variants on CPU-only hosts.
+path instead.  The compiled Pallas backends (``multigraph``,
+``fused_fp``) run only on a TPU and raise on a host without one; their
+``*_interpret`` variants run the same kernel bodies on the CPU.
 """
 from __future__ import annotations
 
@@ -23,12 +24,11 @@ import json
 import sys
 import time
 
-import jax
-
-from ..core.fusion import NABackend, cpu_fallback
+from ..core.fusion import NABackend, require_tpu
 from ..graphs import dataset_metapaths, dataset_target, synthetic_hetgraph
 from ..obs import MetricsRegistry, disable_tracing, enable_tracing
 from ..serve.hgnn_engine import HGNNEngine, make_request_mix
+from .compile_cache import enable_compile_cache
 
 _BACKENDS = {
     "segment": NABackend.SEGMENT,
@@ -39,18 +39,6 @@ _BACKENDS = {
     "fused-fp": NABackend.FUSED_FP,  # alias
     "fused_fp_interpret": NABackend.FUSED_FP_INTERPRET,
 }
-
-
-def _resolve_backend(name: str) -> NABackend:
-    backend = _BACKENDS[name]
-    resolved = cpu_fallback(backend)
-    if resolved is not backend:
-        print(
-            f"note: --na-backend {name} needs a TPU; falling back to "
-            f"{resolved.value} on {jax.default_backend()}",
-            file=sys.stderr,
-        )
-    return resolved
 
 
 def _target_metapaths(name: str, target: str) -> list[tuple[str, ...]]:
@@ -68,7 +56,7 @@ def serve_mix(graph, target, clusters, args, admission, registry=None) -> dict:
         cache_block_rows=args.cache_block_rows,
         cache_policy=args.policy,
         admission=admission,
-        backend=_resolve_backend(args.na_backend),
+        backend=_BACKENDS[args.na_backend],
         block=args.block,
         max_edges=args.max_edges,
         registry=registry,
@@ -112,6 +100,8 @@ def main() -> None:
              "per-step latency histogram) as JSON",
     )
     args = ap.parse_args()
+    require_tpu(_BACKENDS[args.na_backend].value)
+    enable_compile_cache()
 
     graph = synthetic_hetgraph(args.dataset, scale=args.scale, feat_scale=args.feat_scale, seed=0)
     target, _ = dataset_target(args.dataset)

@@ -19,8 +19,10 @@ tolerance — the lane partition only regroups the cross-unit reduction).
 
 R-GAT trains through its per-relation forward with the same fused
 multigraph kernel per relation (its relation-specific projections keep it
-off the consolidated one-launch plan).  Compiled kernels degrade to the
-interpreter on CPU-only hosts (same kernel body, same numbers).
+off the consolidated one-launch plan).  ``--backend kernel`` compiles the
+Pallas kernels for the TPU and refuses to run without one; on a CPU host
+ask for ``kernel_interpret``, which runs the same kernel body under the
+Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -30,8 +32,8 @@ import json
 import jax
 import numpy as np
 
-from ..core import NABackend, cpu_fallback, similarity_schedule
-from ..core.multilane import build_multilane_plan, resolve_multilane_backend
+from ..core import NABackend, require_tpu, similarity_schedule
+from ..core.multilane import build_multilane_plan, place_plan
 from ..data import SyntheticHGNNData
 from ..dist.sharding import lane_axes, make_rules, param_shardings, use_rules
 from ..graphs import (
@@ -51,6 +53,7 @@ from ..train import (
     make_hgnn_train_step,
     train_loop,
 )
+from .compile_cache import enable_compile_cache
 from .mesh import make_lane_mesh
 
 DATASETS = ("acm", "imdb", "dblp")
@@ -124,6 +127,7 @@ def run_training(
     snapshots the metrics registry (step-time histogram, loss/grad-norm
     gauges, characterization stage histogram) to JSON.
     """
+    require_tpu(backend)
     reg = registry if registry is not None else get_registry()
     tracer = enable_tracing(sync=True) if trace else None
     g, data = build_problem(
@@ -145,20 +149,20 @@ def run_training(
         # step, lane-sharded over the mesh (the tentpole configuration)
         n_plan_lanes = plan_lanes or lanes
         assert n_plan_lanes % lanes == 0, (n_plan_lanes, lanes)
-        plan = build_multilane_plan(data.graphs, n_plan_lanes)
-        na_backend = resolve_multilane_backend(backend)
-        forward_fn = lambda p: han_forward_multilane(
-            p, data, plan, mesh=mesh, lane_axes=lane_axes(rules), backend=na_backend
+        # each lane shard of the plan lives on the device that runs it
+        plan = place_plan(
+            build_multilane_plan(data.graphs, n_plan_lanes), mesh, lane_axes(rules)
         )
-        meta_backend = na_backend
+        forward_fn = lambda p: han_forward_multilane(
+            p, data, plan, mesh=mesh, lane_axes=lane_axes(rules), backend=backend
+        )
+        meta_backend = backend
     else:
         # per-relation projections -> per-relation fused kernel launches
         plan = None
-        nab = cpu_fallback(
-            {"kernel": NABackend.MULTIGRAPH,
-             "kernel_interpret": NABackend.MULTIGRAPH_INTERPRET,
-             "reference": NABackend.BLOCK}[backend]
-        )
+        nab = {"kernel": NABackend.MULTIGRAPH,
+               "kernel_interpret": NABackend.MULTIGRAPH_INTERPRET,
+               "reference": NABackend.BLOCK}[backend]
         forward_fn = lambda p: model.forward(p, data, backend=nab)
         meta_backend = nab.value
 
@@ -215,6 +219,10 @@ def run_training(
         dataset=dataset, model=model_name, backend=str(meta_backend),
         lanes=lanes, model_split=model_split,
         plan_lanes=None if plan is None else plan.num_lanes,
+        # device -> shape of the plan's mask shard it holds
+        plan_shards=None if plan is None else {
+            str(s.device): list(s.data.shape) for s in plan.masks.addressable_shards
+        },
         n_params=n_params, n_target=n_target,
         characterize=char,
     )
@@ -235,7 +243,8 @@ def main() -> None:
     ap.add_argument(
         "--backend", default="kernel",
         choices=("reference", "kernel", "kernel_interpret"),
-        help="multilane NA executor (kernel = fused multigraph Pallas launch/shard)",
+        help="multilane NA executor (kernel = fused multigraph Pallas launch per "
+             "shard, TPU only; kernel_interpret = the same kernel interpreted on CPU)",
     )
     ap.add_argument("--hidden", type=int, default=16)
     ap.add_argument("--heads", type=int, default=4)
@@ -262,6 +271,7 @@ def main() -> None:
              "loss/grad-norm gauges, characterization stage histogram)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     state, history, meta = run_training(
         dataset=args.dataset, model_name=args.model, steps=args.steps,

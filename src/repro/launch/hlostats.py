@@ -19,14 +19,6 @@ import dataclasses
 import re
 from collections import defaultdict
 
-
-def normalize_cost_analysis(cost) -> dict:
-    """`Compiled.cost_analysis()` returns a dict on current jax and a
-    one-element list of dicts on older releases; hand back the dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
     "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,

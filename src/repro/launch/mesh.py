@@ -12,9 +12,10 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     n = math.prod(shape)
     devs = jax.devices()
     assert len(devs) >= n, f"need {n} devices, have {len(devs)}"
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type is None else {"axis_types": (axis_type.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, devices=devs[:n], **kw)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devs[:n],
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

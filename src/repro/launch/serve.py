@@ -17,6 +17,7 @@ import numpy as np
 from ..configs import ARCH_IDS, get_config, smoke_config
 from ..models.lm.api import build
 from ..serve.engine import greedy_generate
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--steps", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     api = build(cfg)

@@ -22,6 +22,7 @@ from ..models.lm.api import build
 from ..optim import AdamWConfig
 from ..train import make_train_step, train_loop
 from ..train.step import init_train_state, train_state_axes
+from .compile_cache import enable_compile_cache
 from .mesh import make_mesh, make_production_mesh
 
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     api = build(cfg)

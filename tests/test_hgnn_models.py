@@ -52,13 +52,14 @@ def test_han_backends_and_staged_agree(acm):
 
 
 def test_han_kernel_backend_matches(acm):
-    """The Pallas kernel (interpret mode) is a drop-in NA backend."""
+    """The Pallas kernel (interpret mode) is a drop-in single-graph NA
+    backend: one semantic graph runs as the multigraph kernel at G=1."""
     g, target, ncls, labels, mp, _ = acm
     data = prepare_data(g, mp[:1], target, ncls, labels, block=16)
     model = MODELS["HAN"]
     params = model.init(jax.random.key(2), data)
     l_seg = model.forward(params, data, backend=NABackend.SEGMENT)
-    l_ker = model.forward(params, data, backend=NABackend.KERNEL_INTERPRET)
+    l_ker = model.forward(params, data, backend=NABackend.MULTIGRAPH_INTERPRET)
     np.testing.assert_allclose(np.asarray(l_seg), np.asarray(l_ker), rtol=5e-4, atol=5e-4)
 
 

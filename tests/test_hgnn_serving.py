@@ -57,7 +57,8 @@ def test_fp_cache_capacity_bound_and_hits():
     assert cache.stats.misses == 4 and cache.stats.hits == 0
     assert cache.resident_bytes == 4 * blk_bytes <= cache.capacity_bytes
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(stages.feature_projection(x, w, b)), rtol=1e-6
+        np.asarray(out), np.asarray(stages.feature_projection(x, w, b)),
+        rtol=1e-6, atol=1e-6,
     )
 
     again = cache.project("a", x, w, b)

@@ -9,9 +9,19 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_fp_coeff import fused_fp_coeff
 from repro.kernels.ref import ref_flash_attention, ref_fused_fp_coeff, ref_seg_gat_agg
-from repro.kernels.seg_gat_agg import seg_gat_agg
+from repro.kernels.seg_gat_agg_multigraph import seg_gat_agg_multigraph
 
 TOL = {jnp.float32: dict(rtol=3e-5, atol=3e-5), jnp.bfloat16: dict(rtol=3e-2, atol=3e-2)}
+
+
+def seg_gat_agg(col, masks, ths, thd, hs, *, edge_bias=None, interpret):
+    """Single-graph NA: the multigraph kernel at G=1, one unit per dst row."""
+    R = col.shape[0]
+    return seg_gat_agg_multigraph(
+        col, jnp.zeros((R,), jnp.int32), jnp.arange(R, dtype=jnp.int32), masks,
+        ths[None], thd[None], hs, None if edge_bias is None else edge_bias[None],
+        interpret=interpret,
+    )
 
 
 def _unique_cols(rng, R, W, ncols):
@@ -180,28 +190,29 @@ def test_seg_gat_agg_multigraph_bf16_matches_f32_oracle():
 
 
 def test_seg_gat_agg_multigraph_g1_reduces_to_seg_gat_agg():
-    """G=1 with one unit per dst row in order IS the single-graph kernel."""
-    from repro.kernels import seg_gat_agg_multigraph
+    """Single-graph NA (``neighbor_aggregate``) routes through the
+    multigraph kernel at G=1 and matches the dense single-graph oracle."""
+    from repro.core import NABackend, SemanticGraphBatch, neighbor_aggregate
 
     rng = np.random.default_rng(5)
     B, R, W, H, Dh, nblk = 8, 3, 2, 2, 8, 4
     ns = nblk * B
     col = _unique_cols(rng, R, W, nblk)
     masks = rng.random((R, W, B, B)) < 0.4
-    ths = rng.standard_normal((ns, H)).astype(np.float32)
-    thd = rng.standard_normal((R * B, H)).astype(np.float32)
-    hs = rng.standard_normal((ns, H, Dh)).astype(np.float32)
-    bias = rng.standard_normal((H,)).astype(np.float32)
-    single = seg_gat_agg(
-        jnp.asarray(col), jnp.asarray(masks), jnp.asarray(ths), jnp.asarray(thd),
-        jnp.asarray(hs), edge_bias=jnp.asarray(bias), interpret=True,
+    ths = jnp.asarray(rng.standard_normal((ns, H)).astype(np.float32))
+    thd = jnp.asarray(rng.standard_normal((R * B, H)).astype(np.float32))
+    hs = jnp.asarray(rng.standard_normal((ns, H, Dh)).astype(np.float32))
+    bias = jnp.asarray(rng.standard_normal((H,)).astype(np.float32))
+    batch = SemanticGraphBatch(
+        name="g", src_type="t", dst_type="t", num_src=ns, num_dst=R * B,
+        num_edges=int(masks.sum()), path_types=("t", "t"),
+        col_index=jnp.asarray(col), masks=jnp.asarray(masks), block=B,
     )
-    multi = seg_gat_agg_multigraph(
-        jnp.asarray(col), jnp.zeros((R,), jnp.int32), jnp.arange(R, dtype=jnp.int32),
-        jnp.asarray(masks), jnp.asarray(ths)[None], jnp.asarray(thd)[None],
-        jnp.asarray(hs), jnp.asarray(bias)[None], interpret=True,
+    single = neighbor_aggregate(
+        batch, ths, thd, hs, backend=NABackend.MULTIGRAPH_INTERPRET, edge_bias=bias
     )
-    np.testing.assert_allclose(np.asarray(multi), np.asarray(single), **TOL[jnp.float32])
+    ref = ref_seg_gat_agg(jnp.asarray(col), jnp.asarray(masks), ths, thd, hs, edge_bias=bias)
+    np.testing.assert_allclose(np.asarray(single), np.asarray(ref), **TOL[jnp.float32])
 
 
 def test_seg_gat_agg_multigraph_vjp_matches_block_autodiff():

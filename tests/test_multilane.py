@@ -19,12 +19,12 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import NABackend, batch_semantic_graph, cpu_fallback, neighbor_aggregate
-from repro.core.fusion import build_unit_tables
+from repro.core import NABackend, batch_semantic_graph, neighbor_aggregate
+from repro.core.fusion import build_unit_tables, neighbor_aggregate_multi
 from repro.core.multilane import build_multilane_plan, multilane_na
 from repro.graphs import build_semantic_graphs, dataset_metapaths, synthetic_hetgraph
 from repro.graphs.hetgraph import SemanticGraph
-from repro.launch.hgnn_train import build_problem
+from repro.launch.hgnn_train import build_problem, run_training
 from repro.launch.mesh import make_lane_mesh
 from repro.models.hgnn import han_forward_multilane
 from repro.models.hgnn.han import han_forward, init_han
@@ -80,6 +80,27 @@ def test_multilane_kernel_backend_matches_reference(dblp_setup, lanes):
 def test_multilane_backend_rejects_unknown():
     with pytest.raises(ValueError, match="backend"):
         multilane_na(None, None, None, None, backend="nope")
+
+
+@pytest.mark.parametrize(
+    "call, twin",
+    [
+        (lambda s: multilane_na(build_multilane_plan(s[0], 1), *s[1:], backend="kernel"),
+         "kernel_interpret"),
+        (lambda s: neighbor_aggregate_multi(s[0], *s[1:], backend=NABackend.MULTIGRAPH),
+         "multigraph_interpret"),
+        (lambda s: neighbor_aggregate_multi(s[0], None, None, None, backend=NABackend.FUSED_FP),
+         "fused_fp_interpret"),
+        (lambda s: run_training(steps=1, backend="kernel"), "kernel_interpret"),
+    ],
+    ids=["multilane", "multigraph", "fused_fp", "run_training"],
+)
+def test_compiled_backend_without_tpu_raises(dblp_setup, call, twin):
+    """A compiled Pallas backend never degrades to the interpreter: on a
+    host without a TPU it raises and names the interpret variant."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match=twin):
+        call(dblp_setup)
 
 
 def test_balanced_beats_naive_on_skewed_workload(dblp_setup):
@@ -138,11 +159,7 @@ def test_han_train_step_differential_backends(acm_han):
     at f32 tolerance (MULTIGRAPH's custom-VJP recompute backward vs
     autodiff)."""
     data, params = acm_han
-    backends = [
-        NABackend.BLOCK,
-        cpu_fallback(NABackend.MULTIGRAPH),  # compiled on TPU, interpret on CPU
-        NABackend.MULTIGRAPH_INTERPRET,
-    ]
+    backends = [NABackend.BLOCK, NABackend.MULTIGRAPH_INTERPRET]
     results = [
         _loss_and_grad(data, params, lambda p, b=b: han_forward(p, data, backend=b))
         for b in backends
