@@ -17,8 +17,6 @@ Prints ``name,us_per_call,derived[,backend=...]`` CSV rows:
                        autotune sweep (collective-vs-compute crossover)
   roofline/*         — §Roofline terms per (arch × shape × mesh), from
                        the dry-run artifacts (run launch/dryrun first)
-  obs_overhead/*     — tracing/metrics layer overhead: traced-off vs
-                       traced-on step time + raw span cost (DESIGN.md §12)
 
 ``--json`` additionally writes the rows as ``BENCH_<only>.json`` (or
 ``BENCH.json`` for a full run): a list of
@@ -47,7 +45,6 @@ def _registry() -> dict:
         kernels_bench,
         lanes,
         multilane_bench,
-        obs_overhead,
         roofline,
         similarity,
         stage_fusion,
@@ -74,7 +71,6 @@ def _registry() -> dict:
     register("hgnn_train", hgnn_train.run)
     register("stage_roofline", stage_roofline.run)
     register("roofline", roofline.run)
-    register("obs_overhead", obs_overhead.run)
     return benches
 
 

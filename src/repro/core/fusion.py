@@ -36,7 +36,6 @@ import numpy as np
 
 from ..graphs.formats import to_block_csr, to_padded_edges
 from ..graphs.hetgraph import SemanticGraph
-from ..obs.trace import trace_span
 from . import stages
 
 
@@ -319,13 +318,6 @@ def neighbor_aggregate_multi(
     ``fp=FusedFPInputs(...)`` (raw features + projection/attention params)
     and leave theta_src/theta_dst/h_src as None — no projected tensor is
     ever materialized in HBM (DESIGN.md §10).
-
-    Spans (obs.trace, DESIGN.md §12): fused backends emit one ``stage=NA``
-    span for the whole launch (its indivisibility is the point); the
-    per-graph fallback emits one ``na/<graph>`` span per semantic graph on
-    its own ``sg/<graph>`` lane row.  Under jit these fire at trace time;
-    eager callers (the serving engine, obs.characterize) get real timing
-    via the sync boundary.
     """
     require_tpu(backend.value)
     if backend in _FUSED_FP_BACKENDS:
@@ -351,34 +343,23 @@ def neighbor_aggregate_multi(
         col, gid, row, masks = unit_tables
         x_pad = _pad_rows(fp.x, max(ns_pad, nd_pad))
         g_n = len(batches)
-        with trace_span(
-            "na/fused_fp", stage="NA", backend=backend.value, graphs=g_n,
-            units=int(col.shape[0]), fused_fp=True,
-            graph_names=[bb.name for bb in batches],
-        ) as sp:
-            out = seg_gat_agg_fused_fp(
-                col, gid, row, fp.wsel, masks, x_pad, fp.w, fp.b,
-                fp.a_src, fp.a_dst, edge_bias,
-                leaky_slope=leaky_slope,
-                interpret=backend is NABackend.FUSED_FP_INTERPRET,
-            )  # [G*R*B, H, Dh] — units are g-major, rows in order
-            out = sp.sync(out)
+        out = seg_gat_agg_fused_fp(
+            col, gid, row, fp.wsel, masks, x_pad, fp.w, fp.b,
+            fp.a_src, fp.a_dst, edge_bias,
+            leaky_slope=leaky_slope,
+            interpret=backend is NABackend.FUSED_FP_INTERPRET,
+        )  # [G*R*B, H, Dh] — units are g-major, rows in order
         return out.reshape(g_n, nd_pad, *out.shape[1:])[:, :nd]
 
     if backend not in _MULTIGRAPH_BACKENDS:
-        outs = []
-        for i, bb in enumerate(batches):
-            with trace_span(
-                f"na/{bb.name}", stage="NA", lane=f"sg/{bb.name}",
-                graph=bb.name, backend=backend.value, edges=bb.num_edges,
-            ) as sp:
-                z = neighbor_aggregate(
-                    bb, theta_src[i], theta_dst[i], h_src[: bb.num_src],
-                    backend=backend, leaky_slope=leaky_slope,
-                    edge_bias=0.0 if edge_bias is None else edge_bias[i],
-                )
-                outs.append(sp.sync(z))
-        return jnp.stack(outs)
+        return jnp.stack([
+            neighbor_aggregate(
+                bb, theta_src[i], theta_dst[i], h_src[: bb.num_src],
+                backend=backend, leaky_slope=leaky_slope,
+                edge_bias=0.0 if edge_bias is None else edge_bias[i],
+            )
+            for i, bb in enumerate(batches)
+        ])
 
     from ..kernels.seg_gat_agg_multigraph import seg_gat_agg_multigraph
 
@@ -394,16 +375,11 @@ def neighbor_aggregate_multi(
     th_d = _pad_rows(theta_dst.swapaxes(0, 1), nd_pad).swapaxes(0, 1)
     hs = _pad_rows(h_src, ns_pad)
     g_n = len(batches)
-    with trace_span(
-        "na/multigraph", stage="NA", backend=backend.value, graphs=g_n,
-        units=int(col.shape[0]), graph_names=[bb.name for bb in batches],
-    ) as sp:
-        out = seg_gat_agg_multigraph(
-            col, gid, row, masks, th_s, th_d, hs, edge_bias,
-            leaky_slope=leaky_slope,
-            interpret=backend is NABackend.MULTIGRAPH_INTERPRET,
-        )  # [G*R*B, H, Dh] — units are g-major, rows in order
-        out = sp.sync(out)
+    out = seg_gat_agg_multigraph(
+        col, gid, row, masks, th_s, th_d, hs, edge_bias,
+        leaky_slope=leaky_slope,
+        interpret=backend is NABackend.MULTIGRAPH_INTERPRET,
+    )  # [G*R*B, H, Dh] — units are g-major, rows in order
     return out.reshape(g_n, nd_pad, *out.shape[1:])[:, :nd]
 
 

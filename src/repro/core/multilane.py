@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..obs.trace import trace_span
 from .fusion import FusedFPInputs, SemanticGraphBatch, require_tpu
 from .scheduling import LanePlan, lane_assignment, naive_lane_assignment
 
@@ -231,52 +230,48 @@ def multilane_na(
         edge_bias = jnp.zeros((g_n, h_dim), out_dtype)
 
     lanes, units, w = plan.col_index.shape
-    with trace_span(
-        "na/multilane", stage="NA", backend=backend, lanes=lanes,
-        units=units, graphs=g_n,
-    ) as sp:
-        if backend == "reference":
-            unit_fn = lambda c, m, g, r: _unit_na(
-                c, m, g, r, theta_src, theta_dst, h_src, edge_bias, leaky_slope
-            )
-            per_unit = jax.vmap(jax.vmap(unit_fn))(
-                plan.col_index, plan.masks, plan.graph_id, plan.dst_row
-            )  # [L, U, B, H, Dh]
-        elif fused_fp:
-            from repro.kernels.seg_gat_agg_fused_fp import seg_gat_agg_fused_fp
+    if backend == "reference":
+        unit_fn = lambda c, m, g, r: _unit_na(
+            c, m, g, r, theta_src, theta_dst, h_src, edge_bias, leaky_slope
+        )
+        per_unit = jax.vmap(jax.vmap(unit_fn))(
+            plan.col_index, plan.masks, plan.graph_id, plan.dst_row
+        )  # [L, U, B, H, Dh]
+    elif fused_fp:
+        from repro.kernels.seg_gat_agg_fused_fp import seg_gat_agg_fused_fp
 
-            flat = seg_gat_agg_fused_fp(
-                plan.col_index.reshape(lanes * units, w),
-                plan.graph_id.reshape(lanes * units),
-                plan.dst_row.reshape(lanes * units),
-                fp.wsel,
-                plan.masks.reshape(lanes * units, w, plan.block, plan.block),
-                fp.x, fp.w, fp.b, fp.a_src, fp.a_dst, edge_bias,
-                leaky_slope=leaky_slope,
-                interpret=(backend == "fused_fp_interpret"),
-            )  # [L*U*B, H, Dh]
-            per_unit = flat.reshape(lanes, units, plan.block, h_dim, dh)
-        else:
-            from repro.kernels.seg_gat_agg_multigraph import seg_gat_agg_multigraph
+        flat = seg_gat_agg_fused_fp(
+            plan.col_index.reshape(lanes * units, w),
+            plan.graph_id.reshape(lanes * units),
+            plan.dst_row.reshape(lanes * units),
+            fp.wsel,
+            plan.masks.reshape(lanes * units, w, plan.block, plan.block),
+            fp.x, fp.w, fp.b, fp.a_src, fp.a_dst, edge_bias,
+            leaky_slope=leaky_slope,
+            interpret=(backend == "fused_fp_interpret"),
+        )  # [L*U*B, H, Dh]
+        per_unit = flat.reshape(lanes, units, plan.block, h_dim, dh)
+    else:
+        from repro.kernels.seg_gat_agg_multigraph import seg_gat_agg_multigraph
 
-            flat = seg_gat_agg_multigraph(
-                plan.col_index.reshape(lanes * units, w),
-                plan.graph_id.reshape(lanes * units),
-                plan.dst_row.reshape(lanes * units),
-                plan.masks.reshape(lanes * units, w, plan.block, plan.block),
-                theta_src,
-                theta_dst,
-                h_src,
-                edge_bias,
-                leaky_slope=leaky_slope,
-                interpret=(backend == "kernel_interpret"),
-            )  # [L*U*B, H, Dh]
-            per_unit = flat.reshape(lanes, units, plan.block, h_dim, dh)
+        flat = seg_gat_agg_multigraph(
+            plan.col_index.reshape(lanes * units, w),
+            plan.graph_id.reshape(lanes * units),
+            plan.dst_row.reshape(lanes * units),
+            plan.masks.reshape(lanes * units, w, plan.block, plan.block),
+            theta_src,
+            theta_dst,
+            h_src,
+            edge_bias,
+            leaky_slope=leaky_slope,
+            interpret=(backend == "kernel_interpret"),
+        )  # [L*U*B, H, Dh]
+        per_unit = flat.reshape(lanes, units, plan.block, h_dim, dh)
 
-        out = jnp.zeros((g_n, plan.n_dst_blocks, plan.block, h_dim, dh), out_dtype)
-        contrib = jnp.where(plan.valid[:, :, None, None, None], per_unit, 0.0)
-        out = out.at[plan.graph_id, plan.dst_row].add(contrib)
-        return sp.sync(out.reshape(g_n, plan.n_dst_blocks * plan.block, h_dim, dh))
+    out = jnp.zeros((g_n, plan.n_dst_blocks, plan.block, h_dim, dh), out_dtype)
+    contrib = jnp.where(plan.valid[:, :, None, None, None], per_unit, 0.0)
+    out = out.at[plan.graph_id, plan.dst_row].add(contrib)
+    return out.reshape(g_n, plan.n_dst_blocks * plan.block, h_dim, dh)
 
 
 def _plan_specs(plan: MultiLanePlan, lane_axes: tuple[str, ...]) -> MultiLanePlan:
@@ -370,11 +365,7 @@ def multilane_na_sharded(
             out_specs=rep,
             check_vma=False,
         )
-        with trace_span(
-            "na/multilane_sharded", stage="NA", backend=backend,
-            shards=n_shards, lanes=plan.num_lanes, graphs=g_n, fused_fp=True,
-        ) as sp:
-            return sp.sync(fn(plan, fp, edge_bias))
+        return fn(plan, fp, edge_bias)
 
     def local(plan_loc, ths, thd, hs, bias):
         # backend applies per shard: "kernel" = one fused Pallas launch
@@ -392,8 +383,4 @@ def multilane_na_sharded(
         out_specs=rep,
         check_vma=False,
     )
-    with trace_span(
-        "na/multilane_sharded", stage="NA", backend=backend,
-        shards=n_shards, lanes=plan.num_lanes, graphs=g_n,
-    ) as sp:
-        return sp.sync(fn(plan, theta_src, theta_dst, h_src, edge_bias))
+    return fn(plan, theta_src, theta_dst, h_src, edge_bias)

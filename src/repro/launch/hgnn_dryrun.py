@@ -19,7 +19,7 @@ from ..core.multilane import MultiLanePlan, multilane_na, multilane_na_sharded
 from ..core.scheduling import LanePlan
 from ..core import stages
 from ..dist.sharding import make_rules, use_rules
-from ..obs import disable_tracing, enable_tracing, trace_span
+from ..obs import profile, trace_span
 from .hlostats import analyze, span_attrs
 from .mesh import make_lane_mesh
 
@@ -110,13 +110,19 @@ def main():
     )
     ap.add_argument("--out", default="artifacts/dryrun/hgnn_multilane.json")
     ap.add_argument(
-        "--trace", default=None, metavar="PATH",
-        help="write a Chrome-trace JSON of the dry-run (lower/compile spans; "
-             "the compiled program's span carries hlostats collective-bytes "
-             "and dot-FLOP attributes)",
+        "--trace", default=None, metavar="DIR",
+        help="profile the dry-run into DIR (.xplane.pb + perfetto_trace.json.gz): "
+             "dryrun.compile and dryrun.hlostats spans, the latter carrying "
+             "hlostats collective-bytes and dot-FLOP meta",
     )
     args = ap.parse_args()
-    tracer = enable_tracing(sync=True) if args.trace else None
+    with profile(args.trace):
+        run(ap, args)
+    if args.trace:
+        print(f"wrote a profile under {args.trace}")
+
+
+def run(ap, args):
     if args.schedule == "aligned" and args.executor != "spmd":
         ap.error("--executor shard_map only applies to --schedule balanced")
     if args.schedule == "aligned" and args.na_backend != "reference":
@@ -223,8 +229,8 @@ def main():
                 ).lower(plan, th_s, th_d, h_src, w_g, q)
         try:
             with trace_span(
-                "dryrun/compile", stage="compile", schedule=args.schedule,
-                executor=args.executor, backend=args.na_backend, lanes=lanes,
+                "dryrun.compile", schedule=args.schedule, executor=args.executor,
+                backend=args.na_backend, lanes=lanes,
             ):
                 compiled = lowered.compile()
         except Exception as e:
@@ -237,11 +243,11 @@ def main():
                 ) from e
             raise
     mem = compiled.memory_analysis()
-    with trace_span("dryrun/hlostats", stage="analyze") as sp:
+    with trace_span("dryrun.hlostats") as sp:
         stats = analyze(compiled.as_text())
         # the compiled program's communication/compute footprint rides on
-        # its span in the exported timeline
-        sp.annotate(**span_attrs(stats, schedule=args.schedule))
+        # its span in the profile
+        sp.set_metadata(**span_attrs(stats, schedule=args.schedule))
     edges_equiv = lanes * units * args.width * block * block  # masked-dense positions
     flops = stats.dot_flops
     result = dict(
@@ -262,10 +268,6 @@ def main():
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result, indent=1))
-    if tracer is not None:
-        tracer.export_chrome_trace(args.trace)
-        disable_tracing()
-        print(f"wrote {args.trace}")
 
 
 if __name__ == "__main__":

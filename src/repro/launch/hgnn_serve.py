@@ -26,7 +26,7 @@ import time
 
 from ..core.fusion import NABackend, require_tpu
 from ..graphs import dataset_metapaths, dataset_target, synthetic_hetgraph
-from ..obs import MetricsRegistry, disable_tracing, enable_tracing
+from ..obs import MetricsRegistry, profile
 from ..serve.hgnn_engine import HGNNEngine, make_request_mix
 from .compile_cache import enable_compile_cache
 
@@ -90,9 +90,10 @@ def main() -> None:
     ap.add_argument("--max-edges", type=int, default=20_000)
     ap.add_argument("--compare", action="store_true", help="run FIFO vs similarity admission")
     ap.add_argument(
-        "--trace", default=None, metavar="PATH",
-        help="write a Chrome-trace/Perfetto JSON of the serving run (sync spans: "
-             "serve/step + FP/theta/NA spans, one lane row per slot)",
+        "--trace", default=None, metavar="DIR",
+        help="profile the serving run into DIR: an .xplane.pb and a "
+             "perfetto_trace.json.gz with the engine's serve.* spans and the "
+             "device's ops on one timeline",
     )
     ap.add_argument(
         "--metrics", default=None, metavar="PATH",
@@ -108,11 +109,10 @@ def main() -> None:
     clusters = [[mp] for mp in _target_metapaths(args.dataset, target)]
     assert clusters, f"{args.dataset}: no target->target metapaths"
 
-    tracer = enable_tracing(sync=True) if args.trace else None
     # one registry across runs: --compare accumulates both admissions'
-    # counters; gauges reflect the last engine to step
+    # counters; gauges reflect the last engine built
     reg = MetricsRegistry() if args.metrics else None
-    try:
+    with profile(args.trace):
         if args.compare:
             fifo = serve_mix(graph, target, clusters, args, "fifo", registry=reg)
             sim = serve_mix(graph, target, clusters, args, "similarity", registry=reg)
@@ -124,11 +124,9 @@ def main() -> None:
                 serve_mix(graph, target, clusters, args, args.admission, registry=reg),
                 indent=1,
             ))
-    finally:
-        if tracer is not None:
-            tracer.export_chrome_trace(args.trace)
-            disable_tracing()
-            print(f"wrote {args.trace} (open at https://ui.perfetto.dev)", file=sys.stderr)
+    if args.trace:
+        print(f"wrote a profile under {args.trace} (perfetto_trace.json.gz opens "
+              f"at https://ui.perfetto.dev)", file=sys.stderr)
     if reg is not None:
         reg.export_json(args.metrics)
         print(f"wrote {args.metrics}", file=sys.stderr)

@@ -44,8 +44,7 @@ from ..graphs import (
     synthetic_labels,
 )
 from ..models.hgnn import MODELS, han_forward_multilane, prepare_data
-from ..obs import disable_tracing, enable_tracing, get_registry
-from ..obs.characterize import characterize_hgnn
+from ..obs import get_registry, profile
 from ..optim import AdamWConfig
 from ..train import (
     hgnn_train_state_axes,
@@ -109,7 +108,7 @@ def run_training(
     crash_at: int | None = None,
     log_every: int = 10,
     log=print,
-    trace: str | None = None,        # Chrome-trace JSON output path
+    trace: str | None = None,        # profiler trace directory
     metrics_out: str | None = None,  # metrics-registry snapshot path
     registry=None,
 ):
@@ -118,18 +117,16 @@ def run_training(
     Returns ``(state, history, meta)`` — meta records the resolved mesh /
     plan / backend so callers (benchmarks, tests) can assert on them.
 
-    ``trace=`` enables sync-span tracing for the whole run and writes a
-    Chrome-trace/Perfetto JSON on exit.  For HAN it also runs the eager
-    per-stage characterization pass (``obs/characterize.py``) before the
-    jitted steady state, so the timeline carries honest FP/theta/NA/FA
-    stage timing with one lane row per semantic graph — the paper's §3
-    characterization reproduced on the live model.  ``metrics_out=``
+    ``trace=`` profiles the training loop into that directory
+    (``obs.profile``): one Perfetto timeline with the ``train`` step
+    markers, the garbage collector's passes and the device's ops, each op
+    named by its stage scope (``fp``, ``theta``, ``na``, ``fusion``,
+    ``head``, ``optimizer``) in its ``op_name`` metadata.  ``metrics_out=``
     snapshots the metrics registry (step-time histogram, loss/grad-norm
-    gauges, characterization stage histogram) to JSON.
+    gauges) to JSON.
     """
     require_tpu(backend)
     reg = registry if registry is not None else get_registry()
-    tracer = enable_tracing(sync=True) if trace else None
     g, data = build_problem(
         dataset, scale=scale, feat_scale=feat_scale, block=block,
         max_edges=max_edges, seed=seed,
@@ -173,45 +170,29 @@ def run_training(
         seed=seed,
     )
 
-    char = None
-    try:
-        with mesh, use_rules(rules):
-            state = init_hgnn_train_state(
-                model, jax.random.key(seed), data, opt, **_INIT_KW[model_name](hidden, heads)
-            )
-            axes = hgnn_train_state_axes(state, opt)
-            state_sh = param_shardings(mesh, rules, axes)
-            state = jax.device_put(state, state_sh)
-            n_params = sum(
-                int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(state.params)
-            )
-            log(
-                f"[hgnn_train] {model_name}/{dataset} params={n_params/1e6:.2f}M "
-                f"edges={sum(b.num_edges for b in data.graphs)} mesh=lane{lanes}xmodel"
-                f"{model_split} backend={meta_backend}"
-            )
-            if tracer is not None and model_name == "HAN":
-                # eager per-stage pass (paper §3 measured): honest FP/theta/
-                # NA/FA spans, one lane row per semantic graph — the jitted
-                # steady state below only yields whole-step spans.
-                char = characterize_hgnn(
-                    state.params, data, backend=NABackend.BLOCK, registry=reg
-                )
-                log(
-                    "[characterize] "
-                    + " ".join(f"{k}={v:.0f}us" for k, v in char["stage_us"].items())
-                )
-            step_fn = make_hgnn_train_step(forward_fn, data, opt)
+    with mesh, use_rules(rules):
+        state = init_hgnn_train_state(
+            model, jax.random.key(seed), data, opt, **_INIT_KW[model_name](hidden, heads)
+        )
+        axes = hgnn_train_state_axes(state, opt)
+        state_sh = param_shardings(mesh, rules, axes)
+        state = jax.device_put(state, state_sh)
+        n_params = sum(
+            int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(state.params)
+        )
+        log(
+            f"[hgnn_train] {model_name}/{dataset} params={n_params/1e6:.2f}M "
+            f"edges={sum(b.num_edges for b in data.graphs)} mesh=lane{lanes}xmodel"
+            f"{model_split} backend={meta_backend}"
+        )
+        step_fn = make_hgnn_train_step(forward_fn, data, opt)
+        with profile(trace):
             state, history = train_loop(
                 state=state, train_step=step_fn, data=pipeline, steps=steps,
                 ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, resume=resume,
                 crash_at=crash_at, log_every=log_every, log=log,
                 registry=reg, state_shardings=state_sh,
             )
-    finally:
-        if tracer is not None:
-            tracer.export_chrome_trace(trace)
-            disable_tracing()
     if metrics_out:
         reg.export_json(metrics_out)
 
@@ -224,7 +205,6 @@ def run_training(
             str(s.device): list(s.data.shape) for s in plan.masks.addressable_shards
         },
         n_params=n_params, n_target=n_target,
-        characterize=char,
     )
     return state, history, meta
 
@@ -261,14 +241,15 @@ def main() -> None:
     ap.add_argument("--crash-at", type=int, default=None, help="fault injection (tests)")
     ap.add_argument("--out", default=None, help="write the loss trajectory as JSON")
     ap.add_argument(
-        "--trace", default=None, metavar="PATH",
-        help="write a Chrome-trace/Perfetto JSON of the run (enables sync spans "
-             "+ the eager per-stage characterization pass for HAN)",
+        "--trace", default=None, metavar="DIR",
+        help="profile the training loop into DIR: an .xplane.pb and a "
+             "perfetto_trace.json.gz with step markers, GC passes and the "
+             "device's ops by stage scope",
     )
     ap.add_argument(
         "--metrics", default=None, metavar="PATH",
         help="write a metrics-registry JSON snapshot (step-time histogram, "
-             "loss/grad-norm gauges, characterization stage histogram)",
+             "loss/grad-norm gauges)",
     )
     args = ap.parse_args()
     enable_compile_cache()
@@ -291,7 +272,8 @@ def main() -> None:
             json.dump({"meta": meta, "history": history}, f, indent=1)
         print(f"wrote {args.out}")
     if args.trace:
-        print(f"wrote {args.trace} (open at https://ui.perfetto.dev)")
+        print(f"wrote a profile under {args.trace} (perfetto_trace.json.gz "
+              f"opens at https://ui.perfetto.dev)")
     if args.metrics:
         print(f"wrote {args.metrics}")
 

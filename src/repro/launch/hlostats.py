@@ -227,9 +227,9 @@ def analyze(hlo_text: str) -> HLOStats:
 
 
 def span_attrs(stats: HLOStats, **extra) -> dict:
-    """Flatten an HLOStats into span attributes (obs/trace.py): scalar
-    totals plus per-kind collective bytes, so a compiled program's span in
-    the exported timeline carries its communication/compute footprint."""
+    """Flatten an HLOStats into span meta (obs/trace.py): scalar totals
+    plus per-kind collective bytes, so a compiled program's span in the
+    profile carries its communication/compute footprint."""
     attrs = dict(
         dot_flops=stats.dot_flops,
         collective_bytes=stats.total_collective_bytes,

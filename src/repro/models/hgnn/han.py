@@ -53,26 +53,13 @@ def init_han(
     return params
 
 
-def _han_embed(params, data: HGNNData, backend: NABackend):
-    """FP -> per-graph (theta, NA, LSF) -> GSF.  Pure (fusable)."""
-    x = data.features[data.target_type]
-    heads = params["a_src"].shape[1]
-    n = x.shape[0]
-
-    z_list, w_list = [], []
-    valid_dst = jnp.ones((n,), bool)
-    if backend in _FUSED_FP_BACKENDS:
-        # Megakernel path (DESIGN.md §10): FP happens INSIDE the NA launch
-        # — raw x streams through the fused kernel, h' never materializes
-        # in HBM.  One forward (and, training, one backward) launch for
-        # the whole layer.
-        fp = FusedFPInputs.shared(
-            x, params["w_fp"], params["b_fp"], params["a_src"], params["a_dst"]
-        )
-        z_all = neighbor_aggregate_multi(
-            data.graphs, None, None, None, backend=backend, fp=fp
-        )  # [G, N, H, Dh]
-        for i in range(len(data.graphs)):
+def _fuse(params, z_all, n: int):
+    """ELU, then LSF per semantic graph and GSF over them (scope
+    ``fusion``); ``z_all`` holds one [N, H, Dh] NA output per graph."""
+    with jax.named_scope("fusion"):
+        z_list, w_list = [], []
+        valid_dst = jnp.ones((n,), bool)
+        for i in range(len(z_all)):
             z = jax.nn.elu(z_all[i].reshape(n, -1))
             z = shard(z, "act_vertex", "act_feat")
             w_p = stages.local_semantic_fusion(
@@ -83,42 +70,71 @@ def _han_embed(params, data: HGNNData, backend: NABackend):
         fused, beta = stages.global_semantic_fusion(jnp.stack(w_list), jnp.stack(z_list))
         return shard(fused, "act_vertex", "act_feat"), beta
 
-    h = stages.feature_projection(x, params["w_fp"], params["b_fp"])
-    h = shard(h, "act_vertex", "act_feat")  # projected-once FP output (RAB)
-    hh = h.reshape(n, heads, -1)
 
+def _project(params, x):
+    """FP (scope ``fp``): the target features projected once, [N, H, Dh]."""
+    heads = params["a_src"].shape[1]
+    with jax.named_scope("fp"):
+        h = stages.feature_projection(x, params["w_fp"], params["b_fp"])
+        h = shard(h, "act_vertex", "act_feat")  # projected-once FP output (RAB)
+        return h.reshape(x.shape[0], heads, -1)
+
+
+def _han_embed(params, data: HGNNData, backend: NABackend):
+    """FP -> per-graph (theta, NA, LSF) -> GSF.  Pure (fusable).
+
+    Stages carry ``jax.named_scope`` names (``fp``, ``theta``, ``na``,
+    ``fusion``); the backward pass inherits them, so a profile can put
+    each device op down to its stage.
+    """
+    x = data.features[data.target_type]
+    n = x.shape[0]
+
+    if backend in _FUSED_FP_BACKENDS:
+        # Megakernel path (DESIGN.md §10): FP happens INSIDE the NA launch
+        # — raw x streams through the fused kernel, h' never materializes
+        # in HBM.  One forward (and, training, one backward) launch for
+        # the whole layer.
+        with jax.named_scope("na"):
+            fp = FusedFPInputs.shared(
+                x, params["w_fp"], params["b_fp"], params["a_src"], params["a_dst"]
+            )
+            z_all = neighbor_aggregate_multi(
+                data.graphs, None, None, None, backend=backend, fp=fp
+            )  # [G, N, H, Dh]
+        return _fuse(params, z_all, n)
+
+    hh = _project(params, x)
     if backend in _MULTIGRAPH_BACKENDS:
         # Consolidated path: all relations' theta in one einsum, all
         # relations' NA in ONE fused multigraph launch (fwd and bwd).
-        th_s = jnp.einsum("nhd,ghd->gnh", hh, params["a_src"])
-        th_d = jnp.einsum("nhd,ghd->gnh", hh, params["a_dst"])
-        z_all = neighbor_aggregate_multi(
-            data.graphs, th_s, th_d, hh, backend=backend
-        )  # [G, N, H, Dh]
-        for i in range(len(data.graphs)):
-            z = jax.nn.elu(z_all[i].reshape(n, -1))
-            z = shard(z, "act_vertex", "act_feat")
-            w_p = stages.local_semantic_fusion(
-                z, params["w_g"], params["b_g"], params["q"], valid_dst
-            )
-            z_list.append(z)
-            w_list.append(w_p)
-    else:
-        for i, batch in enumerate(data.graphs):
+        with jax.named_scope("theta"):
+            th_s = jnp.einsum("nhd,ghd->gnh", hh, params["a_src"])
+            th_d = jnp.einsum("nhd,ghd->gnh", hh, params["a_dst"])
+        with jax.named_scope("na"):
+            z_all = neighbor_aggregate_multi(
+                data.graphs, th_s, th_d, hh, backend=backend
+            )  # [G, N, H, Dh]
+        return _fuse(params, z_all, n)
+
+    z_list = []
+    for i, batch in enumerate(data.graphs):
+        with jax.named_scope("theta"):
             th_s, th_d = stages.attention_coefficients(hh, params["a_src"][i], params["a_dst"][i])
-            z = neighbor_aggregate(batch, th_s, th_d, hh, backend=backend)  # [N, H, Dh]
-            z = jax.nn.elu(z.reshape(n, -1))
-            z = shard(z, "act_vertex", "act_feat")
-            w_p = stages.local_semantic_fusion(z, params["w_g"], params["b_g"], params["q"], valid_dst)
-            z_list.append(z)
-            w_list.append(w_p)
-    fused, beta = stages.global_semantic_fusion(jnp.stack(w_list), jnp.stack(z_list))
-    return shard(fused, "act_vertex", "act_feat"), beta
+        with jax.named_scope("na"):
+            z_list.append(neighbor_aggregate(batch, th_s, th_d, hh, backend=backend))  # [N, H, Dh]
+    return _fuse(params, z_list, n)
+
+
+def _head(params, fused):
+    """The classifier (scope ``head``)."""
+    with jax.named_scope("head"):
+        return fused @ params["w_out"] + params["b_out"]
 
 
 def han_forward(params, data: HGNNData, *, backend: NABackend = NABackend.SEGMENT):
     fused, _ = _han_embed(params, data, backend)
-    return fused @ params["w_out"] + params["b_out"]
+    return _head(params, fused)
 
 
 def _han_embed_multilane(
@@ -149,40 +165,25 @@ def _han_embed_multilane(
     are bit-deterministic for a fixed topology.
     """
     x = data.features[data.target_type]
-    heads = params["a_src"].shape[1]
     n = x.shape[0]
-
-    h = stages.feature_projection(x, params["w_fp"], params["b_fp"])
-    h = shard(h, "act_vertex", "act_feat")  # projected-once FP output (RAB)
-    hh = h.reshape(n, heads, -1)
-
-    th_s = jnp.einsum("nhd,ghd->gnh", hh, params["a_src"])
-    th_d = jnp.einsum("nhd,ghd->gnh", hh, params["a_dst"])
     n_pad = plan.n_dst_blocks * plan.block  # shared src/dst vertex space
-    th_s = _pad_rows(th_s.swapaxes(0, 1), n_pad).swapaxes(0, 1)
-    th_d = _pad_rows(th_d.swapaxes(0, 1), n_pad).swapaxes(0, 1)
-    hh_p = _pad_rows(hh, n_pad)
 
-    if mesh is None:
-        z_all = multilane_na(plan, th_s, th_d, hh_p, backend=backend)
-    else:
-        z_all = multilane_na_sharded(
-            plan, th_s, th_d, hh_p, mesh=mesh, lane_axes=lane_axes, backend=backend
-        )
-    z_all = z_all[:, :n]  # [G, N, H, Dh]
-
-    z_list, w_list = [], []
-    valid_dst = jnp.ones((n,), bool)
-    for i in range(len(data.graphs)):
-        z = jax.nn.elu(z_all[i].reshape(n, -1))
-        z = shard(z, "act_vertex", "act_feat")
-        w_p = stages.local_semantic_fusion(
-            z, params["w_g"], params["b_g"], params["q"], valid_dst
-        )
-        z_list.append(z)
-        w_list.append(w_p)
-    fused, beta = stages.global_semantic_fusion(jnp.stack(w_list), jnp.stack(z_list))
-    return shard(fused, "act_vertex", "act_feat"), beta
+    hh = _project(params, x)
+    with jax.named_scope("theta"):
+        th_s = jnp.einsum("nhd,ghd->gnh", hh, params["a_src"])
+        th_d = jnp.einsum("nhd,ghd->gnh", hh, params["a_dst"])
+        th_s = _pad_rows(th_s.swapaxes(0, 1), n_pad).swapaxes(0, 1)
+        th_d = _pad_rows(th_d.swapaxes(0, 1), n_pad).swapaxes(0, 1)
+    with jax.named_scope("na"):
+        hh_p = _pad_rows(hh, n_pad)
+        if mesh is None:
+            z_all = multilane_na(plan, th_s, th_d, hh_p, backend=backend)
+        else:
+            z_all = multilane_na_sharded(
+                plan, th_s, th_d, hh_p, mesh=mesh, lane_axes=lane_axes, backend=backend
+            )
+        z_all = z_all[:, :n]  # [G, N, H, Dh]
+    return _fuse(params, z_all, n)
 
 
 def han_forward_multilane(
@@ -199,7 +200,7 @@ def han_forward_multilane(
     fused, _ = _han_embed_multilane(
         params, data, plan, mesh=mesh, lane_axes=lane_axes, backend=backend
     )
-    return fused @ params["w_out"] + params["b_out"]
+    return _head(params, fused)
 
 
 # --- staged execution (Fig. 4(a) baseline): one jitted program per stage ---

@@ -1,17 +1,13 @@
-"""Observability subsystem (DESIGN.md §12): span tracing + metrics.
+"""Observability subsystem (DESIGN.md §12): program spans + metrics.
 
-Two pillars:
-
-* ``obs.trace``   — span-based tracer with a near-zero-cost disabled
-  mode, optional ``block_until_ready`` span boundaries, and Chrome-
-  trace/Perfetto + JSONL exporters (one lane row per semantic graph /
-  mesh lane / serving slot).
-* ``obs.metrics`` — process-wide registry of counters, gauges, and
-  log-bucketed histograms with labeled series and JSON snapshots.
+* ``obs.trace``   — host spans over ``jax.profiler.TraceAnnotation``,
+  recorded into the profiler's trace next to the device's ops, and
+  ``profile(dir)``, the launchers' ``--trace DIR``.
+* ``obs.metrics`` — registry of counters, gauges, and log-bucketed
+  histograms with labeled series and JSON snapshots.
 
 ``obs.emit`` is the structured line emitter the training loop logs
-through; ``obs.characterize`` (imported explicitly — it pulls in
-``core``) measures the paper's per-stage execution bounds on live runs.
+through.
 """
 from .emit import Emitter
 from .metrics import (
@@ -22,15 +18,7 @@ from .metrics import (
     get_registry,
     reset_registry,
 )
-from .trace import (
-    Span,
-    Tracer,
-    disable_tracing,
-    enable_tracing,
-    get_tracer,
-    trace_span,
-    tracing_enabled,
-)
+from .trace import gc_spans, profile, trace_span
 
 __all__ = [
     "Counter",
@@ -38,13 +26,9 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
-    "Span",
-    "Tracer",
-    "disable_tracing",
-    "enable_tracing",
+    "gc_spans",
     "get_registry",
-    "get_tracer",
+    "profile",
     "reset_registry",
     "trace_span",
-    "tracing_enabled",
 ]

@@ -148,6 +148,7 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._series: dict[tuple, object] = {}
+        self._collectors: list = []
 
     @staticmethod
     def _key(name: str, labels: dict) -> tuple:
@@ -175,6 +176,11 @@ class MetricsRegistry:
     def histogram(self, name: str, *, base: float = 2.0, **labels) -> Histogram:
         return self._get(Histogram, name, labels, base=base)
 
+    def add_collector(self, fn) -> None:
+        """Call ``fn()`` before each snapshot, to set gauges that are
+        too costly to keep current on a hot path."""
+        self._collectors.append(fn)
+
     # -- read side ----------------------------------------------------------
 
     def value(self, name: str, **labels):
@@ -186,6 +192,8 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict:
         """JSON-able snapshot: kind -> name -> [{labels, ...series}]."""
+        for fn in list(self._collectors):
+            fn()
         out: dict[str, dict] = {"counters": {}, "gauges": {}, "histograms": {}}
         with self._lock:
             items = list(self._series.items())

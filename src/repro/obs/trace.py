@@ -1,290 +1,75 @@
-"""Span-based tracing — the paper's characterization harness made live.
+"""Program spans on the profiler's clock (DESIGN.md §12).
 
-HiHGNN's design is derived from a per-stage GPU characterization (paper
-§3, Fig. 2): which stages are compute-bound, which are memory-bound, and
-how much inter-semantic-graph overlap the hardware leaves on the table.
-This tracer turns every launcher in this repo into that harness: spans
-around the FP/theta/NA/FA stages, one *lane row* per semantic graph or
-mesh lane so inter-semantic-graph structure is visible in the timeline,
-and Chrome-trace/Perfetto + JSONL exporters (DESIGN.md §12).
+There is one span system: the JAX profiler's.  ``trace_span(name,
+**meta)`` is a ``jax.profiler.TraceAnnotation``: it is always in the
+code, and the profiler session decides whether anything is recorded.
+Recorded spans land in the same trace as the device's ops, on the same
+clock, so an idle gap of the chip can be put down to the host work that
+overlapped it.  With no session a span costs about a microsecond.
 
-Design constraints:
+Spans belong on the host, around work that the host does.  Inside jitted
+code a span would fire once, at trace time; there, name the stage with
+``jax.named_scope`` instead, which rides into each op's ``op_name``
+metadata and costs nothing at run time.
 
-* **Near-zero cost when disabled.**  The global tracer is ``None`` by
-  default; ``trace_span`` then hands back a shared no-op span and the
-  decorator form calls the wrapped function directly — traced code paths
-  are *bit-identical* to untraced ones (pinned by tests/test_obs.py).
-* **Honest device timing.**  JAX dispatch is asynchronous, so a span
-  that closes after dispatch measures nothing.  ``Span.sync(value)``
-  blocks until ``value``'s device buffers are ready when the tracer was
-  enabled with ``sync=True`` (and is a pass-through otherwise, and under
-  ``jax.jit`` tracing, where blocking is meaningless).
-* **Deterministic structure.**  Span names, attributes, nesting depth
-  and parentage depend only on the code path, never on timing — the
-  same program produces the same span tree on every run.
+Meta values are encoded into the span's name by the profiler, so they
+must not contain ``,``, ``#`` or ``=``.
 
 Usage::
 
-    tracer = enable_tracing(sync=True)
-    with trace_span("na/APA", stage="NA", lane="sg/APA", edges=n) as sp:
-        z = neighbor_aggregate(...)
-        z = sp.sync(z)          # block here, not at some later barrier
-    tracer.export_chrome_trace("trace.json")   # chrome://tracing, Perfetto
-
-    @trace_span("train/step")
-    def step(state, batch): ...
+    with profile("/tmp/run"):                 # one Perfetto timeline
+        with trace_span("serve.step", step=3) as sp:
+            ...
+            sp.set_metadata(slots=2)          # meta known only later
 """
 from __future__ import annotations
 
-import functools
-import json
-import threading
-import time
+import contextlib
+import gc
 
 import jax
 
-__all__ = [
-    "Span",
-    "Tracer",
-    "disable_tracing",
-    "enable_tracing",
-    "get_tracer",
-    "trace_span",
-    "tracing_enabled",
-]
-
-_TRACER: "Tracer | None" = None
+__all__ = ["gc_spans", "profile", "trace_span"]
 
 
-def _block_ready(value):
-    """block_until_ready on every array leaf; pass through jit tracers
-    (blocking is undefined mid-trace) and non-device values."""
-    for leaf in jax.tree_util.tree_leaves(value):
-        if isinstance(leaf, jax.core.Tracer):
-            continue
-        bur = getattr(leaf, "block_until_ready", None)
-        if bur is not None:
-            bur()
-    return value
+def trace_span(name: str, **meta) -> jax.profiler.TraceAnnotation:
+    """A host span named ``name`` (dotted: ``serve.admit``), recorded only
+    while a profiler session is on."""
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
-class Span:
-    """A live span.  ``annotate`` adds attributes; ``sync`` optionally
-    blocks on device values so the close timestamp is honest."""
+@contextlib.contextmanager
+def gc_spans():
+    """Record each pass of Python's garbage collector as a ``py.gc`` span
+    (meta: the generation collected) while the block runs."""
+    open_spans = []
 
-    __slots__ = ("tracer", "name", "lane", "attrs", "depth", "parent", "t0", "_sync")
+    def callback(phase, info):
+        if phase == "start":
+            sp = trace_span("py.gc", generation=info["generation"])
+            sp.__enter__()
+            open_spans.append(sp)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
 
-    def __init__(self, tracer, name, lane, attrs, depth, parent, sync):
-        self.tracer = tracer
-        self.name = name
-        self.lane = lane
-        self.attrs = attrs
-        self.depth = depth
-        self.parent = parent
-        self._sync = sync
-        self.t0 = 0
-
-    def annotate(self, **attrs) -> None:
-        self.attrs.update(attrs)
-
-    def sync(self, value):
-        if self._sync:
-            _block_ready(value)
-        return value
+    gc.callbacks.append(callback)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(callback)
 
 
-class _NoopSpan:
-    """Shared do-nothing span handed out while tracing is disabled."""
-
-    __slots__ = ()
-
-    def annotate(self, **attrs) -> None:
-        pass
-
-    def sync(self, value):
-        return value
-
-
-_NOOP_SPAN = _NoopSpan()
-
-
-class Tracer:
-    """Collects finished spans; exports Chrome-trace JSON and JSONL.
-
-    ``sync=True`` makes ``Span.sync`` block on device values (honest
-    stage timing); spans may override per-span via ``trace_span(...,
-    sync=False)``.  Thread-safe: each thread keeps its own span stack,
-    the finished-event list and lane-row table are lock-guarded.
-    """
-
-    def __init__(self, *, sync: bool = False):
-        self.sync = sync
-        self.events: list[dict] = []
-        self._origin_ns = time.perf_counter_ns()
-        self._lock = threading.Lock()
-        self._local = threading.local()
-        self._lanes: dict[str, int] = {}
-
-    # -- span lifecycle (driven by trace_span) ------------------------------
-
-    def _stack(self) -> list:
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
-
-    def _lane_tid(self, lane: str) -> int:
-        with self._lock:
-            if lane not in self._lanes:
-                self._lanes[lane] = len(self._lanes)
-            return self._lanes[lane]
-
-    def begin(self, name: str, lane: str | None, attrs: dict, sync: bool | None) -> Span:
-        stack = self._stack()
-        parent = stack[-1] if stack else None
-        if lane is None:
-            # inherit the enclosing span's row so nested stages stay on it
-            lane = parent.lane if parent is not None else "main"
-        sp = Span(
-            self, name, lane, attrs,
-            depth=len(stack),
-            parent=None if parent is None else parent.name,
-            sync=self.sync if sync is None else sync,
-        )
-        stack.append(sp)
-        sp.t0 = time.perf_counter_ns()
-        return sp
-
-    def end(self, span: Span) -> None:
-        t1 = time.perf_counter_ns()
-        stack = self._stack()
-        if stack and stack[-1] is span:
-            stack.pop()
-        elif span in stack:  # mis-nested close; drop it and everything above
-            del stack[stack.index(span):]
-        event = dict(
-            name=span.name,
-            ts=(span.t0 - self._origin_ns) / 1e3,   # µs since tracer start
-            dur=(t1 - span.t0) / 1e3,               # µs
-            lane=span.lane,
-            tid=self._lane_tid(span.lane),
-            depth=span.depth,
-            parent=span.parent,
-            attrs=span.attrs,
-        )
-        with self._lock:
-            self.events.append(event)
-
-    # -- exporters ----------------------------------------------------------
-
-    def export_chrome_trace(self, path: str) -> None:
-        """Chrome-trace JSON (chrome://tracing, https://ui.perfetto.dev).
-        One thread row per lane — semantic graphs / mesh lanes / slots
-        each get their own row, so inter-semantic-graph overlap (or its
-        absence) is visible at a glance."""
-        out = [dict(ph="M", name="process_name", pid=0, tid=0,
-                    args=dict(name="repro"))]
-        with self._lock:
-            lanes = sorted(self._lanes.items(), key=lambda kv: kv[1])
-            events = list(self.events)
-        for lane, tid in lanes:
-            out.append(dict(ph="M", name="thread_name", pid=0, tid=tid,
-                            args=dict(name=str(lane))))
-        for e in events:
-            out.append(dict(
-                name=e["name"], ph="X", pid=0, tid=e["tid"],
-                ts=e["ts"], dur=e["dur"],
-                cat=str(e["attrs"].get("stage", "span")),
-                args=dict(e["attrs"], depth=e["depth"], parent=e["parent"]),
-            ))
-        with open(path, "w") as f:
-            json.dump({"traceEvents": out, "displayTimeUnit": "ms"}, f, indent=1)
-
-    def export_jsonl(self, path: str) -> None:
-        """Append-only JSONL event log: one finished span per line."""
-        with self._lock:
-            events = list(self.events)
-        with open(path, "a") as f:
-            for e in events:
-                f.write(json.dumps(e) + "\n")
-
-    # -- introspection (tests) ----------------------------------------------
-
-    def span_names(self) -> list[str]:
-        with self._lock:
-            return [e["name"] for e in self.events]
-
-    def spans(self, name: str | None = None) -> list[dict]:
-        with self._lock:
-            return [e for e in self.events if name is None or e["name"] == name]
-
-
-class trace_span:
-    """Context manager AND decorator opening a span on the global tracer.
-
-    ``lane`` picks the timeline row (default: inherit the enclosing
-    span's row, else ``"main"``); ``sync`` overrides the tracer's
-    block-until-ready default for this span; remaining keywords become
-    span attributes (``stage=`` doubles as the Chrome-trace category).
-
-    Disabled fast path: one attribute-store construction, a single
-    global ``is None`` check, and the shared no-op span — decorated
-    functions are called directly, so outputs are bit-identical.
-    """
-
-    __slots__ = ("name", "lane", "_sync", "attrs", "_span")
-
-    def __init__(self, name: str, *, lane: str | None = None,
-                 sync: bool | None = None, **attrs):
-        self.name = name
-        self.lane = lane
-        self._sync = sync
-        self.attrs = attrs
-        self._span = None
-
-    def __enter__(self):
-        tr = _TRACER
-        if tr is None:
-            return _NOOP_SPAN
-        self._span = tr.begin(self.name, self.lane, dict(self.attrs), self._sync)
-        return self._span
-
-    def __exit__(self, exc_type, exc, tb):
-        sp = self._span
-        if sp is not None:
-            self._span = None
-            sp.tracer.end(sp)
-        return False
-
-    def __call__(self, fn):
-        name, lane, sync, attrs = self.name, self.lane, self._sync, self.attrs
-
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            if _TRACER is None:
-                return fn(*args, **kwargs)
-            with trace_span(name, lane=lane, sync=sync, **attrs) as sp:
-                return sp.sync(fn(*args, **kwargs))
-
-        return wrapped
-
-
-def enable_tracing(*, sync: bool = False) -> Tracer:
-    """Install a fresh global tracer and return it."""
-    global _TRACER
-    _TRACER = Tracer(sync=sync)
-    return _TRACER
-
-
-def disable_tracing() -> None:
-    """Drop the global tracer; trace_span reverts to the no-op fast path."""
-    global _TRACER
-    _TRACER = None
-
-
-def get_tracer() -> Tracer | None:
-    return _TRACER
-
-
-def tracing_enabled() -> bool:
-    return _TRACER is not None
+@contextlib.contextmanager
+def profile(logdir: str | None):
+    """Profile the block into ``logdir`` (an ``.xplane.pb`` and a
+    ``perfetto_trace.json.gz`` for https://ui.perfetto.dev), with the
+    collector's passes as ``py.gc`` spans; does nothing if ``logdir`` is
+    empty.  The profiler's per-call Python tracer stays off: the program's
+    own spans say what the host was doing."""
+    if not logdir:
+        yield
+        return
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(logdir, create_perfetto_trace=True, profiler_options=opts), gc_spans():
+        yield

@@ -45,10 +45,12 @@ from ..core import stages
 from ..core.fusion import (
     _FUSED_FP_BACKENDS,
     _FUSED_TO_MULTIGRAPH,
+    _MULTIGRAPH_BACKENDS,
     FusedFPInputs,
     NABackend,
     SemanticGraphBatch,
     batch_semantic_graph,
+    build_unit_tables,
     neighbor_aggregate_multi,
 )
 from ..core.reuse import FPTraffic, fp_buffer_traffic
@@ -64,13 +66,23 @@ from .fp_cache import FPCache
 @dataclasses.dataclass
 class GraphRequest:
     """A vertex-type-tagged subgraph query: run the given metapaths (all
-    endpoints = the engine's target type) and return the fused embedding."""
+    endpoints = the engine's target type) and return the fused embedding.
+
+    ``*_step`` are engine step numbers; ``*_at`` are ``time.perf_counter()``
+    readings taken at ``submit``, at admission into a slot and when the
+    step that ran its last metapath had dispatched it (the result may
+    still be in flight on the device).  ``admitted_at - submitted_at`` is
+    the request's wait in the admission queue.
+    """
 
     rid: int
     metapaths: list[tuple[str, ...]]
     submitted_step: int = -1
     admitted_step: int = -1
     finished_step: int = -1
+    submitted_at: float | None = None
+    admitted_at: float | None = None
+    finished_at: float | None = None
     result: jnp.ndarray | None = None   # [N_target, H*Dh] on finish
     beta: jnp.ndarray | None = None     # [G] semantic attention on finish
     _progress: int = 0
@@ -149,10 +161,14 @@ class HGNNEngine:
         # Observability (DESIGN.md §12).  Each engine owns a private
         # registry by default so two engines in one process (e.g. the
         # --compare ablation) never mix series; pass a shared registry to
-        # aggregate.  ``_executed`` records, per step, the stable-unique
-        # tuple of vertex types projected through the cache — the input
-        # the analytical FP-traffic model replays in ``fp_model_drift``.
+        # aggregate.  Counters tick where their events happen; the gauges
+        # are computed by ``metrics()``, which the registry also runs when
+        # it is exported, never inside ``step()``.  ``_executed`` records,
+        # per step, the stable-unique tuple of vertex types projected
+        # through the cache — the input the analytical FP-traffic model
+        # replays in ``fp_model_drift``.
         self.registry = registry if registry is not None else MetricsRegistry()
+        self.registry.add_collector(self.metrics)
         self._executed: list[tuple[str, ...]] = []
         for k in sorted(self._COUNTER_KEYS):  # series exist from step zero
             self.registry.counter(f"serve.{k}")
@@ -207,6 +223,7 @@ class HGNNEngine:
             for t in mp:
                 assert t in self.graph.vertex_counts, t
         req.submitted_step = self.steps_run
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
 
     def _admission_order(self) -> list[int]:
@@ -245,6 +262,7 @@ class HGNNEngine:
                 if self.slots[s] is None and self.queue:
                     req = self.queue.pop(0)
                     req.admitted_step = self.steps_run
+                    req.admitted_at = time.perf_counter()
                     self.slots[s] = req
         # refresh eviction demand: FP types still wanted by waiting +
         # in-flight work (similarity-weighted policy only reads this)
@@ -267,39 +285,52 @@ class HGNNEngine:
         neither projected nor admitted — the fused path projects the
         target type inside the NA launch instead."""
         tables: dict[str, jnp.ndarray] = {}
-        with trace_span("serve/fp", stage="FP", step=self.steps_run) as sp:
+        with trace_span("serve.fp"):
             for _, req in active:
                 mp = req.metapaths[req._progress]
                 for t in dict.fromkeys(mp):
                     self.fp_rows_naive += self.graph.num_vertices(t)
                     if t not in tables and t not in skip:
-                        tables[t] = sp.sync(
-                            self.cache.project(
-                                t,
-                                self.features[t],
-                                self.params["w_fp"][t],
-                                self.params["b_fp"][t],
-                            )
+                        tables[t] = self.cache.project(
+                            t,
+                            self.features[t],
+                            self.params["w_fp"][t],
+                            self.params["b_fp"][t],
                         )
-            sp.annotate(types=list(tables))
         self._executed.append(tuple(tables))
         return tables
 
     def step(self) -> int:
         """One engine step: admit, then execute one semantic graph per
-        occupied slot (single fused NA launch).  Returns #active slots."""
-        self._admit()
-        active = [(s, r) for s, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return 0
-        t0 = time.perf_counter()
-        with trace_span("serve/step", step=self.steps_run, slots=len(active)):
+        occupied slot (single fused NA launch).  Returns #active slots.
+
+        Host spans (``obs.trace``; recorded only under a profiler
+        session): ``serve.step`` around the whole call, with
+        ``serve.admit``, ``serve.fp``, ``serve.theta``,
+        ``serve.unit_tables``, ``serve.na`` and ``serve.fuse`` inside it.
+        """
+        with trace_span("serve.step", step=self.steps_run) as span:
+            with trace_span("serve.admit"):
+                self._admit()
+            active = [(s, r) for s, r in enumerate(self.slots) if r is not None]
+            if not active:
+                return 0
+            span.set_metadata(
+                slots=len(active), rids="/".join(str(r.rid) for _, r in active)
+            )
+            t0 = time.perf_counter()
             self._step_body(active)
-        self.registry.histogram("serve.step_ms").observe(
-            (time.perf_counter() - t0) * 1e3
-        )
-        self._sync_registry()
+            self.registry.histogram("serve.step_ms").observe(
+                (time.perf_counter() - t0) * 1e3
+            )
         return len(active)
+
+    def _unit_tables(self, backend: NABackend, batches: list[SemanticGraphBatch]):
+        """The step's work-unit tables, for the backends that take them."""
+        if backend not in _MULTIGRAPH_BACKENDS + _FUSED_FP_BACKENDS:
+            return None
+        with trace_span("serve.unit_tables"):
+            return build_unit_tables(batches)
 
     def _step_body(self, active: list[tuple[int, GraphRequest]]) -> None:
         # Bound-aware dispatch for the fused-FP backend: if the cache
@@ -315,7 +346,6 @@ class HGNNEngine:
             self.fused_cache_bypasses += 1
             self.registry.counter("serve.fused_cache_bypasses").inc()
 
-        graph_names = ["/".join(r.metapaths[r._progress]) for _, r in active]
         if fused:
             self._fp_tables(active, skip={self.target_type})
             batches, a_s, a_d = [], [], []
@@ -332,14 +362,11 @@ class HGNNEngine:
                 jnp.stack(a_s),
                 jnp.stack(a_d),
             )
-            with trace_span(
-                "serve/na", stage="NA", backend=backend.value,
-                graphs=len(active), graph_names=graph_names, fused_fp=True,
-            ) as sp:
-                z_all = sp.sync(
-                    neighbor_aggregate_multi(
-                        batches, None, None, None, backend=backend, fp=fp
-                    )
+            unit_tables = self._unit_tables(backend, batches)
+            with trace_span("serve.na"):
+                z_all = neighbor_aggregate_multi(
+                    batches, None, None, None, backend=backend,
+                    unit_tables=unit_tables, fp=fp,
                 )  # [G_active, N, H, Dh]
             self.fused_steps += 1
             self.registry.counter("serve.fused_steps").inc()
@@ -348,48 +375,40 @@ class HGNNEngine:
             hh = tables[self.target_type].reshape(self.n_target, self.heads, self.hidden)
 
             batches, th_s, th_d = [], [], []
-            with trace_span("serve/theta", stage="theta", graphs=len(active)) as sp:
+            with trace_span("serve.theta"):
                 for _, req in active:
                     mp = req.metapaths[req._progress]
                     a_src, a_dst = self._metapath_params(mp)
                     ts, td = stages.attention_coefficients(hh, a_src, a_dst)
                     batches.append(self._batch(mp))
-                    th_s.append(sp.sync(ts))
-                    th_d.append(sp.sync(td))
-            with trace_span(
-                "serve/na", stage="NA", backend=backend.value,
-                graphs=len(active), graph_names=graph_names,
-            ) as sp:
-                z_all = sp.sync(
-                    neighbor_aggregate_multi(
-                        batches, jnp.stack(th_s), jnp.stack(th_d), hh, backend=backend
-                    )
+                    th_s.append(ts)
+                    th_d.append(td)
+            unit_tables = self._unit_tables(backend, batches)
+            with trace_span("serve.na"):
+                z_all = neighbor_aggregate_multi(
+                    batches, jnp.stack(th_s), jnp.stack(th_d), hh, backend=backend,
+                    unit_tables=unit_tables,
                 )  # [G_active, N, H, Dh]
         self.na_launches += 1
         self.registry.counter("serve.na_launches").inc()
 
         valid = jnp.ones((self.n_target,), bool)
-        for i, (s, req) in enumerate(active):
-            with trace_span(
-                f"serve/fa/slot{s}", stage="FA", lane=f"slot{s}",
-                rid=req.rid, graph=graph_names[i],
-            ) as sp:
+        with trace_span("serve.fuse"):
+            for i, (s, req) in enumerate(active):
                 z = jax.nn.elu(z_all[i].reshape(self.n_target, -1))
-                w_p = sp.sync(
-                    stages.local_semantic_fusion(
-                        z, self.params["w_g"], self.params["b_g"], self.params["q"], valid
-                    )
+                w_p = stages.local_semantic_fusion(
+                    z, self.params["w_g"], self.params["b_g"], self.params["q"], valid
                 )
                 req._z.append(z)
                 req._w.append(w_p)
                 req._progress += 1
                 if req.done:
-                    fused_z, beta = stages.global_semantic_fusion(
+                    req.result, req.beta = stages.global_semantic_fusion(
                         jnp.stack(req._w), jnp.stack(req._z)
                     )
-                    req.result, req.beta = sp.sync(fused_z), beta
                     req._z, req._w = [], []
                     req.finished_step = self.steps_run
+                    req.finished_at = time.perf_counter()
                     self.finished.append(req)
                     self.slots[s] = None
                     self.registry.counter("serve.requests_finished").inc()
@@ -457,14 +476,11 @@ class HGNNEngine:
          "fused_cache_bypasses")
     )
 
-    def _sync_registry(self) -> None:
-        for k, v in self.metrics().items():
-            if k not in self._COUNTER_KEYS:
-                self.registry.gauge(f"serve.{k}").set(float(v))
-
     def metrics(self) -> dict:
+        """Every engine number, the FP-model drift replay included; sets
+        the registry's gauges to the non-counter ones."""
         st = self.cache.stats
-        return dict(
+        out = dict(
             steps=self.steps_run,
             na_launches=self.na_launches,
             requests_finished=len(self.finished),
@@ -486,6 +502,10 @@ class HGNNEngine:
             cache_capacity_bytes=self.cache.capacity_bytes,
             **self.fp_model_drift(),
         )
+        for k, v in out.items():
+            if k not in self._COUNTER_KEYS:
+                self.registry.gauge(f"serve.{k}").set(float(v))
+        return out
 
 
 def make_request_mix(

@@ -90,27 +90,31 @@ def make_hgnn_train_step(
     ``forward_fn(params) -> logits [N_target, C]`` runs the full-graph
     forward; ``batch["idx"]`` selects the step's labeled minibatch.
     Metrics carry ``loss``/``grad_norm`` (the train_loop contract) plus
-    minibatch accuracy.
+    minibatch accuracy.  The loss runs in the ``head`` scope and the clip
+    and AdamW update in ``optimizer`` (the model names its own stages), so
+    a profile can put each device op of the step down to its stage.
     """
     assert data.labels is not None, "training needs labels in HGNNData"
     sched = lr_schedule or (lambda s: jnp.asarray(opt_cfg.lr))
 
     def loss_fn(params, idx):
         logits = forward_fn(params)
-        lp = jax.nn.log_softmax(logits[idx].astype(jnp.float32), axis=-1)
-        y = data.labels[idx]
-        loss = -jnp.take_along_axis(lp, y[:, None], axis=-1)[:, 0].mean()
-        acc = (jnp.argmax(lp, axis=-1) == y).mean()
+        with jax.named_scope("head"):
+            lp = jax.nn.log_softmax(logits[idx].astype(jnp.float32), axis=-1)
+            y = data.labels[idx]
+            loss = -jnp.take_along_axis(lp, y[:, None], axis=-1)[:, 0].mean()
+            acc = (jnp.argmax(lp, axis=-1) == y).mean()
         return loss, {"loss": loss, "acc": acc}
 
     def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch["idx"]
         )
-        lr = sched(state.step)
-        new_params, new_opt, gnorm = apply_updates(
-            state.params, grads, state.opt, opt_cfg, lr
-        )
+        with jax.named_scope("optimizer"):
+            lr = sched(state.step)
+            new_params, new_opt, gnorm = apply_updates(
+                state.params, grads, state.opt, opt_cfg, lr
+            )
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         return TrainState(params=new_params, opt=new_opt, step=state.step + 1), metrics
 

@@ -20,7 +20,6 @@ from ..checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from ..data.pipeline import SyntheticLMData
 from ..obs.emit import Emitter
 from ..obs.metrics import MetricsRegistry, get_registry
-from ..obs.trace import trace_span
 from .step import TrainState
 
 
@@ -48,8 +47,9 @@ def train_loop(
     and emit a structured ``[train] step=… loss=… sec=…`` record through
     :class:`Emitter` (``log=`` stays the injectable sink).  Per-step
     ``sec`` on logged steps includes the device sync the host-side metric
-    conversion forces; between log points it is dispatch wall time —
-    enable tracing (sync spans) for honest per-step device timing.
+    conversion forces; between log points it is dispatch wall time.  Each
+    step's dispatch is a ``train`` span (meta ``step_num``) in a profiler
+    trace (``StepTraceAnnotation``, no sync), beside the device's ops.
     """
     reg = registry if registry is not None else get_registry()
     em = Emitter(sink=log, jsonl_path=log_jsonl)
@@ -78,9 +78,8 @@ def train_loop(
                 raise RuntimeError(f"injected failure at step {step}")
             t0 = time.perf_counter()
             batch = data.next()
-            with trace_span("train/step", step=step) as sp:
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
                 state, metrics = jitted(state, batch)
-                sp.sync(metrics)
             dt = time.perf_counter() - t0
             if step % log_every == 0 or step == steps - 1:
                 m = {k: float(np.asarray(v)) for k, v in metrics.items()}

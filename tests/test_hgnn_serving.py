@@ -1,5 +1,7 @@
 """HGNN serving engine + cross-request FP cache: lifecycle, capacity,
 coherence, admission-policy wins, and the reuse-model regression."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,6 +161,26 @@ def test_engine_request_lifecycle_and_slot_reuse(graph):
     assert m["requests_finished"] == 3 and m["requests_waiting"] == 0
     assert m["na_launches"] == 2  # one fused launch per non-empty step
     assert eng.traffic().total == m["reused_bytes"] + m["fetched_bytes"]
+
+
+def test_request_timestamps_and_queue_wait(graph):
+    eng = _engine(graph, cache_bytes=1 << 20, admission="fifo", num_slots=1)
+    r0 = GraphRequest(rid=0, metapaths=[MDM, MAM])  # holds the slot 2 steps
+    r1 = GraphRequest(rid=1, metapaths=[MKM])
+    eng.submit(r0)
+    eng.submit(r1)
+    assert r0.admitted_at is None and r1.submitted_at >= r0.submitted_at
+    eng.step()
+    after_first = time.perf_counter()
+    # a slot was free: r0 waited for no step, only for the first call
+    assert r0.admitted_step == r0.submitted_step == 0
+    assert r0.submitted_at <= r0.admitted_at <= after_first
+    assert r1.admitted_at is None and r0.finished_at is None
+    eng.run()
+    # r1 waited in the queue until r0 finished and freed the slot
+    assert r1.admitted_step == 2 and r1.admitted_at >= r0.finished_at
+    for r in (r0, r1):
+        assert r.submitted_at <= r.admitted_at <= r.finished_at
 
 
 def test_engine_rejects_non_target_endpoints(graph):

@@ -1,112 +1,74 @@
-"""Observability layer (obs/, DESIGN.md §12): tracer, metrics, emitter,
-benchmark stats, and the serving engine's registry wiring."""
+"""Observability layer (obs/, DESIGN.md §12): profiler spans, metrics,
+emitter, benchmark stats, and the serving engine's registry wiring."""
+import gc
+import glob
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.core.fusion import NABackend
 from repro.graphs import dataset_target, synthetic_hetgraph
-from repro.obs import (
-    Emitter,
-    MetricsRegistry,
-    disable_tracing,
-    enable_tracing,
-    get_tracer,
-    trace_span,
-    tracing_enabled,
-)
+from repro.obs import Emitter, MetricsRegistry, gc_spans, profile, trace_span
 from repro.serve.hgnn_engine import HGNNEngine, make_request_mix
 
-
-@pytest.fixture(autouse=True)
-def _clean_tracer():
-    disable_tracing()
-    yield
-    disable_tracing()
+SERVE_SPANS = ("serve.admit", "serve.fp", "serve.theta", "serve.unit_tables",
+               "serve.na", "serve.fuse")
 
 
-# -- tracer ------------------------------------------------------------------
+def host_spans(logdir) -> list[tuple[str, str, float, float]]:
+    """(line, name, start, end) of every host event in the profile."""
+    [path] = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.append((line.name, e.name, e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+# -- spans -------------------------------------------------------------------
 
 
 def test_disabled_tracer_is_noop_identity():
-    assert not tracing_enabled()
+    # no profiler session: spans record nothing and change nothing
     x = jnp.arange(6.0).reshape(2, 3)
 
     def f(a):
         return a * 2.0 + 1.0
 
-    traced_f = trace_span("t/f", stage="NA")(f)
-    with trace_span("t/outer", k=1) as sp:
-        y = sp.sync(f(x))
-        sp.annotate(extra=2)  # no-op span absorbs annotations
-    # bit-identical outputs through the decorator fast path
-    assert np.array_equal(np.asarray(traced_f(x)), np.asarray(f(x)))
+    with trace_span("t.outer", k=1) as sp:
+        y = f(x)
+        sp.set_metadata(extra=2)  # absorbed while nothing records
+    assert isinstance(sp, jax.profiler.TraceAnnotation)
     assert np.array_equal(np.asarray(y), np.asarray(f(x)))
-    assert get_tracer() is None
+    n_callbacks = len(gc.callbacks)
+    with profile(None):  # an empty --trace profiles nothing, hooks nothing
+        assert len(gc.callbacks) == n_callbacks
 
 
-def test_span_nesting_and_attributes_deterministic():
-    def program():
-        with trace_span("outer", stage="NA", lane="sg/APA", edges=7):
-            with trace_span("inner", stage="FP"):
-                pass
-            with trace_span("inner2", lane="slot0"):
-                pass
-
-    shapes = []
-    for _ in range(2):
-        tracer = enable_tracing()
-        program()
-        shapes.append(
-            [
-                (e["name"], e["depth"], e["parent"], e["lane"], e["attrs"])
-                for e in sorted(tracer.spans(), key=lambda e: e["name"])
-            ]
-        )
-        disable_tracing()
-    assert shapes[0] == shapes[1]  # structure independent of timing
-    by_name = {e[0]: e for e in shapes[0]}
-    assert by_name["outer"] == ("outer", 0, None, "sg/APA", {"stage": "NA", "edges": 7})
-    assert by_name["inner"][1:4] == (1, "outer", "sg/APA")  # lane inherited
-    assert by_name["inner2"][3] == "slot0"  # explicit lane wins
-
-
-def test_chrome_trace_export_valid(tmp_path):
-    tracer = enable_tracing()
-    with trace_span("na/APA", stage="NA", lane="sg/APA", edges=3):
-        pass
-    with trace_span("na/APCPA", stage="NA", lane="sg/APCPA"):
-        pass
-    path = tmp_path / "trace.json"
-    tracer.export_chrome_trace(str(path))
-    doc = json.loads(path.read_text())
-    events = doc["traceEvents"]
-    xs = [e for e in events if e["ph"] == "X"]
-    metas = [e for e in events if e["ph"] == "M"]
-    assert len(xs) == 2
-    for e in xs:
-        assert {"name", "ph", "pid", "tid", "ts", "dur", "cat", "args"} <= set(e)
-        assert e["dur"] >= 0 and e["cat"] == "NA"
-    # one thread_name row per lane, distinct tids per semantic graph
-    lanes = {e["args"]["name"]: e["tid"] for e in metas if e["name"] == "thread_name"}
-    assert set(lanes) == {"sg/APA", "sg/APCPA"}
-    assert len(set(lanes.values())) == 2
-    tids = {e["name"]: e["tid"] for e in xs}
-    assert tids["na/APA"] == lanes["sg/APA"]
-    assert tids["na/APCPA"] == lanes["sg/APCPA"]
-
-
-def test_jsonl_export(tmp_path):
-    tracer = enable_tracing()
-    with trace_span("a", stage="FP"):
-        pass
-    path = tmp_path / "spans.jsonl"
-    tracer.export_jsonl(str(path))
-    lines = [json.loads(ln) for ln in path.read_text().splitlines()]
-    assert [ln["name"] for ln in lines] == ["a"]
-    assert lines[0]["attrs"] == {"stage": "FP"}
+def test_profile_records_spans_meta_and_gc_passes(tmp_path):
+    n_callbacks = len(gc.callbacks)
+    with profile(str(tmp_path)):
+        with trace_span("t.step", step=3) as sp:
+            sp.set_metadata(rids="1/2")
+            gc.collect()
+    assert len(gc.callbacks) == n_callbacks  # the gc hook is gone again
+    spans = host_spans(tmp_path)
+    [(line, _, s0, s1)] = [sp for sp in spans if sp[1] == "t.step"]
+    gcs = [sp for sp in spans if sp[1] == "py.gc"]
+    assert gcs and any(s0 <= s and e <= s1 for _, _, s, e in gcs)
+    [path] = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    [meta] = [dict(e.stats) for p in ProfileData.from_file(path).planes
+              for ln in p.lines for e in ln.events if e.name == "t.step"]
+    assert meta == {"step": 3, "rids": "1/2"}
+    assert glob.glob(f"{tmp_path}/**/perfetto_trace.json.gz", recursive=True)
+    with gc_spans():  # outside a session the hook records nothing and raises nothing
+        gc.collect()
 
 
 # -- metrics -----------------------------------------------------------------
@@ -192,22 +154,50 @@ def test_engine_registry_matches_metrics():
     assert 0.0 < m["fp_model_drift"] <= 1.5
 
 
-def test_engine_spans_under_tracing():
+def test_engine_spans_under_tracing(tmp_path):
     g = synthetic_hetgraph("imdb", scale=0.05, feat_scale=0.02, seed=0)
     target, _ = dataset_target("imdb")
     eng = HGNNEngine(
-        g, target_type=target, num_slots=2, backend=NABackend.BLOCK,
+        g, target_type=target, num_slots=2, backend=NABackend.MULTIGRAPH_INTERPRET,
+        block=8, max_edges=2_000,
     )
-    for req in make_request_mix(0, [[("movie", "director", "movie")]], repeats=2):
+    for req in make_request_mix(0, [[("movie", "director", "movie")]], repeats=3):
         eng.submit(req)
-    tracer = enable_tracing(sync=True)
+    with profile(str(tmp_path)):
+        eng.run()
+    spans = host_spans(tmp_path)
+    steps = [(line, s, e) for line, n, s, e in spans if n == "serve.step"]
+    assert len(steps) == eng.steps_run == 2
+    for name in SERVE_SPANS:
+        mine = [(line, s, e) for line, n, s, e in spans if n == name]
+        assert len(mine) == eng.steps_run, name
+        # each sits inside a serve.step on the same thread
+        for line, s, e in mine:
+            assert any(sl == line and ss <= s and e <= se for sl, ss, se in steps), name
+
+
+def test_step_never_replays_the_fp_model(monkeypatch):
+    import repro.serve.hgnn_engine as engine_mod
+
+    calls = []
+    real = engine_mod.fp_buffer_traffic
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(engine_mod, "fp_buffer_traffic", counted)
+    g = synthetic_hetgraph("imdb", scale=0.05, feat_scale=0.02, seed=0)
+    eng = HGNNEngine(g, target_type="movie", num_slots=2, backend=NABackend.BLOCK)
+    for req in make_request_mix(0, [[("movie", "keyword", "movie")]], repeats=4):
+        eng.submit(req)
     eng.run()
-    names = set(tracer.span_names())
-    assert {"serve/step", "serve/fp", "serve/theta", "serve/na"} <= names
-    assert any(n.startswith("serve/fa/slot") for n in names)
-    # per-graph NA spans from the fallback loop ride their own sg/ lanes
-    na = [e for e in tracer.spans() if e["name"].startswith("na/")]
-    assert na and all(e["lane"].startswith("sg/") for e in na)
+    assert eng.steps_run == 2 and calls == []
+    m = eng.metrics()
+    assert len(calls) == 1
+    assert eng.registry.value("serve.fp_model_drift") == m["fp_model_drift"]
+    eng.registry.snapshot()  # an export computes the gauges too
+    assert len(calls) == 2
 
 
 # -- benchmark stats ---------------------------------------------------------
@@ -232,7 +222,7 @@ def test_run_py_duplicate_registration_fails():
     from benchmarks import run as bench_run
 
     benches = bench_run._registry()
-    assert "obs_overhead" in benches and len(benches) >= 12
+    assert "multilane" in benches and len(benches) >= 11
     # the registry guard itself
     ns: dict = {}
 
