@@ -45,17 +45,25 @@ class MultiLanePlan:
     num_graphs: int
     n_dst_blocks: int       # per graph (shared dst space)
     lane_plan: LanePlan | None  # host-side scheduling metadata (not traced)
+    # host-side: slots with col >= 0 per lane, of U * W each (not traced)
+    live_slots: np.ndarray | None = None
 
     @property
     def num_lanes(self) -> int:
         return int(self.col_index.shape[0])
 
+    def na_slots(self) -> dict:
+        """NA grid slots per lane and how many of them are live; the
+        kernel fetches nothing and runs no body for the rest."""
+        _, units, w = self.col_index.shape
+        return {"grid": int(units * w), "live": self.live_slots.tolist()}
+
 
 def _flatten_unflatten():
     arr = ("col_index", "masks", "graph_id", "dst_row", "valid")
-    # lane_plan holds host-side numpy arrays (scheduling metadata); it must
-    # NOT ride in the pytree aux (aux must be hashable) — reconstructed
-    # copies carry None there, which multilane_na never reads.
+    # lane_plan and live_slots hold host-side numpy arrays; they must NOT
+    # ride in the pytree aux (aux must be hashable) — reconstructed copies
+    # carry None there, which multilane_na never reads.
     meta = ("block", "num_graphs", "n_dst_blocks")
 
     def fl(p):
@@ -128,6 +136,7 @@ def build_multilane_plan(
         num_graphs=len(batches),
         n_dst_blocks=n_rows,
         lane_plan=plan,
+        live_slots=(col >= 0).sum(axis=(1, 2)),
     )
 
 

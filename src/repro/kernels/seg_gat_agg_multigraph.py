@@ -15,6 +15,16 @@ unit run inside one grid step.  Scratch (m, l, acc) carries across W
 log-sum-exp (lse = m + log l), the only residual the backward needs
 beyond the inputs.
 
+A dead slot (``col_index < 0``: the padding after a row's last block,
+or every slot of an all-padding unit) fetches nothing and runs no body,
+forward and backward.  The wrapper forward-fills the flattened (u, w)
+grid, so a dead slot's mask / theta_src / h_src index maps repeat those
+of the last live slot (slot 0 before any) and the Pallas pipeline issues
+no copy for the unchanged block index; the bodies run their per-head
+loops under ``pl.when(col >= 0)``.  A computed dead slot would add p = 0
+under scale 1 and zero gradients, so skipping it changes no bit of any
+result.
+
 The backward is itself one fused multigraph launch (the
 kernel-consolidation result of arXiv 2408.08490 applied to training):
 it *recomputes* the attention probabilities online from lse
@@ -68,6 +78,8 @@ def _fwd_kernel(
     gid_ref,    # int32 [U]
     row_ref,    # int32 [U]
     bias_ref,   # f32   [G, H]
+    fslot_ref,  # int32 [U*W]  filled slot (index maps only)
+    fcol_ref,   # int32 [U*W]  filled col  (index maps only)
     # inputs
     mask_ref,   # bool [1, 1, B, B]
     thd_ref,    # [1, B, H]  dst coefficients of the unit's graph
@@ -95,22 +107,24 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    live = jnp.logical_and(mask_ref[0, 0], col_ref[u, w] >= 0)
-    for hh in range(heads):
-        sl = slice(hh * head_dim, (hh + 1) * head_dim)
-        _, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
-        logits = jnp.where(live, logits, NEG_INF)
-        m_prev = m_ref[:, hh : hh + 1]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
-        scale = jnp.exp(m_prev - m_new)
-        p = jnp.where(live, jnp.exp(logits - m_new), 0.0)
-        l_ref[:, hh : hh + 1] = l_ref[:, hh : hh + 1] * scale + jnp.sum(
-            p, axis=1, keepdims=True
-        )
-        acc_ref[:, sl] = acc_ref[:, sl] * scale + jnp.dot(
-            p, hs_ref[:, sl].astype(jnp.float32), preferred_element_type=jnp.float32
-        )
-        m_ref[:, hh : hh + 1] = m_new
+    @pl.when(col_ref[u, w] >= 0)
+    def _body():
+        live = mask_ref[0, 0]
+        for hh in range(heads):
+            sl = slice(hh * head_dim, (hh + 1) * head_dim)
+            _, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
+            logits = jnp.where(live, logits, NEG_INF)
+            m_prev = m_ref[:, hh : hh + 1]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
+            scale = jnp.exp(m_prev - m_new)
+            p = jnp.where(live, jnp.exp(logits - m_new), 0.0)
+            l_ref[:, hh : hh + 1] = l_ref[:, hh : hh + 1] * scale + jnp.sum(
+                p, axis=1, keepdims=True
+            )
+            acc_ref[:, sl] = acc_ref[:, sl] * scale + jnp.dot(
+                p, hs_ref[:, sl].astype(jnp.float32), preferred_element_type=jnp.float32
+            )
+            m_ref[:, hh : hh + 1] = m_new
 
     @pl.when(w == nw - 1)
     def _finalize():
@@ -130,6 +144,8 @@ def _bwd_kernel(
     gid_ref,    # int32 [U]
     row_ref,    # int32 [U]
     bias_ref,   # f32   [G, H]
+    fslot_ref,  # int32 [U*W]  filled slot (index maps only)
+    fcol_ref,   # int32 [U*W]  filled col  (index maps only)
     # inputs
     mask_ref,   # bool [1, 1, B, B]
     thd_ref,    # [1, B, H]
@@ -157,53 +173,71 @@ def _bwd_kernel(
     def _init():
         dthd_acc_ref[...] = jnp.zeros_like(dthd_acc_ref)
 
-    live = jnp.logical_and(mask_ref[0, 0], col_ref[u, w] >= 0)  # [B(dst), B(src)]
-    for hh in range(heads):
-        sl = slice(hh * head_dim, (hh + 1) * head_dim)
-        pre, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
-        # recompute-p: attention probabilities from the lse residual
-        p = jnp.where(live, jnp.exp(logits - lse_ref[:, hh : hh + 1]), 0.0)
-        g_out = gout_ref[:, sl].astype(jnp.float32)  # [B, Dh]
-        hs = hs_ref[:, sl].astype(jnp.float32)       # [B, Dh]
-        dp = jax.lax.dot_general(                    # g_out @ hs.T  [Bd, Bs]
-            g_out, hs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dlogit = p * (dp - delta_ref[:, hh : hh + 1])  # softmax backward
-        dpre = jnp.where(pre >= 0, dlogit, leaky_slope * dlogit)
-        dths_ref[0, 0, hh : hh + 1, :] = jnp.sum(dpre, axis=0, keepdims=True)
-        dhs_ref[0, 0, :, sl] = jax.lax.dot_general(  # p.T @ g_out  [Bs, Dh]
-            p, g_out, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dthd_acc_ref[:, hh : hh + 1] += jnp.sum(dpre, axis=1, keepdims=True)
+    live_slot = col_ref[u, w] >= 0
+
+    @pl.when(live_slot)
+    def _body():
+        live = mask_ref[0, 0]  # [B(dst), B(src)]
+        for hh in range(heads):
+            sl = slice(hh * head_dim, (hh + 1) * head_dim)
+            pre, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
+            # recompute-p: attention probabilities from the lse residual
+            p = jnp.where(live, jnp.exp(logits - lse_ref[:, hh : hh + 1]), 0.0)
+            g_out = gout_ref[:, sl].astype(jnp.float32)  # [B, Dh]
+            hs = hs_ref[:, sl].astype(jnp.float32)       # [B, Dh]
+            dp = jax.lax.dot_general(                    # g_out @ hs.T  [Bd, Bs]
+                g_out, hs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dlogit = p * (dp - delta_ref[:, hh : hh + 1])  # softmax backward
+            dpre = jnp.where(pre >= 0, dlogit, leaky_slope * dlogit)
+            dths_ref[0, 0, hh : hh + 1, :] = jnp.sum(dpre, axis=0, keepdims=True)
+            dhs_ref[0, 0, :, sl] = jax.lax.dot_general(  # p.T @ g_out  [Bs, Dh]
+                p, g_out, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dthd_acc_ref[:, hh : hh + 1] += jnp.sum(dpre, axis=1, keepdims=True)
+
+    # a dead slot's partial blocks are still written back: zeros, not stale
+    @pl.when(jnp.logical_not(live_slot))
+    def _dead():
+        dths_ref[...] = jnp.zeros_like(dths_ref)
+        dhs_ref[...] = jnp.zeros_like(dhs_ref)
 
     @pl.when(w == nw - 1)
     def _finalize():
         dthd_ref[...] = dthd_acc_ref[...]
 
 
-def _common_maps():
-    def mask_map(u, w, col, gid, row, bias):
-        return (u, w, 0, 0)
+def _fill_dead(col_index):
+    """Forward-fill of the flattened (u, w) grid: each dead slot takes the
+    slot index and col of the most recent live slot (slot 0 before any),
+    so consecutive dead steps present unchanged input block indices."""
+    flat = col_index.reshape(-1)
+    slots = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    fslot = jnp.maximum(
+        jax.lax.cummax(jnp.where(flat >= 0, slots, -1), axis=0), 0
+    )
+    return fslot, jnp.maximum(flat[fslot], 0)
 
-    def thd_map(u, w, col, gid, row, bias):
+
+def _unit_map(u, w, col, gid, row, bias, fslot, fcol):
+    return (u, 0)
+
+
+def _in_specs(B, H, hdh, W):
+    def mask_map(u, w, col, gid, row, bias, fslot, fcol):
+        return (fslot[u * W + w], 0, 0, 0)
+
+    def thd_map(u, w, col, gid, row, bias, fslot, fcol):
         return (gid[u], row[u], 0)
 
-    def ths_map(u, w, col, gid, row, bias):
-        return (gid[u], 0, jnp.maximum(col[u, w], 0))
+    def ths_map(u, w, col, gid, row, bias, fslot, fcol):
+        return (gid[u], 0, fcol[u * W + w])
 
-    def hs_map(u, w, col, gid, row, bias):
-        return (jnp.maximum(col[u, w], 0), 0)
+    def hs_map(u, w, col, gid, row, bias, fslot, fcol):
+        return (fcol[u * W + w], 0)
 
-    def unit_map(u, w, col, gid, row, bias):
-        return (u, 0)
-
-    return mask_map, thd_map, ths_map, hs_map, unit_map
-
-
-def _in_specs(B, H, hdh):
-    mask_map, thd_map, ths_map, hs_map, _ = _common_maps()
     return [
-        pl.BlockSpec((1, 1, B, B), mask_map),
+        pl.BlockSpec((1, 1, B, B), mask_map),  # masks fed as [U*W, 1, B, B]
         pl.BlockSpec((1, B, H), thd_map),
         pl.BlockSpec((1, H, B), ths_map),
         pl.BlockSpec((B, hdh), hs_map),
@@ -217,15 +251,14 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
     G, ns_pad, H = theta_src.shape
     Dh = h_src.shape[-1]
     hdh = H * Dh
-    unit_map = _common_maps()[-1]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=6,
         grid=(U, W),
-        in_specs=_in_specs(B, H, hdh),
+        in_specs=_in_specs(B, H, hdh, W),
         out_specs=[
-            pl.BlockSpec((B, hdh), unit_map),
-            pl.BlockSpec((B, H), unit_map),
+            pl.BlockSpec((B, hdh), _unit_map),
+            pl.BlockSpec((B, H), _unit_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((B, hdh), jnp.float32),
@@ -247,8 +280,9 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
         ),
         interpret=interpret,
         name="seg_gat_agg_multigraph",
-    )(col_index, graph_id, dst_row, edge_bias, masks, theta_dst,
-      theta_src.swapaxes(1, 2), h_src.reshape(ns_pad, hdh))
+    )(col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index),
+      masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
+      h_src.reshape(ns_pad, hdh))
     return out.reshape(U * B, H, Dh), lse
 
 
@@ -259,23 +293,22 @@ def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
     G, ns_pad, H = theta_src.shape
     Dh = h_src.shape[-1]
     hdh = H * Dh
-    unit_map = _common_maps()[-1]
 
-    def slot_map(u, w, col, gid, row, bias):
+    def slot_map(u, w, col, gid, row, bias, fslot, fcol):
         return (u, w, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=6,
         grid=(U, W),
-        in_specs=_in_specs(B, H, hdh) + [
-            pl.BlockSpec((B, hdh), unit_map),
-            pl.BlockSpec((B, H), unit_map),
-            pl.BlockSpec((B, H), unit_map),
+        in_specs=_in_specs(B, H, hdh, W) + [
+            pl.BlockSpec((B, hdh), _unit_map),
+            pl.BlockSpec((B, H), _unit_map),
+            pl.BlockSpec((B, H), _unit_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, H, B), slot_map),
             pl.BlockSpec((1, 1, B, hdh), slot_map),
-            pl.BlockSpec((B, H), unit_map),
+            pl.BlockSpec((B, H), _unit_map),
         ],
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
     )
@@ -294,9 +327,9 @@ def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
         ),
         interpret=interpret,
         name="seg_gat_agg_multigraph_bwd",
-    )(col_index, graph_id, dst_row, edge_bias, masks, theta_dst,
-      theta_src.swapaxes(1, 2), h_src.reshape(ns_pad, hdh),
-      g_out.reshape(U * B, hdh), lse, delta)
+    )(col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index),
+      masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
+      h_src.reshape(ns_pad, hdh), g_out.reshape(U * B, hdh), lse, delta)
     return dths.swapaxes(2, 3), dhs.reshape(U, W, B, H, Dh), dthd
 
 
@@ -334,8 +367,9 @@ def _multigraph_bwd(leaky_slope, interpret, res, g):
     )
 
     # GSF-like scatter of the per-(unit, slot) partials onto the shared
-    # src vertex space.  Padding slots (col < 0) carry exact zeros (p=0),
-    # but mask them anyway so their block-0 landing spot stays clean.
+    # src vertex space.  Padding slots (col < 0) carry exact zeros (the
+    # kernel writes them), but mask them anyway so their block-0 landing
+    # spot stays clean.
     flat_col = col_index.reshape(U * W)
     live_blk = flat_col >= 0
     col_safe = jnp.maximum(flat_col, 0)

@@ -204,6 +204,8 @@ def run_training(
         plan_shards=None if plan is None else {
             str(s.device): list(s.data.shape) for s in plan.masks.addressable_shards
         },
+        # NA grid slots per lane, and how many are live (not padding)
+        na_slots=None if plan is None else plan.na_slots(),
         n_params=n_params, n_target=n_target,
     )
     return state, history, meta

@@ -12,7 +12,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.launch.hgnn_train import run_training
+from repro.core.multilane import build_multilane_plan
+from repro.launch.hgnn_train import build_problem, run_training
 
 _SILENT = lambda *_: None
 
@@ -35,6 +36,10 @@ def test_han_loss_decreases_lane_sharded_kernel():
     assert history[-1]["loss"] < history[0]["loss"]
     assert meta["plan_lanes"] == 2
     assert meta["backend"] == "kernel_interpret"
+    _, data = build_problem("acm", scale=_KW["scale"], block=_KW["block"],
+                            max_edges=_KW["max_edges"])
+    assert meta["na_slots"] == build_multilane_plan(data.graphs, 2).na_slots()
+    assert len(meta["na_slots"]["live"]) == 2
 
 
 def test_rgat_loss_decreases():

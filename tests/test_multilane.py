@@ -12,6 +12,8 @@ The property tests fuzz the plan builders and the multigraph VJP over
 random unit tables and degenerate shapes (empty graph, single edge,
 all-padded block) — degenerate rows must produce exact zeros, never NaN.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,15 +68,92 @@ def test_multilane_matches_reference_any_lane_count(dblp_setup, lanes):
         )
 
 
+def _with_dead_slots_computed(plan):
+    """The plan with every dead slot made a live slot of block 0 under an
+    all-false mask: the kernel then runs its full body there, as it did
+    on padding before dead slots were skipped."""
+    dead = np.asarray(plan.col_index) < 0
+    return dataclasses.replace(
+        plan,
+        col_index=jnp.where(dead, 0, plan.col_index),
+        masks=jnp.where(dead[..., None, None], False, plan.masks),
+    )
+
+
+def _na_and_vjp(plan, ths, thd, hs, bias, backend):
+    f = lambda *a: multilane_na(plan, *a[:3], edge_bias=a[3], backend=backend)
+    out, vjp = jax.vjp(f, ths, thd, hs, bias)
+    cot = jnp.asarray(
+        np.random.default_rng(1).standard_normal(out.shape).astype(np.float32)
+    )
+    return [np.asarray(out)] + [np.asarray(x) for x in vjp(cot)]
+
+
 @pytest.mark.parametrize("lanes", [1, 4])
 def test_multilane_kernel_backend_matches_reference(dblp_setup, lanes):
     """backend="kernel_interpret" (one fused Pallas launch for all lanes'
-    units) must match the vmap reference on the same plan."""
+    units) must match the vmap reference on the same plan.  The plans
+    hold a row of live slots followed by padding, units whose first slot
+    is padding, and (lanes=4) all-padding units of a short lane; dead
+    slots fetch nothing and run no body.  The forward equals the
+    reference bit for bit; the forward and the VJP (d_theta_src,
+    d_theta_dst, d_h_src, d_bias) equal bit for bit the kernel made to
+    compute every dead slot, and the VJP agrees with the reference's
+    autodiff to f32 tolerance."""
     batches, ths, thd, hs = dblp_setup
     plan = build_multilane_plan(batches, lanes)
-    ref = multilane_na(plan, ths, thd, hs)
-    ker = multilane_na(plan, ths, thd, hs, backend="kernel_interpret")
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
+    col, valid = np.asarray(plan.col_index), np.asarray(plan.valid)
+    assert ((col[..., 0] >= 0) & (col[..., -1] < 0)).any()  # live, then padding
+    assert (valid & (col[..., 0] < 0)).any()  # a unit whose first slot is padding
+    assert (~valid).any() == (lanes == 4)  # all-padding units of a short lane
+    bias = jnp.asarray(
+        np.random.default_rng(2).standard_normal((len(batches), ths.shape[-1]))
+        .astype(np.float32)
+    )
+
+    ker = _na_and_vjp(plan, ths, thd, hs, bias, "kernel_interpret")
+    computed = _na_and_vjp(
+        _with_dead_slots_computed(plan), ths, thd, hs, bias, "kernel_interpret"
+    )
+    ref = _na_and_vjp(plan, ths, thd, hs, bias, "reference")
+    np.testing.assert_array_equal(ker[0], ref[0])
+    for k, c in zip(ker, computed):
+        np.testing.assert_array_equal(k, c)
+    for k, r in zip(ker[1:], ref[1:]):
+        np.testing.assert_allclose(k, r, rtol=1e-5, atol=1e-5)
+
+
+def test_multigraph_bwd_dead_slot_partials_are_zero(dblp_setup):
+    """The backward writes exact zeros into the raw d_theta_src / d_h_src
+    partial blocks of every dead slot, none stale."""
+    from repro.kernels.seg_gat_agg_multigraph import _bwd_call, _fwd_call
+
+    batches, ths, thd, hs = dblp_setup
+    plan = build_multilane_plan(batches, 4)
+    lanes, units, w = plan.col_index.shape
+    col = plan.col_index.reshape(lanes * units, w)
+    args = (col, plan.graph_id.reshape(-1), plan.dst_row.reshape(-1),
+            plan.masks.reshape(lanes * units, w, plan.block, plan.block), ths, thd, hs,
+            jnp.zeros((len(batches), ths.shape[-1]), jnp.float32))
+    out, lse = _fwd_call(*args, 0.2, True)
+    g = jnp.ones_like(out)
+    delta = jnp.sum(g * out, axis=-1)
+    dths, dhs, _ = _bwd_call(*args, g, lse, delta, 0.2, True)
+    dead = np.asarray(col) < 0
+    assert np.abs(np.asarray(dths)[dead]).max() == 0.0
+    assert np.abs(np.asarray(dhs)[dead]).max() == 0.0
+    assert np.abs(np.asarray(dhs)[~dead]).max() > 0.0
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 4, 8])
+def test_plan_counts_live_slots_per_lane(dblp_setup, lanes):
+    batches, *_ = dblp_setup
+    plan = build_multilane_plan(batches, lanes)
+    col = np.asarray(plan.col_index)
+    assert plan.na_slots() == {
+        "grid": col.shape[1] * col.shape[2],
+        "live": [int((col[l] >= 0).sum()) for l in range(lanes)],
+    }
 
 
 def test_multilane_backend_rejects_unknown():
