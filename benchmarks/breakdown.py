@@ -20,6 +20,7 @@ from repro.graphs import (
     dataset_target,
     relation_semantic_graphs,
     synthetic_hetgraph,
+    union_graph,
 )
 from repro.models.hgnn import MODELS, prepare_data
 
@@ -27,7 +28,7 @@ from .common import timeit
 
 
 SCALE = 0.15
-HEADS = {"HAN": 8, "R-GAT": 4, "S-HGN": 4}
+HEADS = {"HAN": 8, "R-GAT": 4, "S-HGN": 8}
 
 
 def _stage_fns(name, model, params, data):
@@ -100,11 +101,10 @@ def _stage_fns(name, model, params, data):
 
         return fp, na, sf
 
-    # R-GAT / S-HGN: relation-wise GAT
     heads = HEADS[name]
-    lp = params["layers"][0]
+    if name == "R-GAT":  # relation-wise GAT
+        lp = params["layers"][0]
 
-    if name == "R-GAT":
         @jax.jit
         def fp():
             hs, hd = [], []
@@ -139,38 +139,34 @@ def _stage_fns(name, model, params, data):
 
         return fp, na, sf
 
-    # S-HGN
+    # S-HGN: the first hidden layer over the union graph (joint typed softmax)
+    u = data.graphs[0]
+
     @jax.jit
     def fp():
-        h = {t: feats[t] @ params["fp"][t] for t in feats}
-        return {t: (h[t] @ lp["w"]).reshape(h[t].shape[0], heads, -1) for t in h}
+        h = jnp.concatenate([feats[t] @ params[f"{t}.w_in"] + params[f"{t}.b_in"]
+                             for t in u.path_types])
+        return (h @ params["layer1.w"]).reshape(h.shape[0], heads, -1)
 
     hproj = fp()
 
     @jax.jit
     def na():
-        outs = []
-        for i, b in enumerate(data.graphs):
-            th_s, _ = stages.attention_coefficients(hproj[b.src_type], lp["a_src"], lp["a_dst"])
-            _, th_d = stages.attention_coefficients(hproj[b.dst_type], lp["a_src"], lp["a_dst"])
-            bias = lp["a_edge"] @ (lp["r_emb"][i] @ lp["w_r"])
-            z = stages.segment_softmax_aggregate(
-                b.src, b.dst, b.valid, th_s, th_d, hproj[b.src_type], b.num_dst,
-                edge_bias=bias,
-            )
-            outs.append(z.reshape(b.num_dst, -1))
-        return outs
+        th_s, th_d = stages.attention_coefficients(
+            hproj, params["layer1.attn_src"], params["layer1.attn_dst"])
+        r = (params["layer1.edge_emb"] @ params["layer1.w_edge"]).reshape(-1, heads, params["layer1.attn_edge"].shape[1])
+        bias = jnp.einsum("thk,hk->th", r, params["layer1.attn_edge"])
+        z = stages.segment_softmax_aggregate(
+            u.src, u.dst, u.valid, th_s, th_d, hproj, u.num_dst,
+            leaky_slope=0.05, edge_bias=bias[u.edge_type],
+        )
+        return z.reshape(u.num_dst, -1)
 
-    zs = na()
+    z = na()
 
     @jax.jit
     def sf():
-        out = {}
-        for t in feats:
-            zl = [zs[i] for i, b in enumerate(data.graphs) if b.dst_type == t]
-            if zl:
-                out[t] = jax.nn.elu(sum(zl))
-        return out
+        return jax.nn.elu(z)
 
     return fp, na, sf
 
@@ -181,10 +177,9 @@ def run(report):
         target, ncls = dataset_target(ds)
         mp = build_semantic_graphs(g, dataset_metapaths(ds), max_edges=60_000)
         rel = relation_semantic_graphs(g)
+        views = {"HAN": mp, "S-HGN": [union_graph(g)]}
         for name in ("HAN", "R-GCN", "R-GAT", "S-HGN"):
-            data = prepare_data(
-                g, mp if name == "HAN" else rel, target, ncls, with_blocks=False
-            )
+            data = prepare_data(g, views.get(name, rel), target, ncls, with_blocks=False)
             model = MODELS[name]
             params = model.init(jax.random.key(0), data)
             fp, na, sf = _stage_fns(name, model, params, data)

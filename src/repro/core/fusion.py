@@ -106,10 +106,14 @@ class SemanticGraphBatch:
     src: jnp.ndarray | None = None
     dst: jnp.ndarray | None = None
     valid: jnp.ndarray | None = None
-    # block CSR (BLOCK / MULTIGRAPH / FUSED_FP backends)
+    # block CSR (BLOCK / MULTIGRAPH / FUSED_FP backends); a typed graph's
+    # masks are int8 type tiles (graphs/formats.py)
     col_index: jnp.ndarray | None = None
     masks: jnp.ndarray | None = None
     block: int = 128
+    # typed graphs (the union view): each padded edge's type, and the names
+    edge_type: jnp.ndarray | None = None
+    edge_type_names: tuple[str, ...] = ()
 
     @property
     def num_dst_pad(self) -> int:
@@ -120,12 +124,13 @@ class SemanticGraphBatch:
     def row_edge_counts(self) -> np.ndarray:
         """#edges per dst-block row (workload units for lane scheduling)."""
         assert self.masks is not None
-        return np.asarray(self.masks.sum(axis=(1, 2, 3)), np.int64)
+        return np.asarray((self.masks != 0).sum(axis=(1, 2, 3)), np.int64)
 
 
-_SGB_ARRAY_FIELDS = ("src", "dst", "valid", "col_index", "masks")
+_SGB_ARRAY_FIELDS = ("src", "dst", "valid", "col_index", "masks", "edge_type")
 _SGB_META_FIELDS = (
     "name", "src_type", "dst_type", "num_src", "num_dst", "num_edges", "path_types", "block",
+    "edge_type_names",
 )
 
 
@@ -158,6 +163,8 @@ def batch_semantic_graph(
         kw.update(
             src=jnp.asarray(pe.src), dst=jnp.asarray(pe.dst), valid=jnp.asarray(pe.valid)
         )
+        if pe.edge_type is not None:
+            kw.update(edge_type=jnp.asarray(pe.edge_type))
     if with_blocks:
         bc = to_block_csr(sg, block=block)
         kw.update(col_index=jnp.asarray(bc.col_index), masks=jnp.asarray(bc.masks), block=block)
@@ -169,6 +176,7 @@ def batch_semantic_graph(
         num_dst=sg.num_dst,
         num_edges=sg.num_edges,
         path_types=sg.path_types,
+        edge_type_names=sg.edge_type_names,
         **kw,
     )
 

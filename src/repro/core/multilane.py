@@ -112,7 +112,7 @@ def build_multilane_plan(
     u_max = max(1, max(len(lu) for lu in lanes_units))
 
     col = np.full((num_lanes, u_max, w_max), -1, np.int32)
-    masks = np.zeros((num_lanes, u_max, w_max, b, b), bool)
+    masks = np.zeros((num_lanes, u_max, w_max, b, b), batches[0].masks.dtype)
     gid = np.zeros((num_lanes, u_max), np.int32)
     drow = np.zeros((num_lanes, u_max), np.int32)
     valid = np.zeros((num_lanes, u_max), bool)
@@ -277,10 +277,63 @@ def multilane_na(
         )  # [L*U*B, H, Dh]
         per_unit = flat.reshape(lanes, units, plan.block, h_dim, dh)
 
-    out = jnp.zeros((g_n, plan.n_dst_blocks, plan.block, h_dim, dh), out_dtype)
+    return _scatter_units(plan, per_unit, g_n, out_dtype)
+
+
+def _scatter_units(plan: MultiLanePlan, per_unit, g_n: int, dtype) -> jnp.ndarray:
+    """Per-unit outputs [L, U, B, H, Dh] -> [G, Nd_pad, H, Dh]: each valid
+    unit lands on its (graph, dst block row); the rows are disjoint."""
+    _, _, b, h_dim, dh = per_unit.shape
+    out = jnp.zeros((g_n, plan.n_dst_blocks, b, h_dim, dh), dtype)
     contrib = jnp.where(plan.valid[:, :, None, None, None], per_unit, 0.0)
     out = out.at[plan.graph_id, plan.dst_row].add(contrib)
-    return out.reshape(g_n, plan.n_dst_blocks * plan.block, h_dim, dh)
+    return out.reshape(g_n, plan.n_dst_blocks * b, h_dim, dh)
+
+
+def typed_na(
+    plan: MultiLanePlan,
+    theta_src: jnp.ndarray,  # [1, Ns_pad, H]
+    theta_dst: jnp.ndarray,  # [1, Nd_pad, H]
+    h_src: jnp.ndarray,      # [Ns_pad, H, Dh]
+    type_bias: jnp.ndarray,  # [T, H]
+    *,
+    attn_prev=None,
+    beta: float | None = None,
+    leaky_slope: float = 0.2,
+    backend: str = "kernel",
+):
+    """NA over a typed plan: one graph whose tiles hold edge types (int8,
+    type + 1, 0 = no edge), so each dst vertex's softmax runs jointly over
+    its in-edges of every type, each logit biased by ``type_bias[type]``.
+    One fused multigraph launch, forward and backward, on one lane shard.
+
+    ``attn_prev`` (a previous layer's ``Attention`` over the same plan)
+    with ``beta`` mixes that layer's attention in, rebuilt in-tile (the
+    attention residual; see kernels/seg_gat_agg_multigraph).  Returns
+    ``(z [Nd_pad, H, Dh], Attention)``: this layer's aggregate and what the
+    next layer rebuilds its attention from.  The kernel's dots run in
+    float32 (``HIGHEST``; Mosaic's default is one bfloat16 pass).
+    """
+    from repro.kernels.seg_gat_agg_multigraph import Attention, seg_gat_agg_multigraph
+
+    if backend not in ("kernel", "kernel_interpret"):
+        raise ValueError(f"typed NA runs the multigraph kernel, not backend={backend!r}")
+    require_tpu(backend)
+    lanes, units, w = plan.col_index.shape
+    flat, lse = seg_gat_agg_multigraph(
+        plan.col_index.reshape(lanes * units, w),
+        plan.graph_id.reshape(lanes * units),
+        plan.dst_row.reshape(lanes * units),
+        plan.masks.reshape(lanes * units, w, plan.block, plan.block),
+        theta_src, theta_dst, h_src, type_bias, attn_prev,
+        leaky_slope=leaky_slope, beta=beta, return_lse=True,
+        precision=jax.lax.Precision.HIGHEST, interpret=(backend == "kernel_interpret"),
+    )  # [L*U*B, H, Dh], [L*U*B, H]
+    h_dim, dh = h_src.shape[1:]
+    per_unit = flat.reshape(lanes, units, plan.block, h_dim, dh)
+    z = _scatter_units(plan, per_unit, 1, h_src.dtype)[0]
+    attn = Attention(theta_src, theta_dst, type_bias, lse)
+    return z, jax.tree_util.tree_map(jax.lax.stop_gradient, attn)
 
 
 def _plan_specs(plan: MultiLanePlan, lane_axes: tuple[str, ...]) -> MultiLanePlan:

@@ -1,4 +1,11 @@
-from .hetgraph import HetGraph, Relation, SemanticGraph, make_relation, relation_semantic_graphs
+from .hetgraph import (
+    HetGraph,
+    Relation,
+    SemanticGraph,
+    make_relation,
+    relation_semantic_graphs,
+    union_graph,
+)
 from .sgb import build_semantic_graph, build_semantic_graphs
 from .formats import (
     BlockCSR,
@@ -22,6 +29,7 @@ __all__ = [
     "SemanticGraph",
     "make_relation",
     "relation_semantic_graphs",
+    "union_graph",
     "build_semantic_graph",
     "build_semantic_graphs",
     "BlockCSR",

@@ -14,6 +14,9 @@ Two executable layouts for a SemanticGraph:
   *block-densified* so it runs as masked dense MXU/VPU work from VMEM tiles
   (see DESIGN.md §2).  The per-row block lists are what the fused
   online-softmax kernel (kernels/seg_gat_agg_multigraph.py) iterates over.
+  A graph whose edges carry types (the union view, ``union_graph``) gets
+  *typed tiles* in place of the boolean masks: int8, each pair holding its
+  edge type + 1, 0 for no edge — the same bytes.
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ class PaddedEdges:
     valid: np.ndarray  # bool [E_pad]
     num_src: int
     num_dst: int
+    edge_type: np.ndarray | None = None  # int32 [E_pad] (0 on padding)
 
     @property
     def num_edges(self) -> int:
@@ -58,7 +62,11 @@ def to_padded_edges(sg: SemanticGraph, *, pad_to: int | None = None) -> PaddedEd
     src = np.concatenate([src, np.zeros(pad, np.int32)])
     dst = np.concatenate([dst, np.full(pad, max(sg.num_dst - 1, 0), np.int32)])
     valid = np.concatenate([np.ones(e, bool), np.zeros(pad, bool)])
-    return PaddedEdges(src=src, dst=dst, valid=valid, num_src=sg.num_src, num_dst=sg.num_dst)
+    edge_type = None
+    if sg.edge_type is not None:
+        edge_type = np.concatenate([sg.edge_type[order], np.zeros(pad, np.int32)])
+    return PaddedEdges(src=src, dst=dst, valid=valid, num_src=sg.num_src,
+                       num_dst=sg.num_dst, edge_type=edge_type)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,14 +76,15 @@ class BlockCSR:
     ``col_index[i, j]`` is the src-block column of the j-th kept block in
     dst-block row i, or ``-1`` for padding (its mask slot is all-False).
     ``masks[i, j]`` is the dense B×B boolean adjacency of that block
-    (mask[p, q] == edge (src = col*B + q  ->  dst = row*B + p)).
+    (mask[p, q] == edge (src = col*B + q  ->  dst = row*B + p)); for a
+    typed graph it is the int8 type tile (edge type + 1, 0 = no edge).
     """
 
     block: int
     num_dst_pad: int
     num_src_pad: int
     col_index: np.ndarray  # int32 [n_dst_blocks, max_blocks_per_row]
-    masks: np.ndarray  # bool  [n_dst_blocks, max_blocks_per_row, B, B]
+    masks: np.ndarray  # bool / int8 [n_dst_blocks, max_blocks_per_row, B, B]
     num_edges: int
 
     @property
@@ -96,10 +105,14 @@ def to_block_csr(sg: SemanticGraph, *, block: int = 128, min_blocks_per_row: int
     nd_pad = _ceil_to(max(sg.num_dst, 1), b)
     ns_pad = _ceil_to(max(sg.num_src, 1), b)
     n_rows = nd_pad // b
+    typed = sg.edge_type is not None
+    if typed:
+        assert len(sg.edge_type_names) < 128, "int8 type tiles hold at most 127 types"
+    tile_dtype = np.int8 if typed else bool
 
     if sg.num_edges == 0:
         col_index = np.full((n_rows, min_blocks_per_row), -1, np.int32)
-        masks = np.zeros((n_rows, min_blocks_per_row, b, b), bool)
+        masks = np.zeros((n_rows, min_blocks_per_row, b, b), tile_dtype)
         return BlockCSR(b, nd_pad, ns_pad, col_index, masks, 0)
 
     row_blk = sg.dst_ids // b
@@ -113,7 +126,7 @@ def to_block_csr(sg: SemanticGraph, *, block: int = 128, min_blocks_per_row: int
     width = max(int(blocks_per_row.max()), min_blocks_per_row)
 
     col_index = np.full((n_rows, width), -1, np.int32)
-    masks = np.zeros((n_rows, width, b, b), bool)
+    masks = np.zeros((n_rows, width, b, b), tile_dtype)
     slot_of_block = np.empty(uniq.shape[0], np.int32)
     cursor = np.zeros(n_rows, np.int32)
     for k in range(uniq.shape[0]):
@@ -122,15 +135,18 @@ def to_block_csr(sg: SemanticGraph, *, block: int = 128, min_blocks_per_row: int
         cursor[r] += 1
         col_index[r, s] = u_cols[k]
         slot_of_block[k] = s
-    # scatter edges into their block masks
-    masks[row_blk, slot_of_block[inv], sg.dst_ids % b, sg.src_ids % b] = True
+    # scatter edges into their block masks (typed: edge type + 1)
+    masks[row_blk, slot_of_block[inv], sg.dst_ids % b, sg.src_ids % b] = (
+        sg.edge_type + 1 if typed else True
+    )
     return BlockCSR(b, nd_pad, ns_pad, col_index, masks, sg.num_edges)
 
 
 def block_csr_to_dense(bc: BlockCSR) -> np.ndarray:
-    """Dense [num_dst_pad, num_src_pad] boolean adjacency (test oracle)."""
+    """Dense [num_dst_pad, num_src_pad] adjacency (test oracle): boolean,
+    or the type tiles' values for a typed graph."""
     b = bc.block
-    out = np.zeros((bc.num_dst_pad, bc.num_src_pad), bool)
+    out = np.zeros((bc.num_dst_pad, bc.num_src_pad), bc.masks.dtype)
     for r in range(bc.n_dst_blocks):
         for j in range(bc.max_blocks_per_row):
             c = bc.col_index[r, j]

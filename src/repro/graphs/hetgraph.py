@@ -79,7 +79,9 @@ class SemanticGraph:
     ``path_types`` records every vertex type visited along the metapath —
     that is what similarity-aware scheduling (core/scheduling.py) uses to
     estimate inter-semantic-graph FP reuse, mirroring the paper's hypergraph
-    whose edge weights come from shared vertex types.
+    whose edge weights come from shared vertex types.  The union view
+    (:func:`union_graph`) keeps there the vertex types in the order of its
+    one vertex table, and carries each edge's type id in ``edge_type``.
     """
 
     name: str
@@ -90,6 +92,8 @@ class SemanticGraph:
     num_src: int
     num_dst: int
     path_types: tuple[str, ...]
+    edge_type: np.ndarray | None = None  # int32 [E] ids into edge_type_names
+    edge_type_names: tuple[str, ...] = ()
 
     @property
     def num_edges(self) -> int:
@@ -126,3 +130,41 @@ def relation_semantic_graphs(g: HetGraph) -> list[SemanticGraph]:
             )
         )
     return out
+
+
+SELF_LOOP = "self"
+
+
+def union_graph(g: HetGraph) -> SemanticGraph:
+    """The union ("homogeneous") view of a HetG, the graph S-HGN attends over.
+
+    One vertex table holds every type in ``g.vertex_types`` order (a type's
+    vertices start at the sum of the counts before it).  Every relation's
+    edges keep their direction and take the relation's index in
+    ``g.edge_types`` as their edge type; a self-loop on every vertex is one
+    more type, the last.  A relation pair (v, v) is dropped: the vertex's
+    self-loop takes its place, so each (src, dst) pair holds one type.
+    """
+    types = tuple(g.vertex_types)
+    offsets = dict(zip(types, np.cumsum([0] + [g.num_vertices(t) for t in types[:-1]])))
+    n = sum(g.num_vertices(t) for t in types)
+    src, dst, et = [], [], []
+    for i, rel in enumerate(g.relations.values()):
+        keep = (rel.src_ids != rel.dst_ids) | (rel.src_type != rel.dst_type)
+        src.append(rel.src_ids[keep] + offsets[rel.src_type])
+        dst.append(rel.dst_ids[keep] + offsets[rel.dst_type])
+        et.append(np.full(int(keep.sum()), i, np.int32))
+    loops = np.arange(n, dtype=np.int32)
+    names = tuple(g.edge_types) + (SELF_LOOP,)
+    return SemanticGraph(
+        name="union",
+        src_type="*",
+        dst_type="*",
+        src_ids=np.concatenate(src + [loops]).astype(np.int32),
+        dst_ids=np.concatenate(dst + [loops]).astype(np.int32),
+        num_src=n,
+        num_dst=n,
+        path_types=types,
+        edge_type=np.concatenate(et + [np.full(n, len(names) - 1, np.int32)]),
+        edge_type_names=names,
+    )

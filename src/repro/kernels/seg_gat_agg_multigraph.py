@@ -41,10 +41,27 @@ probability tensor is ever materialized) and produces
 ``seg_gat_agg_multigraph`` carries a ``jax.custom_vjp``, so HAN training
 consolidates all relations of a step into a single forward and a single
 backward launch.
+
+Two static options serve S-HGN's layers over the union graph; with both
+off the launch is the one HAN's relations run through:
+
+* typed tiles — ``masks`` holds int8 edge types (type + 1, 0 = no edge)
+  in place of booleans, and ``edge_bias`` is a [T, H] table looked up per
+  pair from the tile, in place of one bias per graph.  One unit's slots
+  span every type, so the softmax over a dst vertex's in-edges is joint
+  over types.  The backward sums the table's gradient per type in VMEM.
+* the attention residual — ``attn_prev`` (an :class:`Attention`: a
+  previous layer's coefficients, bias table and per-row lse, all per
+  vertex or per type) with a static ``beta``.  The previous layer's
+  probabilities are rebuilt in-tile, p_prev = exp(logits_prev - lse_prev),
+  so no per-edge state is stored, and the output is the aggregate of the
+  mixed attention, (1 - beta) sum p h + beta sum p_prev h.  p_prev takes no
+  gradient (its operands get zeros); h_src's gradient counts both parts.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +79,42 @@ NEG_INF = -1e30
 # [H, B] rows; h_src / outputs are fed flat as [N, H*Dh].
 
 
+class Attention(NamedTuple):
+    """What a layer's attention is rebuilt from in-tile: its coefficients,
+    its [T, H] bias table and the per-row log-sum-exp its forward returns
+    (units in plan order, so the next layer must run the same plan)."""
+
+    theta_src: jnp.ndarray  # [G, Ns_pad, H]
+    theta_dst: jnp.ndarray  # [G, Nd_pad, H]
+    bias: jnp.ndarray       # f32 [T, H]
+    lse: jnp.ndarray        # f32 [U*B, H]
+
+
+class _Opts(NamedTuple):
+    leaky_slope: float
+    interpret: bool
+    beta: float | None  # attention-residual weight; None = no residual
+    precision: jax.lax.Precision | None = None  # of the bodies' dots; None: Mosaic's default
+
+
+def _tile(mask_ref, n_types):
+    """(live [B, B], type tile or None) of the slot's mask block."""
+    if not n_types:
+        return mask_ref[0, 0], None
+    tile = mask_ref[0, 0].astype(jnp.int32)
+    return tile != 0, tile
+
+
+def _bias(bias_ref, gid, hh, tile, n_types):
+    """Head hh's logit bias: the graph's scalar, or per pair from the tile."""
+    if not n_types:
+        return bias_ref[gid, hh]
+    bias = jnp.zeros(tile.shape, jnp.float32)
+    for t in range(n_types):
+        bias = jnp.where(tile == t + 1, bias_ref[t, hh], bias)
+    return bias
+
+
 def _logits(thd_ref, ths_ref, bias, hh, leaky_slope):
     """Pre-activation and LeakyReLU logits [B(dst), B(src)] of head hh."""
     pre = (
@@ -77,26 +130,32 @@ def _fwd_kernel(
     col_ref,    # int32 [U, W]
     gid_ref,    # int32 [U]
     row_ref,    # int32 [U]
-    bias_ref,   # f32   [G, H]
+    bias_ref,   # f32   [G, H], or [T, H] with typed tiles
     fslot_ref,  # int32 [U*W]  filled slot (index maps only)
     fcol_ref,   # int32 [U*W]  filled col  (index maps only)
-    # inputs
-    mask_ref,   # bool [1, 1, B, B]
-    thd_ref,    # [1, B, H]  dst coefficients of the unit's graph
-    ths_ref,    # [1, H, B]  src coefficients of the slot's block
-    hs_ref,     # [B, H*Dh]  shared source features
-    # outputs
-    out_ref,    # [B, H*Dh]
-    lse_ref,    # f32 [B, H]
-    # scratch
-    acc_ref,    # f32 [B, H*Dh]
-    m_ref,      # f32 [B, H]
-    l_ref,      # f32 [B, H]
-    *,
+    *refs,
     heads: int,
     head_dim: int,
     leaky_slope: float,
+    n_types: int,
+    beta: float | None,
+    precision: jax.lax.Precision | None,
 ):
+    residual = beta is not None
+    it = iter(refs)
+    pbias_ref = next(it) if residual else None  # scalar prefetch: f32 [T, H]
+    # inputs: mask bool/int8 [1, 1, B, B]; thd [1, B, H] dst coefficients of
+    # the unit's graph; ths [1, H, B] src coefficients of the slot's block;
+    # hs [B, H*Dh] shared source features
+    mask_ref, thd_ref, ths_ref, hs_ref = (next(it) for _ in range(4))
+    if residual:  # the previous layer's thd, ths and lse [B, H]
+        pthd_ref, pths_ref, plse_ref = (next(it) for _ in range(3))
+    out_ref = next(it)                          # [B, H*Dh]
+    cur_ref = next(it) if residual else None    # f32 [B, H*Dh] unmixed output
+    lse_ref = next(it)                          # f32 [B, H]
+    acc_ref, m_ref, l_ref = (next(it) for _ in range(3))  # scratch
+    pacc_ref = next(it) if residual else None   # f32 [B, H*Dh]
+
     u = pl.program_id(0)
     w = pl.program_id(1)
     nw = pl.num_programs(1)
@@ -106,13 +165,17 @@ def _fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if residual:
+            pacc_ref[...] = jnp.zeros_like(pacc_ref)
 
     @pl.when(col_ref[u, w] >= 0)
     def _body():
-        live = mask_ref[0, 0]
+        live, tile = _tile(mask_ref, n_types)
         for hh in range(heads):
             sl = slice(hh * head_dim, (hh + 1) * head_dim)
-            _, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
+            _, logits = _logits(
+                thd_ref, ths_ref, _bias(bias_ref, gid_ref[u], hh, tile, n_types), hh, leaky_slope
+            )
             logits = jnp.where(live, logits, NEG_INF)
             m_prev = m_ref[:, hh : hh + 1]
             m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
@@ -122,17 +185,30 @@ def _fwd_kernel(
                 p, axis=1, keepdims=True
             )
             acc_ref[:, sl] = acc_ref[:, sl] * scale + jnp.dot(
-                p, hs_ref[:, sl].astype(jnp.float32), preferred_element_type=jnp.float32
+                p, hs_ref[:, sl].astype(jnp.float32), preferred_element_type=jnp.float32,
+                precision=precision,
             )
             m_ref[:, hh : hh + 1] = m_new
+            if residual:  # the previous layer's probabilities, exact from its lse
+                _, plogits = _logits(
+                    pthd_ref, pths_ref, _bias(pbias_ref, gid_ref[u], hh, tile, n_types),
+                    hh, leaky_slope,
+                )
+                pp = jnp.where(live, jnp.exp(plogits - plse_ref[:, hh : hh + 1]), 0.0)
+                pacc_ref[:, sl] += jnp.dot(
+                    pp, hs_ref[:, sl].astype(jnp.float32), preferred_element_type=jnp.float32,
+                    precision=precision,
+                )
 
     @pl.when(w == nw - 1)
     def _finalize():
         for hh in range(heads):
             sl = slice(hh * head_dim, (hh + 1) * head_dim)
-            out_ref[:, sl] = (
-                acc_ref[:, sl] / jnp.maximum(l_ref[:, hh : hh + 1], 1e-9)
-            ).astype(out_ref.dtype)
+            cur = acc_ref[:, sl] / jnp.maximum(l_ref[:, hh : hh + 1], 1e-9)
+            if residual:
+                cur_ref[:, sl] = cur
+                cur = (1.0 - beta) * cur + beta * pacc_ref[:, sl]
+            out_ref[:, sl] = cur.astype(out_ref.dtype)
         # lse of a fully-masked row degenerates to ~NEG_INF; the backward
         # masks those positions with `live` before any use.
         lse_ref[...] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
@@ -143,28 +219,36 @@ def _bwd_kernel(
     col_ref,    # int32 [U, W]
     gid_ref,    # int32 [U]
     row_ref,    # int32 [U]
-    bias_ref,   # f32   [G, H]
+    bias_ref,   # f32   [G, H], or [T, H] with typed tiles
     fslot_ref,  # int32 [U*W]  filled slot (index maps only)
     fcol_ref,   # int32 [U*W]  filled col  (index maps only)
-    # inputs
-    mask_ref,   # bool [1, 1, B, B]
-    thd_ref,    # [1, B, H]
-    ths_ref,    # [1, H, B]
-    hs_ref,     # [B, H*Dh]
-    gout_ref,   # [B, H*Dh]  cotangent of the per-unit output
-    lse_ref,    # f32 [B, H]  forward log-sum-exp residual
-    delta_ref,  # f32 [B, H]  sum_f g_out * out (flash-attention delta)
-    # outputs
-    dths_ref,   # f32 [1, 1, H, B]     per-(unit, slot) src-coeff partial
-    dhs_ref,    # f32 [1, 1, B, H*Dh]  per-(unit, slot) src-feature partial
-    dthd_ref,   # f32 [B, H]           per-unit dst-coeff gradient
-    # scratch
-    dthd_acc_ref,  # f32 [B, H]
-    *,
+    *refs,
     heads: int,
     head_dim: int,
     leaky_slope: float,
+    n_types: int,
+    beta: float | None,
+    precision: jax.lax.Precision | None,
 ):
+    residual = beta is not None
+    it = iter(refs)
+    pbias_ref = next(it) if residual else None  # scalar prefetch: f32 [T, H]
+    # inputs as the forward's, then g_out [B, H*Dh] (cotangent of the
+    # per-unit output), lse [B, H] (forward residual) and delta [B, H]
+    # (sum_f g_out * unmixed out, flash-attention delta)
+    mask_ref, thd_ref, ths_ref, hs_ref, gout_ref, lse_ref, delta_ref = (
+        next(it) for _ in range(7)
+    )
+    if residual:
+        pthd_ref, pths_ref, plse_ref = (next(it) for _ in range(3))
+    # outputs: per-(unit, slot) d_theta_src [1, 1, H, B] and d_h_src
+    # [1, 1, B, H*Dh] partials, per-unit d_theta_dst [B, H], and with typed
+    # tiles the per-unit bias-table partial [1, T*H, B] (row t*H + h)
+    dths_ref, dhs_ref, dthd_ref = (next(it) for _ in range(3))
+    dtb_ref = next(it) if n_types else None
+    dthd_acc_ref = next(it)                            # scratch f32 [B, H]
+    dtb_acc_ref = next(it) if n_types else None        # scratch f32 [T*H, B]
+
     u = pl.program_id(0)
     w = pl.program_id(1)
     nw = pl.num_programs(1)
@@ -172,29 +256,50 @@ def _bwd_kernel(
     @pl.when(w == 0)
     def _init():
         dthd_acc_ref[...] = jnp.zeros_like(dthd_acc_ref)
+        if n_types:
+            dtb_acc_ref[...] = jnp.zeros_like(dtb_acc_ref)
 
     live_slot = col_ref[u, w] >= 0
 
     @pl.when(live_slot)
     def _body():
-        live = mask_ref[0, 0]  # [B(dst), B(src)]
+        live, tile = _tile(mask_ref, n_types)  # [B(dst), B(src)]
         for hh in range(heads):
             sl = slice(hh * head_dim, (hh + 1) * head_dim)
-            pre, logits = _logits(thd_ref, ths_ref, bias_ref[gid_ref[u], hh], hh, leaky_slope)
+            pre, logits = _logits(
+                thd_ref, ths_ref, _bias(bias_ref, gid_ref[u], hh, tile, n_types), hh, leaky_slope
+            )
             # recompute-p: attention probabilities from the lse residual
             p = jnp.where(live, jnp.exp(logits - lse_ref[:, hh : hh + 1]), 0.0)
             g_out = gout_ref[:, sl].astype(jnp.float32)  # [B, Dh]
             hs = hs_ref[:, sl].astype(jnp.float32)       # [B, Dh]
             dp = jax.lax.dot_general(                    # g_out @ hs.T  [Bd, Bs]
-                g_out, hs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                g_out, hs, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32,
             )
             dlogit = p * (dp - delta_ref[:, hh : hh + 1])  # softmax backward
+            if residual:
+                dlogit = (1.0 - beta) * dlogit
             dpre = jnp.where(pre >= 0, dlogit, leaky_slope * dlogit)
             dths_ref[0, 0, hh : hh + 1, :] = jnp.sum(dpre, axis=0, keepdims=True)
-            dhs_ref[0, 0, :, sl] = jax.lax.dot_general(  # p.T @ g_out  [Bs, Dh]
-                p, g_out, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            weights = p
+            if residual:
+                _, plogits = _logits(
+                    pthd_ref, pths_ref, _bias(pbias_ref, gid_ref[u], hh, tile, n_types),
+                    hh, leaky_slope,
+                )
+                pp = jnp.where(live, jnp.exp(plogits - plse_ref[:, hh : hh + 1]), 0.0)
+                weights = (1.0 - beta) * p + beta * pp
+            dhs_ref[0, 0, :, sl] = jax.lax.dot_general(  # weights.T @ g_out  [Bs, Dh]
+                weights, g_out, (((0,), (0,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32,
             )
             dthd_acc_ref[:, hh : hh + 1] += jnp.sum(dpre, axis=1, keepdims=True)
+            for t in range(n_types):
+                r = t * heads + hh
+                dtb_acc_ref[r : r + 1, :] += jnp.sum(
+                    jnp.where(tile == t + 1, dpre, 0.0), axis=0, keepdims=True
+                )
 
     # a dead slot's partial blocks are still written back: zeros, not stale
     @pl.when(jnp.logical_not(live_slot))
@@ -205,6 +310,8 @@ def _bwd_kernel(
     @pl.when(w == nw - 1)
     def _finalize():
         dthd_ref[...] = dthd_acc_ref[...]
+        if n_types:
+            dtb_ref[0] = dtb_acc_ref[...]
 
 
 def _fill_dead(col_index):
@@ -219,140 +326,211 @@ def _fill_dead(col_index):
     return fslot, jnp.maximum(flat[fslot], 0)
 
 
-def _unit_map(u, w, col, gid, row, bias, fslot, fcol):
+# Index maps take (u, w, *scalar-prefetch refs): col, gid, row, bias,
+# fslot, fcol, and the previous layer's bias table with the residual.
+def _unit_map(u, w, *_):
     return (u, 0)
 
 
-def _in_specs(B, H, hdh, W):
-    def mask_map(u, w, col, gid, row, bias, fslot, fcol):
+def _unit_map3(u, w, *_):
+    return (u, 0, 0)
+
+
+def _specs(B, H, hdh, W):
+    """BlockSpecs by operand: masks (fed as [U*W, 1, B, B]), dst and src
+    coefficients, source features."""
+
+    def mask_map(u, w, col, gid, row, bias, fslot, fcol, *_):
         return (fslot[u * W + w], 0, 0, 0)
 
-    def thd_map(u, w, col, gid, row, bias, fslot, fcol):
+    def thd_map(u, w, col, gid, row, *_):
         return (gid[u], row[u], 0)
 
-    def ths_map(u, w, col, gid, row, bias, fslot, fcol):
+    def ths_map(u, w, col, gid, row, bias, fslot, fcol, *_):
         return (gid[u], 0, fcol[u * W + w])
 
-    def hs_map(u, w, col, gid, row, bias, fslot, fcol):
+    def hs_map(u, w, col, gid, row, bias, fslot, fcol, *_):
         return (fcol[u * W + w], 0)
 
-    return [
-        pl.BlockSpec((1, 1, B, B), mask_map),  # masks fed as [U*W, 1, B, B]
+    return (
+        pl.BlockSpec((1, 1, B, B), mask_map),
         pl.BlockSpec((1, B, H), thd_map),
         pl.BlockSpec((1, H, B), ths_map),
         pl.BlockSpec((B, hdh), hs_map),
-    ]
+    )
+
+
+def _n_types(masks, edge_bias):
+    """0 for boolean masks, else the rows of the typed tiles' bias table."""
+    return 0 if masks.dtype == jnp.bool_ else int(edge_bias.shape[0])
+
+
+def _prefetch_and_inputs(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
+                         h_src, edge_bias, attn_prev, B, W):
+    """Scalar-prefetch operands, and the block inputs before the backward's
+    own (the previous layer's thd/ths/lse follow them, see ``_prev``)."""
+    U = col_index.shape[0]
+    ns_pad = h_src.shape[0]
+    prefetch = [col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index)]
+    if attn_prev is not None:
+        prefetch.append(attn_prev.bias)
+    inputs = [masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
+              h_src.reshape(ns_pad, -1)]
+    return prefetch, inputs
+
+
+def _prev(attn_prev, specs, B, H):
+    """Block inputs and specs of the previous layer's attention."""
+    return (
+        [attn_prev.theta_dst, attn_prev.theta_src.swapaxes(1, 2), attn_prev.lse],
+        [specs[1], specs[2], pl.BlockSpec((B, H), _unit_map)],
+    )
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
-              h_src, edge_bias, leaky_slope, interpret):
+              h_src, edge_bias, attn_prev, opts):
     U, W = col_index.shape
     B = masks.shape[-1]
     G, ns_pad, H = theta_src.shape
     Dh = h_src.shape[-1]
     hdh = H * Dh
+    residual = opts.beta is not None
+
+    prefetch, inputs = _prefetch_and_inputs(
+        col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src, edge_bias,
+        attn_prev, B, W)
+    specs = _specs(B, H, hdh, W)
+    in_specs = list(specs)
+    out_specs = [pl.BlockSpec((B, hdh), _unit_map)]
+    out_shape = [jax.ShapeDtypeStruct((U * B, hdh), h_src.dtype)]
+    scratch = [
+        pltpu.VMEM((B, hdh), jnp.float32),
+        pltpu.VMEM((B, H), jnp.float32),
+        pltpu.VMEM((B, H), jnp.float32),
+    ]
+    if residual:
+        more, more_specs = _prev(attn_prev, specs, B, H)
+        inputs += more
+        in_specs += more_specs
+        out_specs.append(pl.BlockSpec((B, hdh), _unit_map))
+        out_shape.append(jax.ShapeDtypeStruct((U * B, hdh), jnp.float32))
+        scratch.append(pltpu.VMEM((B, hdh), jnp.float32))
+    out_specs.append(pl.BlockSpec((B, H), _unit_map))
+    out_shape.append(jax.ShapeDtypeStruct((U * B, H), jnp.float32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=len(prefetch),
         grid=(U, W),
-        in_specs=_in_specs(B, H, hdh, W),
-        out_specs=[
-            pl.BlockSpec((B, hdh), _unit_map),
-            pl.BlockSpec((B, H), _unit_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((B, hdh), jnp.float32),
-            pltpu.VMEM((B, H), jnp.float32),
-            pltpu.VMEM((B, H), jnp.float32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
-    out, lse = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, heads=H, head_dim=Dh, leaky_slope=leaky_slope
+            _fwd_kernel, heads=H, head_dim=Dh, leaky_slope=opts.leaky_slope,
+            n_types=_n_types(masks, edge_bias), beta=opts.beta, precision=opts.precision,
         ),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((U * B, hdh), h_src.dtype),
-            jax.ShapeDtypeStruct((U * B, H), jnp.float32),
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
+        out_shape=tuple(out_shape),
+        compiler_params=_compiler_params(),
+        interpret=opts.interpret,
         name="seg_gat_agg_multigraph",
-    )(col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index),
-      masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
-      h_src.reshape(ns_pad, hdh))
-    return out.reshape(U * B, H, Dh), lse
+    )(*prefetch, *inputs)
+    cur = outs[1].reshape(U * B, H, Dh) if residual else None
+    return outs[0].reshape(U * B, H, Dh), cur, outs[-1]
 
 
 def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
-              h_src, edge_bias, g_out, lse, delta, leaky_slope, interpret):
+              h_src, edge_bias, attn_prev, g_out, lse, delta, opts):
     U, W = col_index.shape
     B = masks.shape[-1]
     G, ns_pad, H = theta_src.shape
     Dh = h_src.shape[-1]
     hdh = H * Dh
+    n_types = _n_types(masks, edge_bias)
 
-    def slot_map(u, w, col, gid, row, bias, fslot, fcol):
+    def slot_map(u, w, *_):
         return (u, w, 0, 0)
 
+    prefetch, inputs = _prefetch_and_inputs(
+        col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src, edge_bias,
+        attn_prev, B, W)
+    specs = _specs(B, H, hdh, W)
+    inputs += [g_out.reshape(U * B, hdh), lse, delta]
+    in_specs = list(specs) + [
+        pl.BlockSpec((B, hdh), _unit_map),
+        pl.BlockSpec((B, H), _unit_map),
+        pl.BlockSpec((B, H), _unit_map),
+    ]
+    if attn_prev is not None:
+        more, more_specs = _prev(attn_prev, specs, B, H)
+        inputs += more
+        in_specs += more_specs
+    out_specs = [
+        pl.BlockSpec((1, 1, H, B), slot_map),
+        pl.BlockSpec((1, 1, B, hdh), slot_map),
+        pl.BlockSpec((B, H), _unit_map),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((U, W, H, B), jnp.float32),
+        jax.ShapeDtypeStruct((U, W, B, hdh), jnp.float32),
+        jax.ShapeDtypeStruct((U * B, H), jnp.float32),
+    ]
+    scratch = [pltpu.VMEM((B, H), jnp.float32)]
+    if n_types:
+        out_specs.append(pl.BlockSpec((1, n_types * H, B), _unit_map3))
+        out_shape.append(jax.ShapeDtypeStruct((U, n_types * H, B), jnp.float32))
+        scratch.append(pltpu.VMEM((n_types * H, B), jnp.float32))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=len(prefetch),
         grid=(U, W),
-        in_specs=_in_specs(B, H, hdh, W) + [
-            pl.BlockSpec((B, hdh), _unit_map),
-            pl.BlockSpec((B, H), _unit_map),
-            pl.BlockSpec((B, H), _unit_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, H, B), slot_map),
-            pl.BlockSpec((1, 1, B, hdh), slot_map),
-            pl.BlockSpec((B, H), _unit_map),
-        ],
-        scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
-    dths, dhs, dthd = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(
-            _bwd_kernel, heads=H, head_dim=Dh, leaky_slope=leaky_slope
+            _bwd_kernel, heads=H, head_dim=Dh, leaky_slope=opts.leaky_slope,
+            n_types=n_types, beta=opts.beta, precision=opts.precision,
         ),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((U, W, H, B), jnp.float32),
-            jax.ShapeDtypeStruct((U, W, B, hdh), jnp.float32),
-            jax.ShapeDtypeStruct((U * B, H), jnp.float32),
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
+        out_shape=tuple(out_shape),
+        compiler_params=_compiler_params(),
+        interpret=opts.interpret,
         name="seg_gat_agg_multigraph_bwd",
-    )(col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index),
-      masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
-      h_src.reshape(ns_pad, hdh), g_out.reshape(U * B, hdh), lse, delta)
-    return dths.swapaxes(2, 3), dhs.reshape(U, W, B, H, Dh), dthd
+    )(*prefetch, *inputs)
+    dths, dhs, dthd = outs[:3]
+    dtb = outs[3] if n_types else None
+    return dths.swapaxes(2, 3), dhs.reshape(U, W, B, H, Dh), dthd, dtb
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
 def _multigraph(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
-                h_src, edge_bias, leaky_slope, interpret):
-    out, _ = _fwd_call(col_index, graph_id, dst_row, masks, theta_src,
-                       theta_dst, h_src, edge_bias, leaky_slope, interpret)
-    return out
+                h_src, edge_bias, attn_prev, opts):
+    out, _, lse = _fwd_call(col_index, graph_id, dst_row, masks, theta_src,
+                            theta_dst, h_src, edge_bias, attn_prev, opts)
+    return out, lse
 
 
 def _multigraph_fwd(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
-                    h_src, edge_bias, leaky_slope, interpret):
-    out, lse = _fwd_call(col_index, graph_id, dst_row, masks, theta_src,
-                         theta_dst, h_src, edge_bias, leaky_slope, interpret)
+                    h_src, edge_bias, attn_prev, opts):
+    out, cur, lse = _fwd_call(col_index, graph_id, dst_row, masks, theta_src,
+                              theta_dst, h_src, edge_bias, attn_prev, opts)
+    # the backward's delta needs the unmixed output
     res = (col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src,
-           edge_bias, out, lse)
-    return out, res
+           edge_bias, attn_prev, out if cur is None else cur, lse)
+    return (out, lse), res
 
 
-def _multigraph_bwd(leaky_slope, interpret, res, g):
+def _multigraph_bwd(opts, res, cts):
     (col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src,
-     edge_bias, out, lse) = res
+     edge_bias, attn_prev, out, lse) = res
+    g, _ = cts  # lse carries no gradient (the wrapper stops it)
     U, W = col_index.shape
     B = masks.shape[-1]
     G, ns_pad, H = theta_src.shape
@@ -361,9 +539,9 @@ def _multigraph_bwd(leaky_slope, interpret, res, g):
     rd = theta_dst.shape[1] // B
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    dths_blk, dhs_blk, dthd_units = _bwd_call(
+    dths_blk, dhs_blk, dthd_units, dtb = _bwd_call(
         col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src,
-        edge_bias, g, lse, delta, leaky_slope, interpret,
+        edge_bias, attn_prev, g, lse, delta, opts,
     )
 
     # GSF-like scatter of the per-(unit, slot) partials onto the shared
@@ -393,9 +571,13 @@ def _multigraph_bwd(leaky_slope, interpret, res, g):
         .add(dthd_units.reshape(U, B, H))
         .reshape(G, rd * B, H)
     )
-    # bias enters every logit additively: its gradient is the total dpre
-    # mass per graph, already summed over dst inside dths_blk.
-    d_bias = jax.ops.segment_sum(dths_blk.sum(axis=1), gid_blk, num_segments=G)
+    if dtb is None:
+        # bias enters every logit additively: its gradient is the total dpre
+        # mass per graph, already summed over dst inside dths_blk.
+        d_bias = jax.ops.segment_sum(dths_blk.sum(axis=1), gid_blk, num_segments=G)
+    else:
+        # typed tiles: the kernel summed dpre per (type, head) over dst
+        d_bias = dtb.sum(axis=(0, 2)).reshape(edge_bias.shape)
 
     f0 = lambda x: np.zeros(x.shape, jax.dtypes.float0)
     return (
@@ -404,34 +586,57 @@ def _multigraph_bwd(leaky_slope, interpret, res, g):
         d_theta_dst.astype(theta_dst.dtype),
         d_h_src.astype(h_src.dtype),
         d_bias.astype(edge_bias.dtype),
+        jax.tree_util.tree_map(jnp.zeros_like, attn_prev),  # p_prev: no gradient
     )
 
 
 _multigraph.defvjp(_multigraph_fwd, _multigraph_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("leaky_slope", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("leaky_slope", "interpret", "beta", "return_lse", "precision")
+)
 def seg_gat_agg_multigraph(
     col_index: jnp.ndarray,  # int32 [U, W]  src block columns (-1 pad, unique/row)
     graph_id: jnp.ndarray,   # int32 [U]
     dst_row: jnp.ndarray,    # int32 [U]     dst block row within the graph
-    masks: jnp.ndarray,      # bool  [U, W, B, B]
+    masks: jnp.ndarray,      # bool [U, W, B, B], or int8 type tiles (type + 1)
     theta_src: jnp.ndarray,  # f32   [G, Ns_pad, H]
     theta_dst: jnp.ndarray,  # f32   [G, Nd_pad, H]
     h_src: jnp.ndarray,      # f32   [Ns_pad, H, Dh] (shared across graphs)
-    edge_bias: jnp.ndarray | None = None,  # [G, H]
+    edge_bias: jnp.ndarray | None = None,  # [G, H]; [T, H] with type tiles
+    attn_prev: Attention | None = None,     # the attention residual's source
     *,
     leaky_slope: float = 0.2,
     interpret: bool = False,
-) -> jnp.ndarray:
+    beta: float | None = None,   # residual weight, given with attn_prev
+    return_lse: bool = False,
+    precision: jax.lax.Precision | None = None,
+):
     """Returns per-unit aggregates [U*B, H, Dh] (caller scatters by
-    (graph_id, dst_row) — disjoint by construction).  Differentiable wrt
-    theta_src / theta_dst / h_src / edge_bias via a fused Pallas backward."""
+    (graph_id, dst_row) — disjoint by construction), and with
+    ``return_lse`` the per-unit-row log-sum-exp [U*B, H] as well, which
+    carries no gradient (a next layer's ``Attention.lse``).
+    Differentiable wrt theta_src / theta_dst / h_src / edge_bias via a
+    fused Pallas backward.  ``precision`` is that of the bodies' dots: on
+    a TPU, Mosaic's default for float32 operands is one bfloat16 pass;
+    ``HIGHEST`` computes them in float32."""
     G, _, H = theta_src.shape
+    if (attn_prev is None) != (beta is None):
+        raise ValueError("the attention residual takes attn_prev and beta together")
     if edge_bias is None:
+        if masks.dtype != jnp.bool_:
+            raise ValueError("typed tiles take a [T, H] bias table")
         edge_bias = jnp.zeros((G, H), jnp.float32)
     edge_bias = jnp.asarray(edge_bias, jnp.float32)
-    return _multigraph(
+    if attn_prev is not None:
+        attn_prev = attn_prev._replace(bias=jnp.asarray(attn_prev.bias, jnp.float32))
+    out, lse = _multigraph(
         col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src,
-        edge_bias, float(leaky_slope), bool(interpret),
+        edge_bias, attn_prev,
+        _Opts(float(leaky_slope), bool(interpret), None if beta is None else float(beta),
+              precision),
     )
+    if return_lse:
+        return out, jax.lax.stop_gradient(lse)
+    return out

@@ -17,12 +17,20 @@ lane restarts* — resume the same checkpoint directory with a different
 forward is bit-identical for any lane count, and gradients agree to f32
 tolerance — the lane partition only regroups the cross-unit reduction).
 
-R-GAT trains through its per-relation forward with the same fused
-multigraph kernel per relation (its relation-specific projections keep it
-off the consolidated one-launch plan).  ``--backend kernel`` compiles the
-Pallas kernels for the TPU and refuses to run without one; on a CPU host
-ask for ``kernel_interpret``, which runs the same kernel body under the
-Pallas interpreter.
+The launcher trains three models.  HAN runs as above.  S-HGN
+(``--model S-HGN``) attends over the union graph of the HetG
+(``graphs.union_graph``): one typed plan of int8 type tiles, and each of
+its three layers (two hidden, one output) one typed multigraph launch,
+forward and backward (``models/hgnn/shgn.shgn_forward_plan``), on one
+lane of one chip; ``--hidden 64 --heads 8 --lr 5e-4 --weight-decay 1e-4``
+are its published settings.  R-GAT trains through its per-relation
+forward with the same fused multigraph kernel per relation (its
+relation-specific projections keep it off the consolidated one-launch
+plan).  ``--backend kernel`` compiles the Pallas kernels for the TPU and
+refuses to run without one; on a CPU host ask for ``kernel_interpret``,
+which runs the same kernel body under the Pallas interpreter;
+``reference`` runs HAN's multilane oracle, S-HGN's plain edge-list
+reference, and R-GAT's BLOCK path.
 """
 from __future__ import annotations
 
@@ -42,8 +50,10 @@ from ..graphs import (
     dataset_target,
     synthetic_hetgraph,
     synthetic_labels,
+    union_graph,
 )
 from ..models.hgnn import MODELS, han_forward_multilane, prepare_data
+from ..models.hgnn.shgn import shgn_forward_plan, shgn_reference
 from ..obs import get_registry, profile
 from ..optim import AdamWConfig
 from ..train import (
@@ -57,10 +67,12 @@ from .mesh import make_lane_mesh
 
 DATASETS = ("acm", "imdb", "dblp")
 
-# model.init keyword vocabularies differ (HAN takes att_dim, R-GAT layers)
+# model.init keyword vocabularies differ (HAN takes att_dim, R-GAT layers,
+# S-HGN its published depth and edge-embedding width)
 _INIT_KW = {
     "HAN": lambda hidden, heads: dict(hidden=hidden, heads=heads, att_dim=2 * hidden),
     "R-GAT": lambda hidden, heads: dict(hidden=hidden, heads=heads, layers=2),
+    "S-HGN": lambda hidden, heads: dict(hidden=hidden, heads=heads, layers=2, edge_dim=64),
 }
 
 
@@ -72,12 +84,17 @@ def build_problem(
     block: int = 128,
     max_edges: int = 400_000,
     seed: int = 0,
+    model_name: str = "HAN",
 ):
-    """Synthesize the Table-5 HetG and its device-resident training data,
-    semantic graphs ordered by the similarity schedule (FP reuse)."""
+    """Synthesize the Table-5 HetG and its device-resident training data:
+    for S-HGN the union graph (typed tiles, every relation uncapped), for
+    the others the metapath semantic graphs ordered by the similarity
+    schedule (FP reuse)."""
     g = synthetic_hetgraph(dataset, scale=scale, feat_scale=feat_scale, seed=seed)
     target, ncls = dataset_target(dataset)
     labels = synthetic_labels(g, dataset, seed=seed)
+    if model_name == "S-HGN":
+        return g, prepare_data(g, [union_graph(g)], target, ncls, labels, block=block)
     sgs = build_semantic_graphs(g, dataset_metapaths(dataset), max_edges=max_edges)
     order, _ = similarity_schedule(sgs, g.vertex_counts)
     data = prepare_data(g, [sgs[i] for i in order], target, ncls, labels, block=block)
@@ -96,6 +113,7 @@ def run_training(
     hidden: int = 16,
     heads: int = 4,
     lr: float = 5e-3,
+    weight_decay: float = 0.0,
     batch: int = 0,  # labeled minibatch size; 0 = full batch
     block: int = 128,
     scale: float = 0.1,
@@ -129,7 +147,7 @@ def run_training(
     reg = registry if registry is not None else get_registry()
     g, data = build_problem(
         dataset, scale=scale, feat_scale=feat_scale, block=block,
-        max_edges=max_edges, seed=seed,
+        max_edges=max_edges, seed=seed, model_name=model_name,
     )
     model = MODELS[model_name]
     n_target = g.vertex_counts[data.target_type]
@@ -154,6 +172,19 @@ def run_training(
             p, data, plan, mesh=mesh, lane_axes=lane_axes(rules), backend=backend
         )
         meta_backend = backend
+    elif model_name == "S-HGN":
+        # one typed plan over the union graph, every layer one typed launch
+        # on one lane; the attention residual reads the previous layer's
+        # per-row lse in unit order, so the plan is not split over lanes
+        if lanes != 1 or model_split != 1 or (plan_lanes or 1) != 1:
+            raise ValueError("S-HGN trains on one lane of one chip")
+        plan = place_plan(build_multilane_plan(data.graphs, 1), mesh, lane_axes(rules))
+        if backend == "reference":
+            forward_fn = lambda p: shgn_reference(p, data)
+            meta_backend = NABackend.SEGMENT.value
+        else:
+            forward_fn = lambda p: shgn_forward_plan(p, data, plan, backend=backend)
+            meta_backend = backend
     else:
         # per-relation projections -> per-relation fused kernel launches
         plan = None
@@ -163,7 +194,7 @@ def run_training(
         forward_fn = lambda p: model.forward(p, data, backend=nab)
         meta_backend = nab.value
 
-    opt = AdamWConfig(lr=lr, weight_decay=0.0)
+    opt = AdamWConfig(lr=lr, weight_decay=weight_decay)
     pipeline = SyntheticHGNNData(
         num_vertices=n_target,
         batch_size=batch if batch > 0 else n_target,
@@ -204,8 +235,14 @@ def run_training(
         plan_shards=None if plan is None else {
             str(s.device): list(s.data.shape) for s in plan.masks.addressable_shards
         },
-        # NA grid slots per lane, and how many are live (not padding)
+        # NA grid slots per lane, and how many are live (not padding); the
+        # NA launches a step makes over the plan, forward (one per layer);
+        # the edge types of a typed plan
         na_slots=None if plan is None else plan.na_slots(),
+        na_layers=None if plan is None else max(
+            1, sum(k.endswith(".attn_src") for k in state.params)  # S-HGN: one a layer
+        ),
+        na_edge_types=len(data.graphs[0].edge_type_names) or None,
         n_params=n_params, n_target=n_target,
     )
     return state, history, meta
@@ -231,6 +268,7 @@ def main() -> None:
     ap.add_argument("--hidden", type=int, default=16)
     ap.add_argument("--heads", type=int, default=4)
     ap.add_argument("--lr", type=float, default=5e-3)
+    ap.add_argument("--weight-decay", type=float, default=0.0, help="AdamW's decoupled decay")
     ap.add_argument("--batch", type=int, default=0, help="labeled minibatch (0 = full)")
     ap.add_argument("--block", type=int, default=128, help="dst block size (paper: 128)")
     ap.add_argument("--scale", type=float, default=0.1)
@@ -260,6 +298,7 @@ def main() -> None:
         dataset=args.dataset, model_name=args.model, steps=args.steps,
         lanes=args.lanes, model_split=args.model_split, plan_lanes=args.plan_lanes,
         backend=args.backend, hidden=args.hidden, heads=args.heads, lr=args.lr,
+        weight_decay=args.weight_decay,
         batch=args.batch, block=args.block, scale=args.scale,
         feat_scale=args.feat_scale, max_edges=args.max_edges, seed=args.seed,
         ckpt_dir=args.ckpt, ckpt_every=args.ckpt_every, resume=not args.no_resume,

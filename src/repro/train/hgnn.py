@@ -41,19 +41,24 @@ _HGNN_PARAM_AXES: dict[str, tuple[str | None, ...]] = {
     "w_dst": ("embed", "mlp"),
     "w_g": ("mlp", None),
     "w_out": ("mlp", None),
+    # S-HGN (flat "<layer or type>.<leaf>" names; keyed by the leaf part)
+    "w_in": ("embed", None),
+    "w": (None, "mlp"),
+    "w_res": ("mlp", None),
 }
 
 
 def hgnn_param_axes(params) -> Any:
     """Logical-axes pytree for an HGNN params tree (same structure).
 
-    Leaves are keyed by their last tree-path component; anything not in
-    the table replicates (``(None,) * ndim``).
+    Leaves are keyed by their last tree-path component, and a dotted
+    name (S-HGN's ``layer1.w``) by its part after the last dot; anything
+    not in the table replicates (``(None,) * ndim``).
     """
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     axes = []
     for path, leaf in flat:
-        name = str(getattr(path[-1], "key", path[-1]))
+        name = str(getattr(path[-1], "key", path[-1])).rsplit(".", 1)[-1]
         ax = _HGNN_PARAM_AXES.get(name)
         if ax is None or len(ax) != leaf.ndim:
             ax = (None,) * leaf.ndim

@@ -13,6 +13,7 @@ from repro.graphs import (
     relation_semantic_graphs,
     synthetic_hetgraph,
     synthetic_labels,
+    union_graph,
 )
 from repro.models.hgnn import MODELS, cross_entropy, prepare_data
 from repro.models.hgnn.han import han_forward_staged
@@ -28,10 +29,17 @@ def acm():
     return g, target, ncls, labels, mp, rel
 
 
+def _views(acm, name):
+    """The semantic graphs each model runs on: HAN metapaths, S-HGN the
+    union graph, the relation-wise models one graph per relation."""
+    g, _, _, _, mp, rel = acm
+    return {"HAN": mp, "S-HGN": [union_graph(g)]}.get(name, rel)
+
+
 @pytest.mark.parametrize("name", ["HAN", "R-GCN", "R-GAT", "S-HGN"])
 def test_model_forward_shapes_finite(acm, name):
     g, target, ncls, labels, mp, rel = acm
-    data = prepare_data(g, mp if name == "HAN" else rel, target, ncls, labels, block=16)
+    data = prepare_data(g, _views(acm, name), target, ncls, labels, block=16)
     model = MODELS[name]
     params = model.init(jax.random.key(0), data)
     logits = model.forward(params, data, backend=NABackend.SEGMENT)
@@ -89,14 +97,15 @@ def test_han_multigraph_backend_matches_and_trains(acm):
 
 
 def test_shgn_edge_bias_matters(acm):
-    """S-HGN's relation embedding term must influence the output."""
-    g, target, ncls, labels, _, rel = acm
-    data = prepare_data(g, rel, target, ncls, labels, block=16)
+    """S-HGN's edge-type term must influence the output, and only through
+    the types: one embedding row moved moves the logits."""
+    g, target, ncls, labels, _, _ = acm
+    data = prepare_data(g, _views(acm, "S-HGN"), target, ncls, labels, block=16)
     model = MODELS["S-HGN"]
     params = model.init(jax.random.key(3), data)
     base = model.forward(params, data)
-    bumped = jax.tree_util.tree_map(lambda x: x, params)
-    bumped["layers"][0]["r_emb"] = params["layers"][0]["r_emb"] + 3.0
+    bumped = dict(params)
+    bumped["layer1.edge_emb"] = params["layer1.edge_emb"].at[0].add(3.0)  # the TP relation
     assert not np.allclose(np.asarray(base), np.asarray(model.forward(bumped, data)))
 
 

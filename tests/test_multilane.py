@@ -126,7 +126,7 @@ def test_multilane_kernel_backend_matches_reference(dblp_setup, lanes):
 def test_multigraph_bwd_dead_slot_partials_are_zero(dblp_setup):
     """The backward writes exact zeros into the raw d_theta_src / d_h_src
     partial blocks of every dead slot, none stale."""
-    from repro.kernels.seg_gat_agg_multigraph import _bwd_call, _fwd_call
+    from repro.kernels.seg_gat_agg_multigraph import _bwd_call, _fwd_call, _Opts
 
     batches, ths, thd, hs = dblp_setup
     plan = build_multilane_plan(batches, 4)
@@ -134,11 +134,12 @@ def test_multigraph_bwd_dead_slot_partials_are_zero(dblp_setup):
     col = plan.col_index.reshape(lanes * units, w)
     args = (col, plan.graph_id.reshape(-1), plan.dst_row.reshape(-1),
             plan.masks.reshape(lanes * units, w, plan.block, plan.block), ths, thd, hs,
-            jnp.zeros((len(batches), ths.shape[-1]), jnp.float32))
-    out, lse = _fwd_call(*args, 0.2, True)
+            jnp.zeros((len(batches), ths.shape[-1]), jnp.float32), None)
+    opts = _Opts(leaky_slope=0.2, interpret=True, beta=None)
+    out, _, lse = _fwd_call(*args, opts)
     g = jnp.ones_like(out)
     delta = jnp.sum(g * out, axis=-1)
-    dths, dhs, _ = _bwd_call(*args, g, lse, delta, 0.2, True)
+    dths, dhs, _, _ = _bwd_call(*args, g, lse, delta, opts)
     dead = np.asarray(col) < 0
     assert np.abs(np.asarray(dths)[dead]).max() == 0.0
     assert np.abs(np.asarray(dhs)[dead]).max() == 0.0

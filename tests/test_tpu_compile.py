@@ -5,7 +5,9 @@ The TPU compiler is installed alongside JAX; it compiles for a described
 shapes that Mosaic refuses (the last two block dims must be multiples of
 (8, 128) or span the whole array dim), so these compiles are what guards
 the kernels' TPU layouts.  Widths are HAN's (8 heads x 8, block 128) on
-ACM's raw feature width (1902).
+ACM's raw feature width (1902), and S-HGN's typed launches on the union
+graph of ACM (8 heads x 64 with the attention residual; the output
+layer's 1 head, its classes padded to 128 lanes; 8 edge types).
 
 The topology is described only inside a fixture: the TPU library may be
 loaded by one process at a time, and pytest-xdist workers import every
@@ -21,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.seg_gat_agg_fused_fp import seg_gat_agg_fused_fp
-from repro.kernels.seg_gat_agg_multigraph import seg_gat_agg_multigraph
+from repro.kernels.seg_gat_agg_multigraph import Attention, seg_gat_agg_multigraph
 
 H, DH, B, G, U, W = 8, 8, 128, 2, 24, 8
 NS = 32 * B        # src vertex space (padded), 32 blocks
@@ -100,3 +102,27 @@ def test_fused_fp_backward_compiles(one_chip, tables):
     txt = _compiled_text(loss_grad, *_fused_fp_args(one_chip, tables))
     assert "tpu_custom_call" in txt
     assert "seg_gat_agg_fused_fp_bwd" in txt
+
+
+@pytest.mark.parametrize("heads,dh,residual", [(8, 64, True), (1, 128, False)],
+                         ids=["hidden-residual", "output"])
+def test_multigraph_typed_tiles_compile(one_chip, heads, dh, residual):
+    """S-HGN's launches: int8 type tiles, a [T, H] bias table, the
+    residual's rebuilt attention, float32 dots; forward and backward in
+    one program."""
+    s = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    i32, t_n, u_n, w_n = jnp.int32, 8, 86, 8
+    n = u_n * B
+    args = (s((u_n, w_n), i32), s((u_n,), i32), s((u_n,), i32), s((u_n, w_n, B, B), jnp.int8),
+            s((1, n, heads)), s((1, n, heads)), s((n, heads, dh)), s((t_n, heads)))
+    prev = Attention(s((1, n, heads)), s((1, n, heads)), s((t_n, heads)), s((n, heads))) if residual else None
+
+    def loss_grad(col, gid, row, tiles, ths, thd, hs, bias, prev):
+        f = lambda *p: seg_gat_agg_multigraph(
+            col, gid, row, tiles, *p, prev, leaky_slope=0.05,
+            beta=0.05 if residual else None, precision=jax.lax.Precision.HIGHEST).sum()
+        return jax.grad(f, argnums=(0, 1, 2, 3))(ths, thd, hs, bias)
+
+    txt = _compiled_text(loss_grad, *args, prev)
+    assert "tpu_custom_call" in txt
+    assert "seg_gat_agg_multigraph_bwd" in txt
