@@ -45,25 +45,30 @@ class MultiLanePlan:
     num_graphs: int
     n_dst_blocks: int       # per graph (shared dst space)
     lane_plan: LanePlan | None  # host-side scheduling metadata (not traced)
-    # host-side: slots with col >= 0 per lane, of U * W each (not traced)
+    # host-side: slots with col >= 0 per lane, of U * W each, and the
+    # distinct source blocks among them per lane (not traced)
     live_slots: np.ndarray | None = None
+    src_runs: np.ndarray | None = None
 
     @property
     def num_lanes(self) -> int:
         return int(self.col_index.shape[0])
 
     def na_slots(self) -> dict:
-        """NA grid slots per lane and how many of them are live; the
-        kernel fetches nothing and runs no body for the rest."""
+        """NA grid slots per lane and how many of them are live (the
+        kernel fetches nothing and runs no body for the rest), and the
+        source blocks the live slots reference: the backward visits each
+        in one run of steps and writes its d_h_src block once."""
         _, units, w = self.col_index.shape
-        return {"grid": int(units * w), "live": self.live_slots.tolist()}
+        return {"grid": int(units * w), "live": self.live_slots.tolist(),
+                "src_runs": self.src_runs.tolist()}
 
 
 def _flatten_unflatten():
     arr = ("col_index", "masks", "graph_id", "dst_row", "valid")
-    # lane_plan and live_slots hold host-side numpy arrays; they must NOT
-    # ride in the pytree aux (aux must be hashable) — reconstructed copies
-    # carry None there, which multilane_na never reads.
+    # lane_plan, live_slots and src_runs hold host-side numpy arrays; they
+    # must NOT ride in the pytree aux (aux must be hashable) — reconstructed
+    # copies carry None there, which multilane_na never reads.
     meta = ("block", "num_graphs", "n_dst_blocks")
 
     def fl(p):
@@ -137,6 +142,7 @@ def build_multilane_plan(
         n_dst_blocks=n_rows,
         lane_plan=plan,
         live_slots=(col >= 0).sum(axis=(1, 2)),
+        src_runs=np.array([np.unique(c[c >= 0]).size for c in col]),
     )
 
 

@@ -29,14 +29,26 @@ The backward is itself one fused multigraph launch (the
 kernel-consolidation result of arXiv 2408.08490 applied to training):
 it *recomputes* the attention probabilities online from lse
 (p = exp(logits - lse), flash-attention style — no [U, W, B, B, H]
-probability tensor is ever materialized) and produces
+probability tensor is ever materialized).  Its grid walks the flattened
+(u, w) slots in source-block order: one stable sort by (col, graph),
+dead slots last.  A source block's slots are then consecutive steps, and
+so are its slots of one graph, and Pallas TPU keeps an output block
+resident while consecutive steps revisit it.  So the GSF-like
+scatter-add onto the shared src vertex space happens in VMEM:
 
-  * d_theta_dst  — accumulated across the W axis in VMEM scratch,
-    written once per unit;
-  * per-(unit, slot) d_theta_src / d_h_src block partials — the GSF-like
-    scatter-add onto the shared src vertex space happens outside the
-    kernel with segment sums (Pallas TPU cannot safely revisit output
-    blocks in non-consecutive grid steps).
+  * d_h_src — summed in its output block over the col's run of steps,
+    written to HBM once per source block;
+  * d_theta_src — summed likewise over each (col, graph) run;
+  * d_theta_dst — one lane-dense [H, B] block per slot, summed onto the
+    units outside the kernel (a unit's slots are no longer consecutive);
+  * with typed tiles, the [T, H] table's gradient, summed per source
+    block like d_h_src and over the blocks outside.
+
+h_src and theta_src stay resident across a run; the unit's g_out, lse,
+delta and theta_dst stream in per step.  Dead steps repeat the last live
+step's block indices, so they fetch and write nothing.  A source block
+that no live slot references is never visited, and the wrapper zeroes
+its rows.
 
 ``seg_gat_agg_multigraph`` carries a ``jax.custom_vjp``, so HAN training
 consolidates all relations of a step into a single forward and a single
@@ -214,14 +226,19 @@ def _fwd_kernel(
         lse_ref[...] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
+# Step flags of the backward's sorted grid.
+_LIVE, _NEW_COL, _NEW_SUB = 1, 2, 4
+
+
 def _bwd_kernel(
-    # scalar prefetch
-    col_ref,    # int32 [U, W]
+    # scalar prefetch: per unit, then per step of the sorted grid
     gid_ref,    # int32 [U]
     row_ref,    # int32 [U]
     bias_ref,   # f32   [G, H], or [T, H] with typed tiles
-    fslot_ref,  # int32 [U*W]  filled slot (index maps only)
-    fcol_ref,   # int32 [U*W]  filled col  (index maps only)
+    slot_ref,   # int32 [U*W]  the step's flat (u, w) slot
+    unit_ref,   # int32 [U*W]  the step's unit
+    col_ref,    # int32 [U*W]  the step's src block column
+    flag_ref,   # int32 [U*W]  _LIVE | _NEW_COL | _NEW_SUB
     *refs,
     heads: int,
     head_dim: int,
@@ -241,33 +258,35 @@ def _bwd_kernel(
     )
     if residual:
         pthd_ref, pths_ref, plse_ref = (next(it) for _ in range(3))
-    # outputs: per-(unit, slot) d_theta_src [1, 1, H, B] and d_h_src
-    # [1, 1, B, H*Dh] partials, per-unit d_theta_dst [B, H], and with typed
-    # tiles the per-unit bias-table partial [1, T*H, B] (row t*H + h)
+    # outputs, f32: d_theta_src [1, H, B] of the step's (graph, col),
+    # d_h_src [B, H*Dh] of its col, both summed in place over their runs;
+    # the slot's d_theta_dst [1, H, B]; with typed tiles the col's
+    # bias-table partial [T*H, B] (row t*H + h), summed over the col run
     dths_ref, dhs_ref, dthd_ref = (next(it) for _ in range(3))
     dtb_ref = next(it) if n_types else None
-    dthd_acc_ref = next(it)                            # scratch f32 [B, H]
-    dtb_acc_ref = next(it) if n_types else None        # scratch f32 [T*H, B]
+    dthd_col_ref = next(it)  # scratch f32 [B, 128k]: column h holds head h's d_theta_dst
 
-    u = pl.program_id(0)
-    w = pl.program_id(1)
-    nw = pl.num_programs(1)
+    i = pl.program_id(0)
+    flags = flag_ref[i]
+    gid = gid_ref[unit_ref[i]]
 
-    @pl.when(w == 0)
-    def _init():
-        dthd_acc_ref[...] = jnp.zeros_like(dthd_acc_ref)
+    @pl.when((flags & _NEW_COL) != 0)
+    def _init_col():
+        dhs_ref[...] = jnp.zeros_like(dhs_ref)
         if n_types:
-            dtb_acc_ref[...] = jnp.zeros_like(dtb_acc_ref)
+            dtb_ref[...] = jnp.zeros_like(dtb_ref)
 
-    live_slot = col_ref[u, w] >= 0
+    @pl.when((flags & _NEW_SUB) != 0)
+    def _init_sub():
+        dths_ref[...] = jnp.zeros_like(dths_ref)
 
-    @pl.when(live_slot)
+    @pl.when((flags & _LIVE) != 0)
     def _body():
         live, tile = _tile(mask_ref, n_types)  # [B(dst), B(src)]
         for hh in range(heads):
             sl = slice(hh * head_dim, (hh + 1) * head_dim)
             pre, logits = _logits(
-                thd_ref, ths_ref, _bias(bias_ref, gid_ref[u], hh, tile, n_types), hh, leaky_slope
+                thd_ref, ths_ref, _bias(bias_ref, gid, hh, tile, n_types), hh, leaky_slope
             )
             # recompute-p: attention probabilities from the lse residual
             p = jnp.where(live, jnp.exp(logits - lse_ref[:, hh : hh + 1]), 0.0)
@@ -281,37 +300,54 @@ def _bwd_kernel(
             if residual:
                 dlogit = (1.0 - beta) * dlogit
             dpre = jnp.where(pre >= 0, dlogit, leaky_slope * dlogit)
-            dths_ref[0, 0, hh : hh + 1, :] = jnp.sum(dpre, axis=0, keepdims=True)
+            dths_ref[0, hh : hh + 1, :] += jnp.sum(dpre, axis=0, keepdims=True)
             weights = p
             if residual:
                 _, plogits = _logits(
-                    pthd_ref, pths_ref, _bias(pbias_ref, gid_ref[u], hh, tile, n_types),
+                    pthd_ref, pths_ref, _bias(pbias_ref, gid, hh, tile, n_types),
                     hh, leaky_slope,
                 )
                 pp = jnp.where(live, jnp.exp(plogits - plse_ref[:, hh : hh + 1]), 0.0)
                 weights = (1.0 - beta) * p + beta * pp
-            dhs_ref[0, 0, :, sl] = jax.lax.dot_general(  # weights.T @ g_out  [Bs, Dh]
+            dhs_ref[:, sl] += jax.lax.dot_general(  # weights.T @ g_out  [Bs, Dh]
                 weights, g_out, (((0,), (0,)), ((), ())), precision=precision,
                 preferred_element_type=jnp.float32,
             )
-            dthd_acc_ref[:, hh : hh + 1] += jnp.sum(dpre, axis=1, keepdims=True)
+            dthd_col_ref[:, hh : hh + 1] = jnp.sum(dpre, axis=1, keepdims=True)
             for t in range(n_types):
                 r = t * heads + hh
-                dtb_acc_ref[r : r + 1, :] += jnp.sum(
+                dtb_ref[r : r + 1, :] += jnp.sum(
                     jnp.where(tile == t + 1, dpre, 0.0), axis=0, keepdims=True
                 )
+        # lane-dense [H, B]: a [B, H] block would pad H to 128 lanes in HBM
+        dthd_ref[0] = dthd_col_ref[...].T[:heads, :]
 
-    # a dead slot's partial blocks are still written back: zeros, not stale
-    @pl.when(jnp.logical_not(live_slot))
-    def _dead():
-        dths_ref[...] = jnp.zeros_like(dths_ref)
-        dhs_ref[...] = jnp.zeros_like(dhs_ref)
 
-    @pl.when(w == nw - 1)
-    def _finalize():
-        dthd_ref[...] = dthd_acc_ref[...]
-        if n_types:
-            dtb_ref[0] = dtb_acc_ref[...]
+def _bwd_order(col_index, graph_id, n_graphs):
+    """The backward's grid: one step per flat (u, w) slot, live slots
+    stably sorted by (col, graph), dead slots last.  Returns per step the
+    slot, its unit, its col and its flags (live; first step of its col
+    run; first of its (col, graph) run).  Dead steps repeat the last live
+    step's slot and col, so they fetch nothing and write nothing."""
+    U, W = col_index.shape
+    flat = col_index.reshape(-1)
+    gid = jnp.repeat(graph_id, W)
+    live = flat >= 0
+    key = jnp.where(live, flat * n_graphs + gid, jnp.iinfo(jnp.int32).max)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    steps = jnp.arange(U * W, dtype=jnp.int32)
+    slot = jnp.argsort(key, stable=True).astype(jnp.int32)
+    slot = slot[jnp.minimum(steps, jnp.maximum(n_live - 1, 0))]
+    col = jnp.maximum(flat[slot], 0)
+    step_live = steps < n_live
+
+    def starts(x):
+        return step_live & ((steps == 0) | (x != jnp.roll(x, 1)))
+
+    new_col = starts(col)
+    new_sub = new_col | starts(gid[slot])
+    flags = (step_live * _LIVE + new_col * _NEW_COL + new_sub * _NEW_SUB).astype(jnp.int32)
+    return slot, slot // W, col, flags
 
 
 def _fill_dead(col_index):
@@ -326,19 +362,16 @@ def _fill_dead(col_index):
     return fslot, jnp.maximum(flat[fslot], 0)
 
 
-# Index maps take (u, w, *scalar-prefetch refs): col, gid, row, bias,
-# fslot, fcol, and the previous layer's bias table with the residual.
+# The forward's index maps take (u, w, *scalar-prefetch refs): col, gid,
+# row, bias, fslot, fcol, and the previous layer's bias table with the
+# residual.
 def _unit_map(u, w, *_):
     return (u, 0)
 
 
-def _unit_map3(u, w, *_):
-    return (u, 0, 0)
-
-
 def _specs(B, H, hdh, W):
-    """BlockSpecs by operand: masks (fed as [U*W, 1, B, B]), dst and src
-    coefficients, source features."""
+    """The forward's BlockSpecs by operand: masks (fed as [U*W, 1, B, B]),
+    dst and src coefficients, source features."""
 
     def mask_map(u, w, col, gid, row, bias, fslot, fcol, *_):
         return (fslot[u * W + w], 0, 0, 0)
@@ -365,32 +398,6 @@ def _n_types(masks, edge_bias):
     return 0 if masks.dtype == jnp.bool_ else int(edge_bias.shape[0])
 
 
-def _prefetch_and_inputs(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
-                         h_src, edge_bias, attn_prev, B, W):
-    """Scalar-prefetch operands, and the block inputs before the backward's
-    own (the previous layer's thd/ths/lse follow them, see ``_prev``)."""
-    U = col_index.shape[0]
-    ns_pad = h_src.shape[0]
-    prefetch = [col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index)]
-    if attn_prev is not None:
-        prefetch.append(attn_prev.bias)
-    inputs = [masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
-              h_src.reshape(ns_pad, -1)]
-    return prefetch, inputs
-
-
-def _prev(attn_prev, specs, B, H):
-    """Block inputs and specs of the previous layer's attention."""
-    return (
-        [attn_prev.theta_dst, attn_prev.theta_src.swapaxes(1, 2), attn_prev.lse],
-        [specs[1], specs[2], pl.BlockSpec((B, H), _unit_map)],
-    )
-
-
-def _compiler_params():
-    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
-
-
 def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
               h_src, edge_bias, attn_prev, opts):
     U, W = col_index.shape
@@ -400,9 +407,11 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
     hdh = H * Dh
     residual = opts.beta is not None
 
-    prefetch, inputs = _prefetch_and_inputs(
-        col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src, edge_bias,
-        attn_prev, B, W)
+    prefetch = [col_index, graph_id, dst_row, edge_bias, *_fill_dead(col_index)]
+    if residual:
+        prefetch.append(attn_prev.bias)
+    inputs = [masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
+              h_src.reshape(ns_pad, hdh)]
     specs = _specs(B, H, hdh, W)
     in_specs = list(specs)
     out_specs = [pl.BlockSpec((B, hdh), _unit_map)]
@@ -413,9 +422,9 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
         pltpu.VMEM((B, H), jnp.float32),
     ]
     if residual:
-        more, more_specs = _prev(attn_prev, specs, B, H)
-        inputs += more
-        in_specs += more_specs
+        # the previous layer's thd, ths and lse
+        inputs += [attn_prev.theta_dst, attn_prev.theta_src.swapaxes(1, 2), attn_prev.lse]
+        in_specs += [specs[1], specs[2], pl.BlockSpec((B, H), _unit_map)]
         out_specs.append(pl.BlockSpec((B, hdh), _unit_map))
         out_shape.append(jax.ShapeDtypeStruct((U * B, hdh), jnp.float32))
         scratch.append(pltpu.VMEM((B, hdh), jnp.float32))
@@ -436,7 +445,7 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
         ),
         grid_spec=grid_spec,
         out_shape=tuple(out_shape),
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=opts.interpret,
         name="seg_gat_agg_multigraph",
     )(*prefetch, *inputs)
@@ -446,6 +455,11 @@ def _fwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
 
 def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
               h_src, edge_bias, attn_prev, g_out, lse, delta, opts):
+    """The backward launch over the grid of ``_bwd_order``.  Returns
+    d_theta_src [G, H, Ns_pad], d_h_src [Ns_pad, H*Dh] and, with typed
+    tiles, the bias-table partials [T*H, Ns_pad] (else None), whose blocks
+    no live slot references hold whatever the output buffer held; and the
+    per-slot d_theta_dst [U*W, H, B], unwritten at dead slots."""
     U, W = col_index.shape
     B = masks.shape[-1]
     G, ns_pad, H = theta_src.shape
@@ -453,45 +467,66 @@ def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
     hdh = H * Dh
     n_types = _n_types(masks, edge_bias)
 
-    def slot_map(u, w, *_):
-        return (u, w, 0, 0)
+    prefetch = [graph_id, dst_row, edge_bias, *_bwd_order(col_index, graph_id, G)]
+    if attn_prev is not None:
+        prefetch.append(attn_prev.bias)
 
-    prefetch, inputs = _prefetch_and_inputs(
-        col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src, edge_bias,
-        attn_prev, B, W)
-    specs = _specs(B, H, hdh, W)
-    inputs += [g_out.reshape(U * B, hdh), lse, delta]
-    in_specs = list(specs) + [
-        pl.BlockSpec((B, hdh), _unit_map),
-        pl.BlockSpec((B, H), _unit_map),
-        pl.BlockSpec((B, H), _unit_map),
+    # index maps take (step, gid, row, bias, slot, unit, col, flags, ...)
+    def mask_map(i, gid, row, bias, slot, *_):
+        return (slot[i], 0, 0, 0)
+
+    def thd_map(i, gid, row, bias, slot, unit, *_):
+        return (gid[unit[i]], row[unit[i]], 0)
+
+    def ths_map(i, gid, row, bias, slot, unit, col, *_):
+        return (gid[unit[i]], 0, col[i])
+
+    def col_map(i, gid, row, bias, slot, unit, col, *_):
+        return (col[i], 0)
+
+    def table_map(i, gid, row, bias, slot, unit, col, *_):
+        return (0, col[i])
+
+    def unit_map(i, gid, row, bias, slot, unit, *_):
+        return (unit[i], 0)
+
+    def slot_map(i, gid, row, bias, slot, *_):
+        return (slot[i], 0, 0)
+
+    inputs = [masks.reshape(U * W, 1, B, B), theta_dst, theta_src.swapaxes(1, 2),
+              h_src.reshape(ns_pad, hdh), g_out.reshape(U * B, hdh), lse, delta]
+    in_specs = [
+        pl.BlockSpec((1, 1, B, B), mask_map),
+        pl.BlockSpec((1, B, H), thd_map),
+        pl.BlockSpec((1, H, B), ths_map),
+        pl.BlockSpec((B, hdh), col_map),
+        pl.BlockSpec((B, hdh), unit_map),
+        pl.BlockSpec((B, H), unit_map),
+        pl.BlockSpec((B, H), unit_map),
     ]
     if attn_prev is not None:
-        more, more_specs = _prev(attn_prev, specs, B, H)
-        inputs += more
-        in_specs += more_specs
+        inputs += [attn_prev.theta_dst, attn_prev.theta_src.swapaxes(1, 2), attn_prev.lse]
+        in_specs += [in_specs[1], in_specs[2], pl.BlockSpec((B, H), unit_map)]
     out_specs = [
-        pl.BlockSpec((1, 1, H, B), slot_map),
-        pl.BlockSpec((1, 1, B, hdh), slot_map),
-        pl.BlockSpec((B, H), _unit_map),
+        pl.BlockSpec((1, H, B), ths_map),
+        pl.BlockSpec((B, hdh), col_map),
+        pl.BlockSpec((1, H, B), slot_map),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((U, W, H, B), jnp.float32),
-        jax.ShapeDtypeStruct((U, W, B, hdh), jnp.float32),
-        jax.ShapeDtypeStruct((U * B, H), jnp.float32),
+        jax.ShapeDtypeStruct((G, H, ns_pad), jnp.float32),
+        jax.ShapeDtypeStruct((ns_pad, hdh), jnp.float32),
+        jax.ShapeDtypeStruct((U * W, H, B), jnp.float32),
     ]
-    scratch = [pltpu.VMEM((B, H), jnp.float32)]
     if n_types:
-        out_specs.append(pl.BlockSpec((1, n_types * H, B), _unit_map3))
-        out_shape.append(jax.ShapeDtypeStruct((U, n_types * H, B), jnp.float32))
-        scratch.append(pltpu.VMEM((n_types * H, B), jnp.float32))
+        out_specs.append(pl.BlockSpec((n_types * H, B), table_map))
+        out_shape.append(jax.ShapeDtypeStruct((n_types * H, ns_pad), jnp.float32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(U, W),
+        grid=(U * W,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((B, -(-H // 128) * 128), jnp.float32)],
     )
     outs = pl.pallas_call(
         functools.partial(
@@ -500,13 +535,12 @@ def _bwd_call(col_index, graph_id, dst_row, masks, theta_src, theta_dst,
         ),
         grid_spec=grid_spec,
         out_shape=tuple(out_shape),
-        compiler_params=_compiler_params(),
+        # consecutive steps revisit the output blocks of a run
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=opts.interpret,
         name="seg_gat_agg_multigraph_bwd",
     )(*prefetch, *inputs)
-    dths, dhs, dthd = outs[:3]
-    dtb = outs[3] if n_types else None
-    return dths.swapaxes(2, 3), dhs.reshape(U, W, B, H, Dh), dthd, dtb
+    return outs[0], outs[1], outs[3] if n_types else None, outs[2]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
@@ -539,45 +573,42 @@ def _multigraph_bwd(opts, res, cts):
     rd = theta_dst.shape[1] // B
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    dths_blk, dhs_blk, dthd_units, dtb = _bwd_call(
+    dths, d_h_src, dtb, dthd_slots = _bwd_call(
         col_index, graph_id, dst_row, masks, theta_src, theta_dst, h_src,
         edge_bias, attn_prev, g, lse, delta, opts,
     )
 
-    # GSF-like scatter of the per-(unit, slot) partials onto the shared
-    # src vertex space.  Padding slots (col < 0) carry exact zeros (the
-    # kernel writes them), but mask them anyway so their block-0 landing
-    # spot stays clean.
+    # src blocks that no live slot references were never visited: zeros
     flat_col = col_index.reshape(U * W)
-    live_blk = flat_col >= 0
-    col_safe = jnp.maximum(flat_col, 0)
-    gid_blk = jnp.repeat(graph_id, W)
-
-    dths_blk = jnp.where(live_blk[:, None, None], dths_blk.reshape(U * W, B, H), 0.0)
-    d_theta_src = jax.ops.segment_sum(
-        dths_blk, gid_blk * nblk + col_safe, num_segments=G * nblk
-    ).reshape(G, ns_pad, H)
-
-    dhs_blk = jnp.where(
-        live_blk[:, None, None, None], dhs_blk.reshape(U * W, B, H, Dh), 0.0
+    live = flat_col >= 0
+    seen = (
+        jnp.zeros((G, nblk), bool)
+        .at[jnp.repeat(graph_id, W), jnp.where(live, flat_col, nblk)]
+        .set(True, mode="drop")
     )
-    d_h_src = jax.ops.segment_sum(
-        dhs_blk, col_safe, num_segments=nblk
+    dths = jnp.where(seen[:, None, :, None], dths.reshape(G, H, nblk, B), 0.0)
+    d_theta_src = dths.reshape(G, H, ns_pad).swapaxes(1, 2)
+    d_h_src = jnp.where(
+        seen.any(axis=0)[:, None, None], d_h_src.reshape(nblk, B, H * Dh), 0.0
     ).reshape(ns_pad, H, Dh)
 
+    dthd_units = jnp.where(live[:, None, None], dthd_slots, 0.0).reshape(U, W, H, B).sum(1)
     d_theta_dst = (
         jnp.zeros((G, rd, B, H), jnp.float32)
         .at[graph_id, dst_row]
-        .add(dthd_units.reshape(U, B, H))
+        .add(dthd_units.swapaxes(1, 2))
         .reshape(G, rd * B, H)
     )
     if dtb is None:
         # bias enters every logit additively: its gradient is the total dpre
-        # mass per graph, already summed over dst inside dths_blk.
-        d_bias = jax.ops.segment_sum(dths_blk.sum(axis=1), gid_blk, num_segments=G)
+        # mass per graph, already summed over dst inside d_theta_src.
+        d_bias = dths.sum(axis=(2, 3))
     else:
         # typed tiles: the kernel summed dpre per (type, head) over dst
-        d_bias = dtb.sum(axis=(0, 2)).reshape(edge_bias.shape)
+        n_types = edge_bias.shape[0]
+        d_bias = jnp.where(
+            seen.any(axis=0)[None, None, :, None], dtb.reshape(n_types, H, nblk, B), 0.0
+        ).sum(axis=(2, 3))
 
     f0 = lambda x: np.zeros(x.shape, jax.dtypes.float0)
     return (

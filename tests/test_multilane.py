@@ -13,6 +13,7 @@ random unit tables and degenerate shapes (empty graph, single edge,
 all-padded block) — degenerate rows must produce exact zeros, never NaN.
 """
 import dataclasses
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -123,27 +124,125 @@ def test_multilane_kernel_backend_matches_reference(dblp_setup, lanes):
         np.testing.assert_allclose(k, r, rtol=1e-5, atol=1e-5)
 
 
-def test_multigraph_bwd_dead_slot_partials_are_zero(dblp_setup):
-    """The backward writes exact zeros into the raw d_theta_src / d_h_src
-    partial blocks of every dead slot, none stale."""
-    from repro.kernels.seg_gat_agg_multigraph import _bwd_call, _fwd_call, _Opts
+def _dense_na(col, gid, row, masks, ths, thd, hs, bias, slope):
+    """Block reference of one multigraph launch, dense over each unit's
+    slots: [U, B, H, Dh].  Typed tiles (int8, type + 1) look their bias up
+    in a [T, H] table, boolean masks take one bias per graph."""
+    B = masks.shape[-1]
+    src = jnp.maximum(col, 0)[..., None] * B + jnp.arange(B)     # [U, W, Bs]
+    dst = row[:, None] * B + jnp.arange(B)                        # [U, Bd]
+    live = (masks != 0) & (col >= 0)[..., None, None]             # [U, W, Bd, Bs]
+    if masks.dtype == jnp.bool_:
+        b = bias[gid][:, None, None, None, :]
+    else:
+        b = bias[jnp.maximum(masks.astype(jnp.int32) - 1, 0)]
+    pre = (thd[gid[:, None], dst][:, None, :, None] + ths[gid[:, None, None], src][:, :, None]
+           + b)                                                   # [U, W, Bd, Bs, H]
+    logit = jnp.where(live[..., None], jnp.where(pre >= 0, pre, slope * pre), -jnp.inf)
+    m = jax.lax.stop_gradient(logit.max(axis=(1, 3), keepdims=True))
+    e = jnp.where(live[..., None], jnp.exp(logit - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
+    p = e / jnp.maximum(e.sum(axis=(1, 3), keepdims=True), 1e-30)
+    return jnp.einsum("uwdsh,uwshe->udhe", p, hs[src])
 
-    batches, ths, thd, hs = dblp_setup
-    plan = build_multilane_plan(batches, 4)
-    lanes, units, w = plan.col_index.shape
-    col = plan.col_index.reshape(lanes * units, w)
-    args = (col, plan.graph_id.reshape(-1), plan.dst_row.reshape(-1),
-            plan.masks.reshape(lanes * units, w, plan.block, plan.block), ths, thd, hs,
-            jnp.zeros((len(batches), ths.shape[-1]), jnp.float32), None)
-    opts = _Opts(leaky_slope=0.2, interpret=True, beta=None)
-    out, _, lse = _fwd_call(*args, opts)
-    g = jnp.ones_like(out)
-    delta = jnp.sum(g * out, axis=-1)
-    dths, dhs, _, _ = _bwd_call(*args, g, lse, delta, opts)
-    dead = np.asarray(col) < 0
-    assert np.abs(np.asarray(dths)[dead]).max() == 0.0
-    assert np.abs(np.asarray(dhs)[dead]).max() == 0.0
-    assert np.abs(np.asarray(dhs)[~dead]).max() > 0.0
+
+def _bwd_case(name, dblp_setup):
+    """Unit tables and operands of one backward case: (col, gid, row,
+    masks, ths, thd, hs, bias, beta)."""
+    if name == "lanes":  # HAN's graphs over 4 lanes, flattened into one launch
+        batches, ths, thd, hs = dblp_setup
+        plan = build_multilane_plan(batches, 4)
+        lanes, units, w = plan.col_index.shape
+        bias = np.random.default_rng(4).standard_normal((len(batches), ths.shape[-1]))
+        return (plan.col_index.reshape(-1, w), plan.graph_id.reshape(-1),
+                plan.dst_row.reshape(-1),
+                plan.masks.reshape(lanes * units, w, plan.block, plan.block),
+                ths, thd, hs, jnp.asarray(bias, jnp.float32), None)
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    B, U, W, H, Dh, nblk = 8, 6, 3, 2, 8, 5
+    typed = name.startswith("typed")
+    G = 1 if typed else 3
+    # the last block is referenced by no slot
+    col = np.stack([rng.permutation(nblk - 1)[:W] for _ in range(U)]).astype(np.int32)
+    col[rng.random((U, W)) < 0.3] = -1
+    dead = {"dead-unit-first": 0, "dead-unit-middle": U // 2, "dead-unit-last": U - 1}
+    if name in dead:
+        col[dead[name]] = -1
+    gid = rng.integers(0, G, U).astype(np.int32)
+    row = rng.integers(0, nblk, U).astype(np.int32)
+    edge = rng.random((U, W, B, B)) < 0.4
+    masks = np.where(edge, rng.integers(1, 4, edge.shape), 0).astype(np.int8) if typed else edge
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+    ths, thd, hs = f(G, nblk * B, H), f(G, nblk * B, H), f(nblk * B, H, Dh)
+    bias = f(3, H) if typed else f(G, H)
+    beta = 0.05 if name == "typed+residual" else None
+    return (jnp.asarray(col), jnp.asarray(gid), jnp.asarray(row), jnp.asarray(masks),
+            ths, thd, hs, bias, beta)
+
+
+@pytest.mark.parametrize("case", [
+    "dead-unit-first", "dead-unit-middle", "dead-unit-last", "unreferenced-block",
+    "lanes", "typed", "typed+residual",
+])
+def test_multigraph_bwd_source_order_matches_block_autodiff(dblp_setup, case):
+    """The backward walks the slots sorted by (col, graph) and sums
+    d_h_src and d_theta_src over each run in VMEM.  Its VJP matches
+    autodiff of the dense block reference for all-dead units anywhere in
+    the grid, col runs across graphs and lanes, and typed tiles with and
+    without the attention residual; the rows of a source block that no
+    live slot references (never visited) are exact zeros."""
+    from repro.kernels.seg_gat_agg_multigraph import Attention, seg_gat_agg_multigraph
+
+    col, gid, row, masks, ths, thd, hs, bias, beta = _bwd_case(case, dblp_setup)
+    B = masks.shape[-1]
+    colh, gidh = np.asarray(col), np.asarray(gid)
+    slot_gid = np.broadcast_to(gidh[:, None], colh.shape)
+    if case.startswith("dead-unit"):
+        assert (colh < 0).all(axis=1).any()
+    if case == "lanes":  # a col run spans units of several graphs and lanes
+        lane = np.broadcast_to(np.arange(colh.shape[0])[:, None] // (colh.shape[0] // 4), colh.shape)
+        assert any(
+            len(set(slot_gid[colh == c])) > 1 and len(set(lane[colh == c])) > 1
+            for c in set(colh[colh >= 0].tolist())
+        )
+    prev, kw = None, {}
+    if beta is not None:
+        rng = np.random.default_rng(9)
+        pths, pthd, pbias = (jnp.asarray(rng.standard_normal(x.shape).astype(np.float32))
+                             for x in (ths, thd, bias))
+        _, plse = seg_gat_agg_multigraph(col, gid, row, masks, pths, pthd, hs, pbias,
+                                         interpret=True, return_lse=True)
+        prev, kw = Attention(pths, pthd, pbias, plse), {"beta": beta}
+
+    def f_kernel(a, b, c, d):
+        out = seg_gat_agg_multigraph(col, gid, row, masks, a, b, c, d, prev,
+                                     interpret=True, **kw)
+        return out.reshape(-1, B, *c.shape[1:])
+
+    def f_ref(a, b, c, d):
+        out = _dense_na(col, gid, row, masks, a, b, c, d, 0.2)
+        if beta is None:
+            return out
+        return (1 - beta) * out + beta * _dense_na(col, gid, row, masks, prev.theta_src,
+                                                   prev.theta_dst, c, prev.bias, 0.2)
+
+    args = (ths, thd, hs, bias)
+    out_k, vjp_k = jax.vjp(f_kernel, *args)
+    out_r, vjp_r = jax.vjp(f_ref, *args)
+    cot = jnp.asarray(np.random.default_rng(1).standard_normal(out_k.shape).astype(np.float32))
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), rtol=1e-4, atol=1e-5)
+    grads_k = [np.asarray(x) for x in vjp_k(cot)]
+    for k, r in zip(grads_k, vjp_r(cot)):
+        np.testing.assert_allclose(k, np.asarray(r), rtol=1e-4, atol=1e-5)
+
+    nblk = hs.shape[0] // B
+    seen = np.zeros((ths.shape[0], nblk), bool)
+    seen[slot_gid[colh >= 0], colh[colh >= 0]] = True
+    d_ths = grads_k[0].reshape(ths.shape[0], nblk, B, -1)
+    d_hs = grads_k[2].reshape(nblk, B, -1)
+    assert np.all(d_ths[~seen] == 0.0) and np.all(d_hs[~seen.any(axis=0)] == 0.0)
+    if case == "unreferenced-block":
+        assert not seen.any(axis=0).all()
+    assert np.abs(d_hs).max() > 0.0
 
 
 @pytest.mark.parametrize("lanes", [1, 2, 4, 8])
@@ -154,6 +253,7 @@ def test_plan_counts_live_slots_per_lane(dblp_setup, lanes):
     assert plan.na_slots() == {
         "grid": col.shape[1] * col.shape[2],
         "live": [int((col[l] >= 0).sum()) for l in range(lanes)],
+        "src_runs": [len(np.unique(col[l][col[l] >= 0])) for l in range(lanes)],
     }
 
 
