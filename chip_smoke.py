@@ -26,7 +26,7 @@ that every chip holds its shard.
 Differently ordered formulations differ by more than f32 rounding on a
 TPU (f32 matmuls run as bf16 passes by default), so results are compared by
 normwise relative error, max|a - b| / max|b| <= 2e-2.  Times printed
-are smoke observations, not benchmarks.  Any failed check raises; the
+are smoke observations, not benchmark results.  Any failed check raises; the
 last line printed on success is one JSON object naming the device.
 """
 from __future__ import annotations

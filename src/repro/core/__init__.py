@@ -7,7 +7,6 @@ from .fusion import (
     NABackend,
     SemanticGraphBatch,
     batch_semantic_graph,
-    build_unit_tables,
     mean_aggregate,
     neighbor_aggregate,
     neighbor_aggregate_multi,
@@ -30,7 +29,6 @@ __all__ = [
     "NABackend",
     "SemanticGraphBatch",
     "batch_semantic_graph",
-    "build_unit_tables",
     "mean_aggregate",
     "neighbor_aggregate",
     "neighbor_aggregate_multi",

@@ -1,4 +1,4 @@
-"""Bound-aware stage fusion — execution paths for the NA stage (paper §4.1).
+"""Bound-aware stage fusion — NA backends and the formats they read (paper §4.1).
 
 Interchangeable NA backends with identical semantics:
 
@@ -13,6 +13,11 @@ Interchangeable NA backends with identical semantics:
   expressed as VMEM-tiled MXU work, all semantic graphs of a layer in one
   launch (one graph is the G=1 case).
 * ``FUSED_FP`` — the same launch with the FP stage pulled inside.
+
+SEGMENT and BLOCK run here, one semantic graph at a time.  The kernel
+backends launch only through a work-unit plan (core/multilane.py:
+``build_multilane_plan`` and ``multilane_na``), which is where an
+``NABackend`` names its kernel.
 
 A compiled Pallas backend runs only on a TPU; its ``*_INTERPRET`` twin
 runs the same kernel body under the Pallas interpreter (CPU validation).
@@ -55,16 +60,6 @@ class NABackend(enum.Enum):
     FUSED_FP = "fused_fp"
     FUSED_FP_INTERPRET = "fused_fp_interpret"
 
-
-_MULTIGRAPH_BACKENDS = (NABackend.MULTIGRAPH, NABackend.MULTIGRAPH_INTERPRET)
-_FUSED_FP_BACKENDS = (NABackend.FUSED_FP, NABackend.FUSED_FP_INTERPRET)
-# materialized-path equivalent of each fused backend (e.g. for serving's
-# FP-cache-hit bypass: the projected table already exists, so re-projecting
-# inside the kernel would waste the cache)
-_FUSED_TO_MULTIGRAPH = {
-    NABackend.FUSED_FP: NABackend.MULTIGRAPH,
-    NABackend.FUSED_FP_INTERPRET: NABackend.MULTIGRAPH_INTERPRET,
-}
 
 # compiled Pallas backend name (NABackend value or multilane backend
 # string) -> the interpret-mode name that runs the same kernel body
@@ -114,6 +109,9 @@ class SemanticGraphBatch:
     # typed graphs (the union view): each padded edge's type, and the names
     edge_type: jnp.ndarray | None = None
     edge_type_names: tuple[str, ...] = ()
+    # edges per dst-block row, counted on the host while the block CSR
+    # was built there (lane scheduling's row costs)
+    row_edges: tuple[int, ...] = ()
 
     @property
     def num_dst_pad(self) -> int:
@@ -123,14 +121,15 @@ class SemanticGraphBatch:
 
     def row_edge_counts(self) -> np.ndarray:
         """#edges per dst-block row (workload units for lane scheduling)."""
-        assert self.masks is not None
-        return np.asarray((self.masks != 0).sum(axis=(1, 2, 3)), np.int64)
+        assert self.col_index is not None, "batch built without block CSR"
+        assert len(self.row_edges) == self.col_index.shape[0], "row edges not counted"
+        return np.asarray(self.row_edges, np.int64)
 
 
 _SGB_ARRAY_FIELDS = ("src", "dst", "valid", "col_index", "masks", "edge_type")
 _SGB_META_FIELDS = (
     "name", "src_type", "dst_type", "num_src", "num_dst", "num_edges", "path_types", "block",
-    "edge_type_names",
+    "edge_type_names", "row_edges",
 )
 
 
@@ -167,7 +166,10 @@ def batch_semantic_graph(
             kw.update(edge_type=jnp.asarray(pe.edge_type))
     if with_blocks:
         bc = to_block_csr(sg, block=block)
-        kw.update(col_index=jnp.asarray(bc.col_index), masks=jnp.asarray(bc.masks), block=block)
+        kw.update(
+            col_index=jnp.asarray(bc.col_index), masks=jnp.asarray(bc.masks), block=block,
+            row_edges=tuple(int(np.count_nonzero(m)) for m in bc.masks),
+        )
     return SemanticGraphBatch(
         name=sg.name,
         src_type=sg.src_type,
@@ -239,15 +241,9 @@ def neighbor_aggregate(
     leaky_slope: float = 0.2,
     edge_bias: jnp.ndarray | float = 0.0,
 ) -> jnp.ndarray:
-    """Attention NA with the chosen backend.  Returns [num_dst, H, Dh]."""
-    if backend in _MULTIGRAPH_BACKENDS:
-        bias = edge_bias
-        if not (hasattr(bias, "ndim") and bias.ndim == 2):
-            bias = jnp.broadcast_to(jnp.asarray(bias, jnp.float32), (1, theta_src.shape[-1]))
-        return neighbor_aggregate_multi(
-            [batch], theta_src[None], theta_dst[None], h_src,
-            backend=backend, leaky_slope=leaky_slope, edge_bias=bias,
-        )[0]
+    """Attention NA of one semantic graph, SEGMENT or BLOCK.  Returns
+    [num_dst, H, Dh].  The kernel backends run through a plan
+    (core/multilane.py)."""
     if backend is NABackend.SEGMENT:
         assert batch.src is not None, "batch built without edge list"
         return stages.segment_softmax_aggregate(
@@ -255,7 +251,7 @@ def neighbor_aggregate(
             batch.num_dst, leaky_slope=leaky_slope, edge_bias=edge_bias,
         )
 
-    assert backend is NABackend.BLOCK, f"{backend} needs neighbor_aggregate_multi"
+    assert backend is NABackend.BLOCK, f"{backend} runs through core.multilane"
     assert batch.col_index is not None, "batch built without block CSR"
     ns_pad = ((batch.num_src + batch.block - 1) // batch.block) * batch.block
     out = stages.block_softmax_aggregate(
@@ -266,129 +262,26 @@ def neighbor_aggregate(
     return out[: batch.num_dst]
 
 
-def build_unit_tables(batches: list[SemanticGraphBatch]):
-    """Stack the block-CSR rows of several semantic graphs into the flat
-    (col_index, graph_id, dst_row, masks) work-unit layout of
-    kernels/seg_gat_agg_multigraph: one unit per (graph, dst-block row),
-    col widths padded to the max across graphs.
-
-    Requires all graphs to share the dst vertex space and block size
-    (HAN's metapath graphs do).  Host-side; build once per layer.
-    """
-    assert batches, "no semantic graphs"
-    b = batches[0].block
-    n_rows = int(batches[0].col_index.shape[0])
-    for bb in batches:
-        assert bb.col_index is not None, "batch built without block CSR"
-        assert bb.block == b and int(bb.col_index.shape[0]) == n_rows
-
-    w_max = max(int(bb.col_index.shape[1]) for bb in batches)
-    g_n = len(batches)
-    col = np.full((g_n, n_rows, w_max), -1, np.int32)
-    masks = np.zeros((g_n, n_rows, w_max, b, b), bool)
-    for i, bb in enumerate(batches):
-        wg = int(bb.col_index.shape[1])
-        col[i, :, :wg] = np.asarray(bb.col_index)
-        masks[i, :, :wg] = np.asarray(bb.masks)
-    gid = np.repeat(np.arange(g_n, dtype=np.int32), n_rows)
-    row = np.tile(np.arange(n_rows, dtype=np.int32), g_n)
-    return (
-        jnp.asarray(col.reshape(g_n * n_rows, w_max)),
-        jnp.asarray(gid),
-        jnp.asarray(row),
-        jnp.asarray(masks.reshape(g_n * n_rows, w_max, b, b)),
-    )
-
-
 def neighbor_aggregate_multi(
     batches: list[SemanticGraphBatch],
-    theta_src: jnp.ndarray | None,  # [G, Ns, H]   (None with FUSED_FP)
-    theta_dst: jnp.ndarray | None,  # [G, Nd, H]   (None with FUSED_FP)
-    h_src: jnp.ndarray | None,      # [Ns, H, Dh]  (None with FUSED_FP)
+    theta_src: jnp.ndarray,  # [G, Ns, H]
+    theta_dst: jnp.ndarray,  # [G, Nd, H]
+    h_src: jnp.ndarray,      # [Ns, H, Dh]
     *,
-    backend: NABackend = NABackend.MULTIGRAPH_INTERPRET,
+    backend: NABackend = NABackend.SEGMENT,
     leaky_slope: float = 0.2,
     edge_bias: jnp.ndarray | None = None,  # [G, H]
-    unit_tables: tuple | None = None,
-    fp: FusedFPInputs | None = None,
 ) -> jnp.ndarray:
-    """NA for ALL semantic graphs of a layer at once.  Returns
-    [G, num_dst, H, Dh].
-
-    With a MULTIGRAPH backend this is a single fused Pallas launch (one
-    forward and, under autodiff, one backward kernel for the whole layer);
-    any other backend falls back to a per-graph loop of
-    ``neighbor_aggregate`` — same semantics, G separate dispatches.
-    ``unit_tables`` (from :func:`build_unit_tables`) may be passed to skip
-    the host-side stacking inside jitted callers.
-
-    With a FUSED_FP backend the FP stage runs *inside* the launch: pass
-    ``fp=FusedFPInputs(...)`` (raw features + projection/attention params)
-    and leave theta_src/theta_dst/h_src as None — no projected tensor is
-    ever materialized in HBM (DESIGN.md §10).
-    """
-    require_tpu(backend.value)
-    if backend in _FUSED_FP_BACKENDS:
-        if fp is None:
-            raise ValueError(
-                "FUSED_FP backends take fp=FusedFPInputs (raw features + "
-                "weight tables) in place of theta_src/theta_dst/h_src"
-            )
-        from ..kernels.seg_gat_agg_fused_fp import seg_gat_agg_fused_fp
-
-        b0 = batches[0]
-        assert b0.num_src == b0.num_dst, (
-            "fused FP+NA streams ONE raw-feature table for both src and dst "
-            "tiles; src and dst must share the vertex space (HAN's "
-            "target-type metapath graphs do)"
+    """NA for all semantic graphs of a layer, one ``neighbor_aggregate``
+    per graph (SEGMENT or BLOCK).  Returns [G, num_dst, H, Dh]."""
+    return jnp.stack([
+        neighbor_aggregate(
+            bb, theta_src[i], theta_dst[i], h_src[: bb.num_src],
+            backend=backend, leaky_slope=leaky_slope,
+            edge_bias=0.0 if edge_bias is None else edge_bias[i],
         )
-        b = b0.block
-        nd = b0.num_dst
-        nd_pad = b0.num_dst_pad
-        ns_pad = ((b0.num_src + b - 1) // b) * b
-        if unit_tables is None:
-            unit_tables = build_unit_tables(batches)
-        col, gid, row, masks = unit_tables
-        x_pad = _pad_rows(fp.x, max(ns_pad, nd_pad))
-        g_n = len(batches)
-        out = seg_gat_agg_fused_fp(
-            col, gid, row, fp.wsel, masks, x_pad, fp.w, fp.b,
-            fp.a_src, fp.a_dst, edge_bias,
-            leaky_slope=leaky_slope,
-            interpret=backend is NABackend.FUSED_FP_INTERPRET,
-        )  # [G*R*B, H, Dh] — units are g-major, rows in order
-        return out.reshape(g_n, nd_pad, *out.shape[1:])[:, :nd]
-
-    if backend not in _MULTIGRAPH_BACKENDS:
-        return jnp.stack([
-            neighbor_aggregate(
-                bb, theta_src[i], theta_dst[i], h_src[: bb.num_src],
-                backend=backend, leaky_slope=leaky_slope,
-                edge_bias=0.0 if edge_bias is None else edge_bias[i],
-            )
-            for i, bb in enumerate(batches)
-        ])
-
-    from ..kernels.seg_gat_agg_multigraph import seg_gat_agg_multigraph
-
-    b = batches[0].block
-    nd = batches[0].num_dst
-    nd_pad = batches[0].num_dst_pad
-    ns_pad = ((batches[0].num_src + b - 1) // b) * b
-    if unit_tables is None:
-        unit_tables = build_unit_tables(batches)
-    col, gid, row, masks = unit_tables
-
-    th_s = _pad_rows(theta_src.swapaxes(0, 1), ns_pad).swapaxes(0, 1)
-    th_d = _pad_rows(theta_dst.swapaxes(0, 1), nd_pad).swapaxes(0, 1)
-    hs = _pad_rows(h_src, ns_pad)
-    g_n = len(batches)
-    out = seg_gat_agg_multigraph(
-        col, gid, row, masks, th_s, th_d, hs, edge_bias,
-        leaky_slope=leaky_slope,
-        interpret=backend is NABackend.MULTIGRAPH_INTERPRET,
-    )  # [G*R*B, H, Dh] — units are g-major, rows in order
-    return out.reshape(g_n, nd_pad, *out.shape[1:])[:, :nd]
+        for i, bb in enumerate(batches)
+    ])
 
 
 def mean_aggregate(

@@ -12,6 +12,12 @@ All units share one static shape (W block slots, padded with -1 columns),
 so lane execution is a single dense program regardless of how irregular
 the semantic graphs are — the TPU answer to the crossbar/scheduler
 machinery of the accelerator.
+
+This module is the one door to the NA kernels: every launch of
+kernels/seg_gat_agg_multigraph and kernels/seg_gat_agg_fused_fp goes
+through a plan from :func:`build_multilane_plan` (one lane where nothing
+is split: HAN's single-chip forward, R-GAT, serving) and
+:func:`multilane_na`, :func:`multilane_na_sharded` or :func:`typed_na`.
 """
 from __future__ import annotations
 
@@ -23,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from .fusion import FusedFPInputs, SemanticGraphBatch, require_tpu
+from .fusion import FusedFPInputs, NABackend, SemanticGraphBatch, require_tpu
 from .scheduling import LanePlan, lane_assignment, naive_lane_assignment
 
 NEG_INF = -1e30
@@ -45,10 +51,6 @@ class MultiLanePlan:
     num_graphs: int
     n_dst_blocks: int       # per graph (shared dst space)
     lane_plan: LanePlan | None  # host-side scheduling metadata (not traced)
-    # host-side: slots with col >= 0 per lane, of U * W each, and the
-    # distinct source blocks among them per lane (not traced)
-    live_slots: np.ndarray | None = None
-    src_runs: np.ndarray | None = None
 
     @property
     def num_lanes(self) -> int:
@@ -58,17 +60,19 @@ class MultiLanePlan:
         """NA grid slots per lane and how many of them are live (the
         kernel fetches nothing and runs no body for the rest), and the
         source blocks the live slots reference: the backward visits each
-        in one run of steps and writes its d_h_src block once."""
-        _, units, w = self.col_index.shape
-        return {"grid": int(units * w), "live": self.live_slots.tolist(),
-                "src_runs": self.src_runs.tolist()}
+        in one run of steps and writes its d_h_src block once.  Counted
+        from a host copy of ``col_index`` when asked."""
+        col = np.asarray(self.col_index)
+        _, units, w = col.shape
+        return {"grid": int(units * w), "live": [int((c >= 0).sum()) for c in col],
+                "src_runs": [int(np.unique(c[c >= 0]).size) for c in col]}
 
 
 def _flatten_unflatten():
     arr = ("col_index", "masks", "graph_id", "dst_row", "valid")
-    # lane_plan, live_slots and src_runs hold host-side numpy arrays; they
-    # must NOT ride in the pytree aux (aux must be hashable) — reconstructed
-    # copies carry None there, which multilane_na never reads.
+    # lane_plan holds host-side numpy arrays; it must NOT ride in the
+    # pytree aux (aux must be hashable) — reconstructed copies carry None
+    # there, which multilane_na never reads.
     meta = ("block", "num_graphs", "n_dst_blocks")
 
     def fl(p):
@@ -94,13 +98,20 @@ def build_multilane_plan(
 ) -> MultiLanePlan:
     """Partition the block rows of all semantic graphs onto lanes.
 
-    Requires all graphs to share the dst/src vertex space (HAN's metapath
-    graphs do); col widths are padded to the max across graphs.
+    This is the one builder of the kernels' work-unit tables: one unit
+    per (graph, dst-block row), col widths padded to the max across
+    graphs.  At one lane every unit sits on lane 0 in graph-major order,
+    so the tables are the graphs' rows stacked.  Each graph's tables are
+    copied to the host once.
+
+    Requires all graphs to share the dst vertex space (HAN's metapath
+    graphs do).  Host-side; build once per layer, or per serving step.
     """
     assert batches, "no semantic graphs"
     b = batches[0].block
     n_rows = int(batches[0].col_index.shape[0])
     for bb in batches:
+        assert bb.col_index is not None, "batch built without block CSR"
         assert bb.block == b and int(bb.col_index.shape[0]) == n_rows
 
     row_costs = [bb.row_edge_counts() for bb in batches]
@@ -110,39 +121,47 @@ def build_multilane_plan(
         else naive_lane_assignment(row_costs, num_lanes)
     )
 
+    # the graph-major stack: unit u is row u % n_rows of graph u // n_rows
+    g_n = len(batches)
     w_max = max(int(bb.col_index.shape[1]) for bb in batches)
-    lanes_units: list[list[int]] = [[] for _ in range(num_lanes)]
-    for u in range(plan.unit_graph.shape[0]):
-        lanes_units[int(plan.unit_lane[u])].append(u)
-    u_max = max(1, max(len(lu) for lu in lanes_units))
-
-    col = np.full((num_lanes, u_max, w_max), -1, np.int32)
-    masks = np.zeros((num_lanes, u_max, w_max, b, b), batches[0].masks.dtype)
-    gid = np.zeros((num_lanes, u_max), np.int32)
-    drow = np.zeros((num_lanes, u_max), np.int32)
-    valid = np.zeros((num_lanes, u_max), bool)
-    for l, lu in enumerate(lanes_units):
-        for j, u in enumerate(lu):
-            g = int(plan.unit_graph[u])
-            r = int(plan.unit_row[u])
-            wg = int(batches[g].col_index.shape[1])
-            col[l, j, :wg] = np.asarray(batches[g].col_index[r])
-            masks[l, j, :wg] = np.asarray(batches[g].masks[r])
-            gid[l, j] = g
-            drow[l, j] = r
-            valid[l, j] = True
+    col = np.full((g_n, n_rows, w_max), -1, np.int32)
+    masks = np.zeros((g_n, n_rows, w_max, b, b), batches[0].masks.dtype)
+    for i, bb in enumerate(batches):
+        wg = int(bb.col_index.shape[1])
+        col[i, :, :wg] = np.asarray(bb.col_index)
+        masks[i, :, :wg] = np.asarray(bb.masks)
+    col = col.reshape(1, g_n * n_rows, w_max)
+    masks = masks.reshape(1, g_n * n_rows, w_max, b, b)
+    gid = plan.unit_graph[None]
+    drow = plan.unit_row[None]
+    valid = np.ones_like(gid, bool)
+    if num_lanes > 1:
+        # each lane's units, in unit order, padded to the longest lane
+        lanes_units = [np.flatnonzero(plan.unit_lane == l) for l in range(num_lanes)]
+        u_max = max(1, max(lu.size for lu in lanes_units))
+        lane_col = np.full((num_lanes, u_max, w_max), -1, np.int32)
+        lane_masks = np.zeros((num_lanes, u_max, w_max, b, b), masks.dtype)
+        gid = np.zeros((num_lanes, u_max), np.int32)
+        drow = np.zeros((num_lanes, u_max), np.int32)
+        valid = np.zeros((num_lanes, u_max), bool)
+        for l, lu in enumerate(lanes_units):
+            lane_col[l, : lu.size] = col[0, lu]
+            lane_masks[l, : lu.size] = masks[0, lu]
+            gid[l, : lu.size] = plan.unit_graph[lu]
+            drow[l, : lu.size] = plan.unit_row[lu]
+            valid[l, : lu.size] = True
+        col, masks = lane_col, lane_masks
+    col_d, masks_d, gid_d, drow_d, valid_d = jax.device_put((col, masks, gid, drow, valid))
     return MultiLanePlan(
-        col_index=jnp.asarray(col),
-        masks=jnp.asarray(masks),
-        graph_id=jnp.asarray(gid),
-        dst_row=jnp.asarray(drow),
-        valid=jnp.asarray(valid),
+        col_index=col_d,
+        masks=masks_d,
+        graph_id=gid_d,
+        dst_row=drow_d,
+        valid=valid_d,
         block=b,
-        num_graphs=len(batches),
+        num_graphs=g_n,
         n_dst_blocks=n_rows,
         lane_plan=plan,
-        live_slots=(col >= 0).sum(axis=(1, 2)),
-        src_runs=np.array([np.unique(c[c >= 0]).size for c in col]),
     )
 
 
@@ -196,6 +215,27 @@ def _unit_na(
 
 
 MULTILANE_BACKENDS = ("reference", "kernel", "kernel_interpret", "fused_fp", "fused_fp_interpret")
+_FUSED_FP = ("fused_fp", "fused_fp_interpret")
+# The executor each kernel NABackend runs: the one place where a caller
+# that holds an NABackend (HAN's forward, R-GAT, the serving engine)
+# reaches a kernel.  SEGMENT and BLOCK run per graph (core/fusion.py).
+_PLAN_BACKEND = {
+    NABackend.MULTIGRAPH: "kernel",
+    NABackend.MULTIGRAPH_INTERPRET: "kernel_interpret",
+    NABackend.FUSED_FP: "fused_fp",
+    NABackend.FUSED_FP_INTERPRET: "fused_fp_interpret",
+}
+
+
+def _executor(backend: str | NABackend) -> str:
+    """The plan executor ``backend`` names.  A compiled kernel on a host
+    without a TPU is refused under the caller's own spelling, so the
+    error names the interpret twin the caller can ask for."""
+    name = _PLAN_BACKEND.get(backend, backend)
+    if name not in MULTILANE_BACKENDS:
+        raise ValueError(f"backend={backend!r}, expected one of {MULTILANE_BACKENDS}")
+    require_tpu(getattr(backend, "value", backend))
+    return name
 
 
 def multilane_na(
@@ -206,7 +246,7 @@ def multilane_na(
     *,
     edge_bias: jnp.ndarray | None = None,  # [G, H]
     leaky_slope: float = 0.2,
-    backend: str = "reference",
+    backend: str | NABackend = "reference",
     fp: FusedFPInputs | None = None,
 ) -> jnp.ndarray:
     """Run NA for all semantic graphs across lanes.
@@ -226,12 +266,13 @@ def multilane_na(
         ``fp=FusedFPInputs`` (raw features padded to [N_pad, Din] +
         projection/attention params) and leave the theta/h operands None;
         the FP stage runs inside the launch (DESIGN.md §10).
+    A kernel ``NABackend`` names its executor: ``MULTIGRAPH[_INTERPRET]``
+    is ``"kernel[_interpret]"``, ``FUSED_FP[_INTERPRET]`` is
+    ``"fused_fp[_interpret]"``.
     All backends scatter identically, so they agree to f32 tolerance.
     """
-    if backend not in MULTILANE_BACKENDS:
-        raise ValueError(f"backend={backend!r}, expected one of {MULTILANE_BACKENDS}")
-    require_tpu(backend)
-    fused_fp = backend in ("fused_fp", "fused_fp_interpret")
+    backend = _executor(backend)
+    fused_fp = backend in _FUSED_FP
     if fused_fp:
         if fp is None:
             raise ValueError(f"backend={backend!r} needs fp=FusedFPInputs")
@@ -380,7 +421,7 @@ def multilane_na_sharded(
     lane_axes: tuple[str, ...] = ("lane",),
     edge_bias: jnp.ndarray | None = None,  # [G, H]
     leaky_slope: float = 0.2,
-    backend: str = "reference",
+    backend: str | NABackend = "reference",
     fp: FusedFPInputs | None = None,
 ) -> jnp.ndarray:
     """``multilane_na`` with the lane dimension dispatched over mesh chips.
@@ -398,7 +439,8 @@ def multilane_na_sharded(
     """
     n_shards = math.prod(mesh.shape[a] for a in lane_axes)
     assert plan.num_lanes % n_shards == 0, (plan.num_lanes, n_shards)
-    fused_fp = backend in ("fused_fp", "fused_fp_interpret")
+    backend = _executor(backend)
+    fused_fp = backend in _FUSED_FP
     if fused_fp:
         if fp is None:
             raise ValueError(f"backend={backend!r} needs fp=FusedFPInputs")

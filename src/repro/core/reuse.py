@@ -11,8 +11,8 @@ by the previous graph (reuse) or must be re-fetched from HBM (miss).
 
 ``fp_buffer_traffic`` simulates exactly that: an FP-Buf of given capacity
 holding per-type projected feature tables, consumed in a given execution
-order.  It returns reused vs re-fetched bytes, which benchmarks/similarity.py
-sweeps across (total-features / FP-Buf) ratios to reproduce Fig. 15.
+order.  It returns reused vs re-fetched bytes; the serving engine replays
+its executed steps through it (``HGNNEngine.fp_model_drift``).
 """
 from __future__ import annotations
 

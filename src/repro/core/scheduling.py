@@ -149,6 +149,17 @@ class LanePlan:
         return float(self.lane_load.max() / max(mean, 1e-9))
 
 
+def _units(row_costs: Sequence[np.ndarray]) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Every (graph, row) work unit, graph-major: its graph, row and cost."""
+    counts = [len(rc) for rc in row_costs]
+    unit_graph = np.repeat(np.arange(len(counts), dtype=np.int32), counts)
+    unit_row = np.concatenate(
+        [np.zeros(0, np.int32)] + [np.arange(c, dtype=np.int32) for c in counts]
+    )
+    unit_cost = np.concatenate([np.zeros(0)] + [np.asarray(rc, float) for rc in row_costs])
+    return unit_graph, unit_row, unit_cost
+
+
 def lane_assignment(
     row_costs: Sequence[np.ndarray],
     num_lanes: int,
@@ -163,50 +174,38 @@ def lane_assignment(
     list (OW) and are then greedily placed on the least-loaded lanes
     (largest first).  Threshold defaults to ceil(total/num_lanes).
     """
-    units_g, units_r, units_c = [], [], []
-    for g, rc in enumerate(row_costs):
-        for r, c in enumerate(np.asarray(rc)):
-            units_g.append(g)
-            units_r.append(r)
-            units_c.append(float(c))
-    unit_graph = np.asarray(units_g, np.int32)
-    unit_row = np.asarray(units_r, np.int32)
-    unit_cost = np.asarray(units_c)
+    unit_graph, unit_row, unit_cost = _units(row_costs)
     total = unit_cost.sum()
     if threshold is None:
         threshold = float(np.ceil(total / max(num_lanes, 1)))
 
-    lane_load = np.zeros(num_lanes)
-    unit_lane = np.full(unit_graph.shape[0], -1, np.int32)
+    # host floats: the same float64 sums as numpy, without its per-scalar cost
+    costs = unit_cost.tolist()
+    lane_load = [0.0] * num_lanes
+    unit_lane = [-1] * len(costs)
     overflow: list[int] = []
     # phase 1: home-lane assignment up to threshold
-    for u in range(unit_graph.shape[0]):
-        home = int(unit_graph[u]) % num_lanes
-        if lane_load[home] + unit_cost[u] <= threshold:
+    for u, home in enumerate((unit_graph % num_lanes).tolist()):
+        if lane_load[home] + costs[u] <= threshold:
             unit_lane[u] = home
-            lane_load[home] += unit_cost[u]
+            lane_load[home] += costs[u]
         else:
             overflow.append(u)
-    # phase 2: overflow to least-loaded lanes, largest units first (LPT)
-    for u in sorted(overflow, key=lambda i: -unit_cost[i]):
-        l = int(np.argmin(lane_load))
+    # phase 2: overflow to least-loaded lanes (the first of equals), largest
+    # units first (LPT)
+    for u in sorted(overflow, key=lambda i: -costs[i]):
+        l = min(range(num_lanes), key=lane_load.__getitem__)
         unit_lane[u] = l
-        lane_load[l] += unit_cost[u]
-    return LanePlan(unit_graph, unit_row, unit_cost, unit_lane, lane_load)
+        lane_load[l] += costs[u]
+    return LanePlan(
+        unit_graph, unit_row, unit_cost, np.asarray(unit_lane, np.int32), np.asarray(lane_load)
+    )
 
 
 def naive_lane_assignment(row_costs: Sequence[np.ndarray], num_lanes: int) -> LanePlan:
     """Baseline without workload-aware scheduling: graph g entirely on lane
     g % num_lanes (the paper's 'w/o' ablation)."""
-    units_g, units_r, units_c = [], [], []
-    for g, rc in enumerate(row_costs):
-        for r, c in enumerate(np.asarray(rc)):
-            units_g.append(g)
-            units_r.append(r)
-            units_c.append(float(c))
-    unit_graph = np.asarray(units_g, np.int32)
-    unit_row = np.asarray(units_r, np.int32)
-    unit_cost = np.asarray(units_c)
+    unit_graph, unit_row, unit_cost = _units(row_costs)
     unit_lane = (unit_graph % num_lanes).astype(np.int32)
     lane_load = np.zeros(num_lanes)
     np.add.at(lane_load, unit_lane, unit_cost)

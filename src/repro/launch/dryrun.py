@@ -305,7 +305,6 @@ def lower_cell(
             compute_s=hlo_flops_dev / PEAK_FLOPS,
             # memory term: loop-corrected HLO byte traffic is not separable
             # from cost_analysis; use bytes_accessed (static) as the floor
-            # and the analytic traffic model in benchmarks/roofline.py
             memory_s_floor=bytes_dev / HBM_BW,
             collective_s=coll_dev / ICI_BW,
             model_flops_utilization=mf / max(hlo_flops_dev * chips, 1.0),

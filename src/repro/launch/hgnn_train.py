@@ -24,9 +24,9 @@ its three layers (two hidden, one output) one typed multigraph launch,
 forward and backward (``models/hgnn/shgn.shgn_forward_plan``), on one
 lane of one chip; ``--hidden 64 --heads 8 --lr 5e-4 --weight-decay 1e-4``
 are its published settings.  R-GAT trains through its per-relation
-forward with the same fused multigraph kernel per relation (its
-relation-specific projections keep it off the consolidated one-launch
-plan).  ``--backend kernel`` compiles the Pallas kernels for the TPU and
+forward, the same multigraph kernel over a one-lane plan per relation
+(its relation-specific projections keep it off the consolidated
+one-launch plan).  ``--backend kernel`` compiles the Pallas kernels for the TPU and
 refuses to run without one; on a CPU host ask for ``kernel_interpret``,
 which runs the same kernel body under the Pallas interpreter;
 ``reference`` runs HAN's multilane oracle, S-HGN's plain edge-list
@@ -188,11 +188,9 @@ def run_training(
     else:
         # per-relation projections -> per-relation fused kernel launches
         plan = None
-        nab = {"kernel": NABackend.MULTIGRAPH,
-               "kernel_interpret": NABackend.MULTIGRAPH_INTERPRET,
-               "reference": NABackend.BLOCK}[backend]
+        nab = NABackend.BLOCK if backend == "reference" else backend
         forward_fn = lambda p: model.forward(p, data, backend=nab)
-        meta_backend = nab.value
+        meta_backend = getattr(nab, "value", nab)
 
     opt = AdamWConfig(lr=lr, weight_decay=weight_decay)
     pipeline = SyntheticHGNNData(

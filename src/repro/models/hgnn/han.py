@@ -14,16 +14,13 @@ import jax
 import jax.numpy as jnp
 
 from ...core import stages
-from ...core.fusion import (
-    _FUSED_FP_BACKENDS,
-    _MULTIGRAPH_BACKENDS,
-    FusedFPInputs,
-    NABackend,
-    _pad_rows,
-    neighbor_aggregate,
-    neighbor_aggregate_multi,
+from ...core.fusion import FusedFPInputs, NABackend, _pad_rows, neighbor_aggregate
+from ...core.multilane import (
+    MultiLanePlan,
+    build_multilane_plan,
+    multilane_na,
+    multilane_na_sharded,
 )
-from ...core.multilane import MultiLanePlan, multilane_na, multilane_na_sharded
 from ...dist.sharding import shard
 from .common import HGNNData, HGNNModel, glorot, split_keys
 
@@ -85,38 +82,29 @@ def _han_embed(params, data: HGNNData, backend: NABackend):
 
     Stages carry ``jax.named_scope`` names (``fp``, ``theta``, ``na``,
     ``fusion``); the backward pass inherits them, so a profile can put
-    each device op down to its stage.
+    each device op down to its stage.  A kernel backend runs the layer
+    over a one-lane plan (``_han_embed_multilane``).
     """
     x = data.features[data.target_type]
     n = x.shape[0]
 
-    if backend in _FUSED_FP_BACKENDS:
+    if backend not in (NABackend.SEGMENT, NABackend.BLOCK):
+        plan = build_multilane_plan(data.graphs, 1)
+        if backend not in (NABackend.FUSED_FP, NABackend.FUSED_FP_INTERPRET):
+            return _han_embed_multilane(params, data, plan, backend=backend)
         # Megakernel path (DESIGN.md §10): FP happens INSIDE the NA launch
         # — raw x streams through the fused kernel, h' never materializes
         # in HBM.  One forward (and, training, one backward) launch for
         # the whole layer.
         with jax.named_scope("na"):
             fp = FusedFPInputs.shared(
-                x, params["w_fp"], params["b_fp"], params["a_src"], params["a_dst"]
+                _pad_rows(x, plan.n_dst_blocks * plan.block), params["w_fp"],
+                params["b_fp"], params["a_src"], params["a_dst"],
             )
-            z_all = neighbor_aggregate_multi(
-                data.graphs, None, None, None, backend=backend, fp=fp
-            )  # [G, N, H, Dh]
+            z_all = multilane_na(plan, None, None, None, backend=backend, fp=fp)[:, :n]
         return _fuse(params, z_all, n)
 
     hh = _project(params, x)
-    if backend in _MULTIGRAPH_BACKENDS:
-        # Consolidated path: all relations' theta in one einsum, all
-        # relations' NA in ONE fused multigraph launch (fwd and bwd).
-        with jax.named_scope("theta"):
-            th_s = jnp.einsum("nhd,ghd->gnh", hh, params["a_src"])
-            th_d = jnp.einsum("nhd,ghd->gnh", hh, params["a_dst"])
-        with jax.named_scope("na"):
-            z_all = neighbor_aggregate_multi(
-                data.graphs, th_s, th_d, hh, backend=backend
-            )  # [G, N, H, Dh]
-        return _fuse(params, z_all, n)
-
     z_list = []
     for i, batch in enumerate(data.graphs):
         with jax.named_scope("theta"):
@@ -144,13 +132,12 @@ def _han_embed_multilane(
     *,
     mesh=None,
     lane_axes: tuple[str, ...] = ("lane",),
-    backend: str = "reference",
+    backend: str | NABackend = "reference",
 ):
     """The consolidated HAN layer over a lane-partitioned work-unit plan.
 
-    Same semantics as the MULTIGRAPH path of ``_han_embed`` — one theta
-    einsum for all relations, all NA units in one fused dispatch — but the
-    units execute through ``core.multilane``: vmapped lanes on one chip
+    One theta einsum for all relations, all NA units in one fused
+    dispatch, executed through ``core.multilane``: vmapped lanes on one chip
     (``mesh=None``) or ``shard_map``ped over the mesh's lane axis (paper
     §4.2.1).  ``backend="kernel"`` runs one fused multigraph Pallas launch
     per lane shard, forward AND backward (custom VJP) — the training path

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core import stages
-from ...core.fusion import NABackend, neighbor_aggregate
+from ...core.fusion import NABackend, _pad_rows, neighbor_aggregate
+from ...core.multilane import build_multilane_plan, multilane_na
 from ...dist.sharding import shard
 from .common import HGNNData, HGNNModel, glorot, split_keys
 
@@ -51,7 +52,21 @@ def init_rgat(
     }
 
 
-def rgat_forward(params, data: HGNNData, *, backend: NABackend = NABackend.SEGMENT):
+def _relation_na(batch, th_s, th_d, hs, backend: NABackend | str):
+    """One relation's NA: SEGMENT and BLOCK per graph, a kernel over the
+    relation's one-lane plan.  Source and destination types may differ,
+    so each side is padded to its own block rows."""
+    if backend in (NABackend.SEGMENT, NABackend.BLOCK):
+        return neighbor_aggregate(batch, th_s, th_d, hs, backend=backend)
+    ns_pad = -(-batch.num_src // batch.block) * batch.block
+    z = multilane_na(
+        build_multilane_plan([batch], 1), _pad_rows(th_s, ns_pad)[None],
+        _pad_rows(th_d, batch.num_dst_pad)[None], _pad_rows(hs, ns_pad), backend=backend,
+    )
+    return z[0, : batch.num_dst]
+
+
+def rgat_forward(params, data: HGNNData, *, backend: NABackend | str = NABackend.SEGMENT):
     h = dict(data.features)
     heads = params["layers"][0]["rel"]["g0"]["a_src"].shape[0]
     for lp in params["layers"]:
@@ -65,7 +80,7 @@ def rgat_forward(params, data: HGNNData, *, backend: NABackend = NABackend.SEGME
             hd = hd.reshape(batch.num_dst, heads, -1)
             th_s, _ = stages.attention_coefficients(hs, rp["a_src"], rp["a_dst"])
             _, th_d = stages.attention_coefficients(hd, rp["a_src"], rp["a_dst"])
-            z = neighbor_aggregate(batch, th_s, th_d, hs, backend=backend)
+            z = _relation_na(batch, th_s, th_d, hs, backend)
             agg.setdefault(batch.dst_type, []).append(z.reshape(batch.num_dst, -1))
         h_new = {}
         for t in h:

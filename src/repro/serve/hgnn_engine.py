@@ -14,10 +14,11 @@ graph per occupied slot:
    ``core/reuse.py:fp_buffer_traffic``'s working-set accounting, measured
    instead of modeled.
 2. **NA** — attention coefficients from the target-type table, then ONE
-   fused multigraph launch for all slots' semantic graphs
-   (``fusion.neighbor_aggregate_multi``, ``backend=MULTIGRAPH`` on TPU /
-   ``MULTIGRAPH_INTERPRET`` on CPU; the non-multigraph backends fall back
-   to a per-graph loop with identical semantics).
+   fused multigraph launch for all slots' semantic graphs over the
+   step's one-lane plan (``multilane.build_multilane_plan`` and
+   ``multilane_na``, ``backend=MULTIGRAPH`` on TPU /
+   ``MULTIGRAPH_INTERPRET`` on CPU; SEGMENT and BLOCK run a per-graph
+   loop with identical semantics, ``fusion.neighbor_aggregate_multi``).
 3. **LSF/GSF** — per-graph semantic importances accumulate on the slot;
    when a request's last metapath completes, global semantic fusion
    produces its embedding and the slot is freed for the queue.
@@ -32,6 +33,7 @@ blocks.  ``admission="fifo"`` is the ablation baseline.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import time
 from collections import Counter
@@ -43,16 +45,14 @@ import numpy as np
 
 from ..core import stages
 from ..core.fusion import (
-    _FUSED_FP_BACKENDS,
-    _FUSED_TO_MULTIGRAPH,
-    _MULTIGRAPH_BACKENDS,
     FusedFPInputs,
     NABackend,
     SemanticGraphBatch,
+    _pad_rows,
     batch_semantic_graph,
-    build_unit_tables,
     neighbor_aggregate_multi,
 )
+from ..core.multilane import MultiLanePlan, build_multilane_plan, multilane_na
 from ..core.reuse import FPTraffic, fp_buffer_traffic
 from ..core.scheduling import shortest_hamilton_path, similarity_matrix
 from ..graphs.hetgraph import HetGraph
@@ -102,6 +102,21 @@ class GraphRequest:
     @property
     def done(self) -> bool:
         return self._progress >= len(self.metapaths)
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "n"))
+def _plan_na(plan: MultiLanePlan, th_s, th_d, hh, fp: FusedFPInputs | None, *,
+             backend: NABackend, n: int) -> jnp.ndarray:
+    """A step's kernel NA over its one-lane plan as one program: the
+    operands padded to the plan's rows (the shared src/dst space), the
+    launch and the scatter by unit.  Returns [G_active, n, H, Dh]."""
+    n_pad = plan.n_dst_blocks * plan.block
+    if fp is not None:
+        fp = dataclasses.replace(fp, x=_pad_rows(fp.x, n_pad))
+    else:
+        th_s, th_d = (_pad_rows(t.swapaxes(0, 1), n_pad).swapaxes(0, 1) for t in (th_s, th_d))
+        hh = _pad_rows(hh, n_pad)
+    return multilane_na(plan, th_s, th_d, hh, backend=backend, fp=fp)[:, :n]
 
 
 def _stable_seed(name: str) -> int:
@@ -325,12 +340,10 @@ class HGNNEngine:
             )
         return len(active)
 
-    def _unit_tables(self, backend: NABackend, batches: list[SemanticGraphBatch]):
-        """The step's work-unit tables, for the backends that take them."""
-        if backend not in _MULTIGRAPH_BACKENDS + _FUSED_FP_BACKENDS:
-            return None
+    def _plan(self, batches: list[SemanticGraphBatch]) -> MultiLanePlan:
+        """The step's one-lane plan: the kernels' work-unit tables."""
         with trace_span("serve.unit_tables"):
-            return build_unit_tables(batches)
+            return build_multilane_plan(batches, 1)
 
     def _step_body(self, active: list[tuple[int, GraphRequest]]) -> None:
         # Bound-aware dispatch for the fused-FP backend: if the cache
@@ -339,13 +352,14 @@ class HGNNEngine:
         # hit.  On a miss, the megakernel projects raw features on-chip
         # and h' never round-trips through HBM (nothing is admitted).
         backend = self.backend
-        fused = backend in _FUSED_FP_BACKENDS
+        fused = backend in (NABackend.FUSED_FP, NABackend.FUSED_FP_INTERPRET)
         if fused and self.cache.table_coverage(self.target_type, self.n_target) >= 1.0:
-            backend = _FUSED_TO_MULTIGRAPH[backend]
+            backend = NABackend(backend.value.replace("fused_fp", "multigraph"))
             fused = False
             self.fused_cache_bypasses += 1
             self.registry.counter("serve.fused_cache_bypasses").inc()
 
+        n = self.n_target
         if fused:
             self._fp_tables(active, skip={self.target_type})
             batches, a_s, a_d = [], [], []
@@ -362,17 +376,14 @@ class HGNNEngine:
                 jnp.stack(a_s),
                 jnp.stack(a_d),
             )
-            unit_tables = self._unit_tables(backend, batches)
+            plan = self._plan(batches)
             with trace_span("serve.na"):
-                z_all = neighbor_aggregate_multi(
-                    batches, None, None, None, backend=backend,
-                    unit_tables=unit_tables, fp=fp,
-                )  # [G_active, N, H, Dh]
+                z_all = _plan_na(plan, None, None, None, fp, backend=backend, n=n)
             self.fused_steps += 1
             self.registry.counter("serve.fused_steps").inc()
         else:
             tables = self._fp_tables(active)
-            hh = tables[self.target_type].reshape(self.n_target, self.heads, self.hidden)
+            hh = tables[self.target_type].reshape(n, self.heads, self.hidden)
 
             batches, th_s, th_d = [], [], []
             with trace_span("serve.theta"):
@@ -383,12 +394,17 @@ class HGNNEngine:
                     batches.append(self._batch(mp))
                     th_s.append(ts)
                     th_d.append(td)
-            unit_tables = self._unit_tables(backend, batches)
-            with trace_span("serve.na"):
-                z_all = neighbor_aggregate_multi(
-                    batches, jnp.stack(th_s), jnp.stack(th_d), hh, backend=backend,
-                    unit_tables=unit_tables,
-                )  # [G_active, N, H, Dh]
+            if backend in (NABackend.SEGMENT, NABackend.BLOCK):
+                with trace_span("serve.na"):
+                    z_all = neighbor_aggregate_multi(
+                        batches, jnp.stack(th_s), jnp.stack(th_d), hh, backend=backend
+                    )  # [G_active, N, H, Dh]
+            else:
+                plan = self._plan(batches)
+                with trace_span("serve.na"):
+                    z_all = _plan_na(
+                        plan, jnp.stack(th_s), jnp.stack(th_d), hh, None, backend=backend, n=n
+                    )  # [G_active, N, H, Dh]
         self.na_launches += 1
         self.registry.counter("serve.na_launches").inc()
 
@@ -515,7 +531,7 @@ def make_request_mix(
     *,
     interleave: bool = True,
 ) -> list[GraphRequest]:
-    """Request mix builder used by benchmarks/tests: ``repeats`` requests
+    """Request mix builder for the launcher and the tests: ``repeats`` requests
     per metapath cluster, interleaved round-robin (the adversarial arrival
     order for FIFO admission) or grouped."""
     reqs: list[GraphRequest] = []

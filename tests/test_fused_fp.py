@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import NABackend, batch_semantic_graph
-from repro.core.fusion import FusedFPInputs, build_unit_tables, neighbor_aggregate_multi
+from repro.core.fusion import FusedFPInputs, _pad_rows
 from repro.core.multilane import build_multilane_plan, multilane_na, multilane_na_sharded
 from repro.graphs import (
     build_semantic_graphs,
@@ -122,14 +122,14 @@ def test_neighbor_aggregate_multi_fused_fp_matches_multigraph(acm):
     b = jnp.asarray(rng.standard_normal((heads * dh,)).astype(np.float32))
     a_s = jnp.asarray(rng.standard_normal((gn, heads, dh)).astype(np.float32))
     a_d = jnp.asarray(rng.standard_normal((gn, heads, dh)).astype(np.float32))
-    fp = FusedFPInputs.shared(x, w, b, a_s, a_d)
-    z_f = neighbor_aggregate_multi(
-        data.graphs, None, None, None, backend=NABackend.FUSED_FP_INTERPRET, fp=fp)
-    h = (x @ w + b).reshape(x.shape[0], heads, dh)
+    plan = build_multilane_plan(data.graphs, 1)
+    n_pad = plan.n_dst_blocks * plan.block
+    fp = FusedFPInputs.shared(_pad_rows(x, n_pad), w, b, a_s, a_d)
+    z_f = multilane_na(plan, None, None, None, backend=NABackend.FUSED_FP_INTERPRET, fp=fp)
+    h = _pad_rows((x @ w + b).reshape(x.shape[0], heads, dh), n_pad)
     th_s = jnp.einsum("nhd,ghd->gnh", h, a_s)
     th_d = jnp.einsum("nhd,ghd->gnh", h, a_d)
-    z_m = neighbor_aggregate_multi(
-        data.graphs, th_s, th_d, h, backend=NABackend.MULTIGRAPH_INTERPRET)
+    z_m = multilane_na(plan, th_s, th_d, h, backend=NABackend.MULTIGRAPH_INTERPRET)
     np.testing.assert_allclose(np.asarray(z_f), np.asarray(z_m), rtol=1e-4, atol=1e-6)
 
 
@@ -137,8 +137,9 @@ def test_neighbor_aggregate_multi_fused_fp_requires_fp(acm):
     g, target, ncls, labels, mp = acm
     data = prepare_data(g, mp, target, ncls, labels, block=16)
     with pytest.raises(ValueError, match="fp"):
-        neighbor_aggregate_multi(
-            data.graphs, None, None, None, backend=NABackend.FUSED_FP_INTERPRET)
+        multilane_na(
+            build_multilane_plan(data.graphs, 1), None, None, None,
+            backend=NABackend.FUSED_FP_INTERPRET)
 
 
 @pytest.fixture(scope="module")

@@ -190,9 +190,10 @@ def test_seg_gat_agg_multigraph_bf16_matches_f32_oracle():
 
 
 def test_seg_gat_agg_multigraph_g1_reduces_to_seg_gat_agg():
-    """Single-graph NA (``neighbor_aggregate``) routes through the
-    multigraph kernel at G=1 and matches the dense single-graph oracle."""
-    from repro.core import NABackend, SemanticGraphBatch, neighbor_aggregate
+    """Single-graph NA (a one-lane plan of one graph) runs the multigraph
+    kernel at G=1 and matches the dense single-graph oracle."""
+    from repro.core import NABackend, SemanticGraphBatch
+    from repro.core.multilane import build_multilane_plan, multilane_na
 
     rng = np.random.default_rng(5)
     B, R, W, H, Dh, nblk = 8, 3, 2, 2, 8, 4
@@ -207,10 +208,12 @@ def test_seg_gat_agg_multigraph_g1_reduces_to_seg_gat_agg():
         name="g", src_type="t", dst_type="t", num_src=ns, num_dst=R * B,
         num_edges=int(masks.sum()), path_types=("t", "t"),
         col_index=jnp.asarray(col), masks=jnp.asarray(masks), block=B,
+        row_edges=tuple(int(m.sum()) for m in masks),
     )
-    single = neighbor_aggregate(
-        batch, ths, thd, hs, backend=NABackend.MULTIGRAPH_INTERPRET, edge_bias=bias
-    )
+    single = multilane_na(
+        build_multilane_plan([batch], 1), ths[None], thd[None], hs,
+        backend=NABackend.MULTIGRAPH_INTERPRET, edge_bias=bias[None],
+    )[0]
     ref = ref_seg_gat_agg(jnp.asarray(col), jnp.asarray(masks), ths, thd, hs, edge_bias=bias)
     np.testing.assert_allclose(np.asarray(single), np.asarray(ref), **TOL[jnp.float32])
 

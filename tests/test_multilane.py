@@ -22,15 +22,23 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import NABackend, batch_semantic_graph, neighbor_aggregate
-from repro.core.fusion import build_unit_tables, neighbor_aggregate_multi
+from repro.core import (
+    NABackend,
+    batch_semantic_graph,
+    lane_assignment,
+    naive_lane_assignment,
+    neighbor_aggregate,
+)
 from repro.core.multilane import build_multilane_plan, multilane_na
-from repro.graphs import build_semantic_graphs, dataset_metapaths, synthetic_hetgraph
+from repro.graphs import build_semantic_graphs, dataset_metapaths, synthetic_hetgraph, union_graph
 from repro.graphs.hetgraph import SemanticGraph
 from repro.launch.hgnn_train import build_problem, run_training
 from repro.launch.mesh import make_lane_mesh
 from repro.models.hgnn import han_forward_multilane
+from repro.models.hgnn.common import HGNNData
 from repro.models.hgnn.han import han_forward, init_han
+from repro.serve import GraphRequest, HGNNEngine
+from repro.serve import hgnn_engine
 
 
 @pytest.fixture(scope="module")
@@ -257,9 +265,120 @@ def test_plan_counts_live_slots_per_lane(dblp_setup, lanes):
     }
 
 
+def _per_row_plan(batches, num_lanes, balanced):
+    """The plan by the per-row construction: row costs reduced from the
+    device masks, and each unit's row sliced from the device on its own."""
+    row_costs = [np.asarray((b.masks != 0).sum(axis=(1, 2, 3)), np.int64) for b in batches]
+    lp = (lane_assignment(row_costs, num_lanes) if balanced
+          else naive_lane_assignment(row_costs, num_lanes))
+    lanes_units = [[] for _ in range(num_lanes)]
+    for u in range(lp.unit_graph.shape[0]):
+        lanes_units[int(lp.unit_lane[u])].append(u)
+    u_max = max(1, max(len(lu) for lu in lanes_units))
+    w_max = max(int(b.col_index.shape[1]) for b in batches)
+    blk = batches[0].block
+    col = np.full((num_lanes, u_max, w_max), -1, np.int32)
+    masks = np.zeros((num_lanes, u_max, w_max, blk, blk), batches[0].masks.dtype)
+    gid = np.zeros((num_lanes, u_max), np.int32)
+    drow = np.zeros((num_lanes, u_max), np.int32)
+    valid = np.zeros((num_lanes, u_max), bool)
+    for l, lu in enumerate(lanes_units):
+        for j, u in enumerate(lu):
+            g, r = int(lp.unit_graph[u]), int(lp.unit_row[u])
+            wg = int(batches[g].col_index.shape[1])
+            col[l, j, :wg] = np.asarray(batches[g].col_index[r])
+            masks[l, j, :wg] = np.asarray(batches[g].masks[r])
+            gid[l, j], drow[l, j], valid[l, j] = g, r, True
+    arrays = dict(col_index=col, masks=masks, graph_id=gid, dst_row=drow, valid=valid)
+    slots = {"grid": u_max * w_max, "live": (col >= 0).sum(axis=(1, 2)).tolist(),
+             "src_runs": [np.unique(c[c >= 0]).size for c in col]}
+    return arrays, slots, lp
+
+
+@pytest.fixture(scope="module")
+def shgn_union():
+    g = synthetic_hetgraph("acm", scale=0.05, feat_scale=0.1)
+    return [batch_semantic_graph(union_graph(g), block=16)]
+
+
+@pytest.mark.parametrize(
+    "view, lanes, balanced",
+    [("han", 1, True), ("han", 2, True), ("han", 4, True), ("han", 4, False),
+     ("shgn", 1, True)],
+    ids=["han-1", "han-2", "han-4", "han-4-naive", "shgn-1"],
+)
+def test_plan_matches_per_row_construction(dblp_setup, shgn_union, view, lanes, balanced):
+    """build_multilane_plan (one host copy per graph, row costs counted on
+    the host when the batch was built) gives the per-row construction's
+    arrays, NA slot counts and lane plan, bit for bit and dtype for dtype:
+    HAN's bool masks at 1, 2 and 4 lanes, balanced and naive, and S-HGN's
+    int8 type tiles at one lane."""
+    batches = dblp_setup[0] if view == "han" else shgn_union
+    plan = build_multilane_plan(batches, lanes, balanced=balanced)
+    arrays, slots, lp = _per_row_plan(batches, lanes, balanced)
+    assert arrays["masks"].dtype == (np.bool_ if view == "han" else np.int8)
+    for f, want in arrays.items():
+        got = np.asarray(getattr(plan, f))
+        assert got.dtype == want.dtype, f
+        np.testing.assert_array_equal(got, want, err_msg=f)
+    assert plan.na_slots() == slots
+    for f in ("unit_graph", "unit_row", "unit_cost", "unit_lane", "lane_load"):
+        a, b = getattr(plan.lane_plan, f), getattr(lp, f)
+        assert a.dtype == b.dtype, f
+        np.testing.assert_array_equal(a, b, err_msg=f)
+
+
+def test_engine_step_runs_one_lane_plan(monkeypatch):
+    """One serving step under MULTIGRAPH_INTERPRET runs its NA as
+    ``multilane_na`` over ``build_multilane_plan(batches, 1)`` of the
+    step's graphs in slot order: the engine's plan and NA output equal
+    that call's on the same operands, bit for bit."""
+    graph = synthetic_hetgraph("imdb", scale=0.05, feat_scale=0.02, seed=0)
+    eng = HGNNEngine(
+        graph, target_type="movie", hidden=4, heads=2, num_slots=2, admission="fifo",
+        backend=NABackend.MULTIGRAPH_INTERPRET, block=8, max_edges=2_000,
+    )
+    calls, plan_na = [], hgnn_engine._plan_na
+
+    def spy(plan, *args, **kw):
+        out = plan_na(plan, *args, **kw)
+        calls.append((plan, args, out))
+        return out
+
+    monkeypatch.setattr(hgnn_engine, "_plan_na", spy)
+    mps = [("movie", "director", "movie"), ("movie", "actor", "movie")]
+    for rid, mp in enumerate(mps):
+        eng.submit(GraphRequest(rid=rid, metapaths=[mp]))
+    assert eng.step() == 2
+    [(plan, (th_s, th_d, hh, fp), out)] = calls
+    assert fp is None
+    want_plan = build_multilane_plan([eng._batch(mp) for mp in mps], 1)
+    for f in ("col_index", "masks", "graph_id", "dst_row", "valid"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(plan, f)), np.asarray(getattr(want_plan, f)), err_msg=f
+        )
+    n, n_pad = eng.n_target, want_plan.n_dst_blocks * want_plan.block
+    pad = lambda t: jnp.pad(t, [(0, 0), (0, n_pad - n)] + [(0, 0)] * (t.ndim - 2))
+    want = multilane_na(
+        want_plan, pad(th_s), pad(th_d), pad(hh[None])[0],
+        backend=NABackend.MULTIGRAPH_INTERPRET,
+    )[:, :n]
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
 def test_multilane_backend_rejects_unknown():
     with pytest.raises(ValueError, match="backend"):
         multilane_na(None, None, None, None, backend="nope")
+
+
+def _han_forward(batches, backend):
+    """HAN logits over ``batches``, all-ones features 4 wide."""
+    data = HGNNData(
+        features={"v": jnp.ones((batches[0].num_dst, 4))}, graphs=batches,
+        target_type="v", num_classes=2,
+    )
+    params = init_han(jax.random.key(0), data, hidden=4, heads=2, att_dim=8)
+    return han_forward(params, data, backend=backend)
 
 
 @pytest.mark.parametrize(
@@ -267,10 +386,8 @@ def test_multilane_backend_rejects_unknown():
     [
         (lambda s: multilane_na(build_multilane_plan(s[0], 1), *s[1:], backend="kernel"),
          "kernel_interpret"),
-        (lambda s: neighbor_aggregate_multi(s[0], *s[1:], backend=NABackend.MULTIGRAPH),
-         "multigraph_interpret"),
-        (lambda s: neighbor_aggregate_multi(s[0], None, None, None, backend=NABackend.FUSED_FP),
-         "fused_fp_interpret"),
+        (lambda s: _han_forward(s[0], NABackend.MULTIGRAPH), "multigraph_interpret"),
+        (lambda s: _han_forward(s[0], NABackend.FUSED_FP), "fused_fp_interpret"),
         (lambda s: run_training(steps=1, backend="kernel"), "kernel_interpret"),
     ],
     ids=["multilane", "multigraph", "fused_fp", "run_training"],
@@ -409,20 +526,29 @@ def _draw_batches(data_obj, *, with_degenerates: bool):
 @settings(max_examples=10, deadline=None)
 @given(st.data())
 def test_plan_builders_fuzz_invariants(data_obj):
-    """build_unit_tables / build_multilane_plan over random unit tables:
-    every (graph, dst-row) is exactly one work unit, edges are conserved
+    """build_multilane_plan over random unit tables: the host row counts
+    are the masks' edges, the one-lane plan holds every (graph, dst-row)
+    as exactly one work unit in graph-major order, edges are conserved
     through the block masks, and lane loads account for every edge."""
     batches, n = _draw_batches(data_obj, with_degenerates=True)
     lanes = data_obj.draw(st.integers(1, 4))
     G = len(batches)
     n_rows = int(batches[0].col_index.shape[0])
+    for b in batches:
+        np.testing.assert_array_equal(
+            b.row_edge_counts(), np.asarray(b.masks).sum(axis=(1, 2, 3))
+        )
     total_edges = sum(int(b.row_edge_counts().sum()) for b in batches)
 
-    col, gid, drow, masks = build_unit_tables(batches)
+    one = build_multilane_plan(batches, 1)
+    col, gid, drow, masks = (
+        np.asarray(a)[0] for a in (one.col_index, one.graph_id, one.dst_row, one.masks)
+    )
     assert col.shape[0] == G * n_rows == gid.shape[0] == drow.shape[0]
-    units = sorted(zip(np.asarray(gid).tolist(), np.asarray(drow).tolist()))
+    units = list(zip(gid.tolist(), drow.tolist()))
     assert units == [(g, r) for g in range(G) for r in range(n_rows)]
-    assert int(np.asarray(masks).sum()) == total_edges
+    assert np.asarray(one.valid).all()
+    assert int(masks.sum()) == total_edges
 
     plan = build_multilane_plan(batches, lanes)
     valid = np.asarray(plan.valid)

@@ -198,39 +198,3 @@ def test_step_never_replays_the_fp_model(monkeypatch):
     assert eng.registry.value("serve.fp_model_drift") == m["fp_model_drift"]
     eng.registry.snapshot()  # an export computes the gauges too
     assert len(calls) == 2
-
-
-# -- benchmark stats ---------------------------------------------------------
-
-
-def test_timeit_stats_shape_and_median():
-    from benchmarks.common import timeit, timeit_stats
-
-    calls = []
-
-    def fn():
-        calls.append(1)
-        return ()
-
-    p10, p50, p90, iters = timeit_stats(fn, warmup=1, iters=5)
-    assert iters == 5 and len(calls) == 6
-    assert 0.0 <= p10 <= p50 <= p90
-    assert timeit(fn, warmup=0, iters=3) >= 0.0
-
-
-def test_run_py_duplicate_registration_fails():
-    from benchmarks import run as bench_run
-
-    benches = bench_run._registry()
-    assert "multilane" in benches and len(benches) >= 11
-    # the registry guard itself
-    ns: dict = {}
-
-    def register(name, fn, benches=ns):
-        if name in benches:
-            raise SystemExit(f"duplicate benchmark registration: {name!r}")
-        benches[name] = fn
-
-    register("x", lambda r: None)
-    with pytest.raises(SystemExit, match="duplicate"):
-        register("x", lambda r: None)

@@ -52,6 +52,51 @@ def test_lane_assignment_balances(data):
     assert plan.lane_load.max() <= thresh + biggest + 1e-9
 
 
+def _loop_lane_assignment(row_costs, num_lanes, threshold=None):
+    """The lane assignment as a plain loop over numpy arrays: the
+    reference the host-float implementation must match bit for bit."""
+    units = [(g, r, float(c)) for g, rc in enumerate(row_costs) for r, c in enumerate(rc)]
+    unit_graph = np.asarray([u[0] for u in units], np.int32)
+    unit_cost = np.asarray([u[2] for u in units])
+    if threshold is None:
+        threshold = float(np.ceil(unit_cost.sum() / max(num_lanes, 1)))
+    lane_load = np.zeros(num_lanes)
+    unit_lane = np.full(len(units), -1, np.int32)
+    overflow = []
+    for u in range(len(units)):
+        home = int(unit_graph[u]) % num_lanes
+        if lane_load[home] + unit_cost[u] <= threshold:
+            unit_lane[u] = home
+            lane_load[home] += unit_cost[u]
+        else:
+            overflow.append(u)
+    for u in sorted(overflow, key=lambda i: -unit_cost[i]):
+        l = int(np.argmin(lane_load))
+        unit_lane[u] = l
+        lane_load[l] += unit_cost[u]
+    unit_row = np.asarray([u[1] for u in units], np.int32)
+    return unit_graph, unit_row, unit_cost, unit_lane, lane_load
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.data())
+def test_lane_assignment_matches_loop_reference(data):
+    """Every field of the LanePlan equals the plain loop's, bytes and
+    dtype, over empty graphs, ties and given thresholds."""
+    num_lanes = data.draw(st.integers(1, 8))
+    rng = np.random.default_rng(data.draw(st.integers(0, 9_999)))
+    row_costs = [
+        rng.integers(0, data.draw(st.sampled_from([2, 5, 1000])), size=rng.integers(0, 30))
+        for _ in range(data.draw(st.integers(0, 5)))
+    ]
+    threshold = data.draw(st.sampled_from([None, 0.0, 7.0, 500.0]))
+    plan = lane_assignment(row_costs, num_lanes, threshold=threshold)
+    fields = (plan.unit_graph, plan.unit_row, plan.unit_cost, plan.unit_lane, plan.lane_load)
+    for got, want in zip(fields, _loop_lane_assignment(row_costs, num_lanes, threshold)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+
+
 def test_similarity_matrix_paper_formula():
     g = synthetic_hetgraph("acm", scale=0.05, feat_scale=0.1)
     sgs = build_semantic_graphs(g, dataset_metapaths("acm"), max_edges=2000)
