@@ -39,7 +39,7 @@ def main():
     # 4. Workload-aware lane balancing (independency-aware parallelism)
     batches = [batch_semantic_graph(s, block=32) for s in sgs]
     plan = build_multilane_plan(batches, num_lanes=4)
-    print("lane loads (edges):", plan.lane_plan.lane_load.astype(int).tolist(),
+    print("lane loads (live slots):", plan.lane_plan.lane_load.astype(int).tolist(),
           f"imbalance={plan.lane_plan.imbalance():.2f}")
 
     # 5. Fused HAN forward + a few training steps

@@ -37,7 +37,6 @@ import enum
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..graphs.formats import to_block_csr, to_padded_edges
 from ..graphs.hetgraph import SemanticGraph
@@ -109,9 +108,6 @@ class SemanticGraphBatch:
     # typed graphs (the union view): each padded edge's type, and the names
     edge_type: jnp.ndarray | None = None
     edge_type_names: tuple[str, ...] = ()
-    # edges per dst-block row, counted on the host while the block CSR
-    # was built there (lane scheduling's row costs)
-    row_edges: tuple[int, ...] = ()
 
     @property
     def num_dst_pad(self) -> int:
@@ -119,17 +115,11 @@ class SemanticGraphBatch:
             return self.num_dst
         return int(self.col_index.shape[0]) * self.block
 
-    def row_edge_counts(self) -> np.ndarray:
-        """#edges per dst-block row (workload units for lane scheduling)."""
-        assert self.col_index is not None, "batch built without block CSR"
-        assert len(self.row_edges) == self.col_index.shape[0], "row edges not counted"
-        return np.asarray(self.row_edges, np.int64)
-
 
 _SGB_ARRAY_FIELDS = ("src", "dst", "valid", "col_index", "masks", "edge_type")
 _SGB_META_FIELDS = (
     "name", "src_type", "dst_type", "num_src", "num_dst", "num_edges", "path_types", "block",
-    "edge_type_names", "row_edges",
+    "edge_type_names",
 )
 
 
@@ -166,10 +156,7 @@ def batch_semantic_graph(
             kw.update(edge_type=jnp.asarray(pe.edge_type))
     if with_blocks:
         bc = to_block_csr(sg, block=block)
-        kw.update(
-            col_index=jnp.asarray(bc.col_index), masks=jnp.asarray(bc.masks), block=block,
-            row_edges=tuple(int(np.count_nonzero(m)) for m in bc.masks),
-        )
+        kw.update(col_index=jnp.asarray(bc.col_index), masks=jnp.asarray(bc.masks), block=block)
     return SemanticGraphBatch(
         name=sg.name,
         src_type=sg.src_type,

@@ -104,6 +104,11 @@ def build_multilane_plan(
     so the tables are the graphs' rows stacked.  Each graph's tables are
     copied to the host once.
 
+    A unit costs the scheduler its live block slots (``col_index >= 0``
+    along its row): the kernel pays per live tile, whatever its edge
+    count, and every lane runs the same padded grid, so a lane's time
+    grows with its live slots alone (DESIGN.md §5).
+
     Requires all graphs to share the dst vertex space (HAN's metapath
     graphs do).  Host-side; build once per layer, or per serving step.
     """
@@ -114,13 +119,6 @@ def build_multilane_plan(
         assert bb.col_index is not None, "batch built without block CSR"
         assert bb.block == b and int(bb.col_index.shape[0]) == n_rows
 
-    row_costs = [bb.row_edge_counts() for bb in batches]
-    plan = (
-        lane_assignment(row_costs, num_lanes, threshold=threshold)
-        if balanced
-        else naive_lane_assignment(row_costs, num_lanes)
-    )
-
     # the graph-major stack: unit u is row u % n_rows of graph u // n_rows
     g_n = len(batches)
     w_max = max(int(bb.col_index.shape[1]) for bb in batches)
@@ -130,6 +128,13 @@ def build_multilane_plan(
         wg = int(bb.col_index.shape[1])
         col[i, :, :wg] = np.asarray(bb.col_index)
         masks[i, :, :wg] = np.asarray(bb.masks)
+
+    row_costs = list((col >= 0).sum(axis=2))
+    plan = (
+        lane_assignment(row_costs, num_lanes, threshold=threshold)
+        if balanced
+        else naive_lane_assignment(row_costs, num_lanes)
+    )
     col = col.reshape(1, g_n * n_rows, w_max)
     masks = masks.reshape(1, g_n * n_rows, w_max, b, b)
     gid = plan.unit_graph[None]

@@ -8,12 +8,14 @@
    path (exact Held-Karp DP — #semantic graphs <= ~16 in practice, and the
    paper measures <0.1% preprocessing overhead on CPU).
 
-2. Workload-aware scheduling (paper §4.2.2): balance edge workloads across
+2. Workload-aware scheduling (paper §4.2.2): balance workloads across
    lanes.  Units of work are dst-block rows (each dst vertex lives in
    exactly one unit, so no cross-lane NA reduction is needed); rows whose
    lane would exceed the allocation threshold spill to the overflow list
    (OW) and are re-assigned to under-loaded lanes, exactly mirroring the
-   paper's Local Scheduler.
+   paper's Local Scheduler.  A unit's cost is whatever the caller's
+   datapath pays per row: the paper's pays per edge, the TPU plan
+   (core/multilane.py) per live block slot.
 """
 from __future__ import annotations
 
@@ -129,8 +131,9 @@ class LanePlan:
     """Static lane assignment of work units.
 
     unit_graph[u], unit_row[u]: which (semantic graph, dst-block row) unit u is.
+    unit_cost[u]: its cost, in the unit the caller's row costs are in.
     unit_lane[u]: the lane executing it.
-    lane_load[l]: total edges on lane l.
+    lane_load[l]: total cost on lane l, in that same unit.
     """
 
     unit_graph: np.ndarray
@@ -168,7 +171,9 @@ def lane_assignment(
 ) -> LanePlan:
     """Workload-aware scheduling over dst-block-row work units.
 
-    ``row_costs[g][r]`` = #edges of row r of semantic graph g.  Graph g's
+    ``row_costs[g][r]`` = the cost of row r of semantic graph g, in
+    whatever unit the lanes pay (edges for the paper's datapath, live
+    block slots for the TPU plan of ``build_multilane_plan``).  Graph g's
     rows start on lane ``g % num_lanes`` (the paper assigns W_i to Lane_i);
     rows that would push the lane past the threshold go to the overflow
     list (OW) and are then greedily placed on the least-loaded lanes

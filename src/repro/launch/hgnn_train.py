@@ -225,6 +225,7 @@ def run_training(
     if metrics_out:
         reg.export_json(metrics_out)
 
+    na_slots = None if plan is None else plan.na_slots()
     meta = dict(
         dataset=dataset, model=model_name, backend=str(meta_backend),
         lanes=lanes, model_split=model_split,
@@ -236,7 +237,12 @@ def run_training(
         # NA grid slots per lane, and how many are live (not padding); the
         # NA launches a step makes over the plan, forward (one per layer);
         # the edge types of a typed plan
-        na_slots=None if plan is None else plan.na_slots(),
+        na_slots=na_slots,
+        # max / mean live slots over the plan's lanes: how far the lane the
+        # psum waits for is above the mean NA work (1.0 on one lane)
+        lane_imbalance=None if plan is None else float(
+            max(na_slots["live"]) / max(np.mean(na_slots["live"]), 1e-9)
+        ),
         na_layers=None if plan is None else max(
             1, sum(k.endswith(".attn_src") for k in state.params)  # S-HGN: one a layer
         ),

@@ -42,6 +42,16 @@ def test_han_loss_decreases_lane_sharded_kernel():
     assert len(meta["na_slots"]["live"]) == 2
 
 
+def test_lane_imbalance_reads_live_slots_per_lane():
+    """run_training's meta reports ``lane_imbalance``: the largest lane's
+    live NA slots over the mean of the plan's lanes."""
+    _, _, meta = run_training(steps=1, lanes=2, backend="reference", **_KW)
+    live = meta["na_slots"]["live"]
+    assert len(live) == 2
+    assert meta["lane_imbalance"] == max(live) / np.mean(live)
+    assert meta["lane_imbalance"] >= 1.0
+
+
 def test_rgat_loss_decreases():
     state, history, meta = run_training(
         steps=8, lanes=1, backend="kernel_interpret", **{**_KW, "model_name": "R-GAT"},

@@ -208,7 +208,6 @@ def test_seg_gat_agg_multigraph_g1_reduces_to_seg_gat_agg():
         name="g", src_type="t", dst_type="t", num_src=ns, num_dst=R * B,
         num_edges=int(masks.sum()), path_types=("t", "t"),
         col_index=jnp.asarray(col), masks=jnp.asarray(masks), block=B,
-        row_edges=tuple(int(m.sum()) for m in masks),
     )
     single = multilane_na(
         build_multilane_plan([batch], 1), ths[None], thd[None], hs,

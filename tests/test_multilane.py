@@ -265,10 +265,22 @@ def test_plan_counts_live_slots_per_lane(dblp_setup, lanes):
     }
 
 
-def _per_row_plan(batches, num_lanes, balanced):
-    """The plan by the per-row construction: row costs reduced from the
-    device masks, and each unit's row sliced from the device on its own."""
-    row_costs = [np.asarray((b.masks != 0).sum(axis=(1, 2, 3)), np.int64) for b in batches]
+def _live_slot_costs(batches):
+    """Each row's live block slots, reduced from the device col_index."""
+    return [np.asarray((b.col_index >= 0).sum(axis=1), np.int64) for b in batches]
+
+
+def _edge_costs(batches):
+    """Each row's edges, reduced from the device masks (the lane cost of
+    the paper's datapath, which pays per edge)."""
+    return [np.asarray((b.masks != 0).sum(axis=(1, 2, 3)), np.int64) for b in batches]
+
+
+def _per_row_plan(batches, num_lanes, balanced, costs=_live_slot_costs):
+    """The plan by the per-row construction: row costs reduced on the
+    device by ``costs``, and each unit's row sliced from the device on its
+    own."""
+    row_costs = costs(batches)
     lp = (lane_assignment(row_costs, num_lanes) if balanced
           else naive_lane_assignment(row_costs, num_lanes))
     lanes_units = [[] for _ in range(num_lanes)]
@@ -301,6 +313,16 @@ def shgn_union():
     return [batch_semantic_graph(union_graph(g), block=16)]
 
 
+@pytest.fixture(scope="module")
+def acm_full():
+    """The metapath graphs of the han-acm benchmark configuration: ACM at
+    Table-5 size, B=128, PPSP capped at 400k edges, graph seed 0."""
+    _, data = build_problem(
+        "acm", scale=1.0, feat_scale=1.0, block=128, max_edges=400_000, seed=0
+    )
+    return data.graphs
+
+
 @pytest.mark.parametrize(
     "view, lanes, balanced",
     [("han", 1, True), ("han", 2, True), ("han", 4, True), ("han", 4, False),
@@ -308,8 +330,8 @@ def shgn_union():
     ids=["han-1", "han-2", "han-4", "han-4-naive", "shgn-1"],
 )
 def test_plan_matches_per_row_construction(dblp_setup, shgn_union, view, lanes, balanced):
-    """build_multilane_plan (one host copy per graph, row costs counted on
-    the host when the batch was built) gives the per-row construction's
+    """build_multilane_plan (one host copy per graph, each row costing its
+    live slots, counted from that copy) gives the per-row construction's
     arrays, NA slot counts and lane plan, bit for bit and dtype for dtype:
     HAN's bool masks at 1, 2 and 4 lanes, balanced and naive, and S-HGN's
     int8 type tiles at one lane."""
@@ -326,6 +348,39 @@ def test_plan_matches_per_row_construction(dblp_setup, shgn_union, view, lanes, 
         a, b = getattr(plan.lane_plan, f), getattr(lp, f)
         assert a.dtype == b.dtype, f
         np.testing.assert_array_equal(a, b, err_msg=f)
+
+
+@pytest.mark.parametrize("view", ["han", "shgn", "acm-full"])
+def test_one_lane_plan_does_not_depend_on_row_costs(dblp_setup, shgn_union, acm_full, view):
+    """At one lane every unit sits on lane 0 in graph-major order whatever
+    the row costs: the plan balanced on live slots holds the arrays, the
+    slot counts and the unit order of the plan balanced on edges, bit for
+    bit, so every one-lane program (single-chip training, serving) is
+    unchanged by the cost the scheduler balances."""
+    batches = {"han": dblp_setup[0], "shgn": shgn_union, "acm-full": acm_full}[view]
+    plan = build_multilane_plan(batches, 1)
+    arrays, slots, lp = _per_row_plan(batches, 1, True, costs=_edge_costs)
+    for f, want in arrays.items():
+        got = np.asarray(getattr(plan, f))
+        assert got.dtype == want.dtype, f
+        np.testing.assert_array_equal(got, want, err_msg=f)
+    assert plan.na_slots() == slots
+    for f in ("unit_graph", "unit_row", "unit_lane"):
+        np.testing.assert_array_equal(getattr(plan.lane_plan, f), getattr(lp, f), err_msg=f)
+
+
+def test_acm_four_lane_plan_levels_live_slots(acm_full):
+    """Full-size ACM on four lanes: ACM's rows cost 24 live slots in PAP
+    and PSP and 19 in PPAP and PPSP, so balancing edges left the lanes at
+    833 / 570 / 528 / 133 live slots of an 888-slot grid; balancing live
+    slots levels them to 528 / 528 / 504 / 504 of a 27 x 24 grid."""
+    plan = build_multilane_plan(acm_full, 4)
+    col = np.asarray(plan.col_index)
+    assert col.shape == (4, 27, 24)
+    assert plan.na_slots()["live"] == [528, 528, 504, 504]
+    np.testing.assert_array_equal(plan.lane_plan.lane_load, [528, 528, 504, 504])
+    _, slots, _ = _per_row_plan(acm_full, 4, True, costs=_edge_costs)
+    assert (slots["grid"], slots["live"]) == (888, [833, 570, 528, 133])
 
 
 def test_engine_step_runs_one_lane_plan(monkeypatch):
@@ -526,19 +581,16 @@ def _draw_batches(data_obj, *, with_degenerates: bool):
 @settings(max_examples=10, deadline=None)
 @given(st.data())
 def test_plan_builders_fuzz_invariants(data_obj):
-    """build_multilane_plan over random unit tables: the host row counts
-    are the masks' edges, the one-lane plan holds every (graph, dst-row)
-    as exactly one work unit in graph-major order, edges are conserved
-    through the block masks, and lane loads account for every edge."""
+    """build_multilane_plan over random unit tables: the one-lane plan
+    holds every (graph, dst-row) as exactly one work unit in graph-major
+    order, edges are conserved through the block masks, and lane loads
+    account for every live slot, lane by lane."""
     batches, n = _draw_batches(data_obj, with_degenerates=True)
     lanes = data_obj.draw(st.integers(1, 4))
     G = len(batches)
     n_rows = int(batches[0].col_index.shape[0])
-    for b in batches:
-        np.testing.assert_array_equal(
-            b.row_edge_counts(), np.asarray(b.masks).sum(axis=(1, 2, 3))
-        )
-    total_edges = sum(int(b.row_edge_counts().sum()) for b in batches)
+    total_edges = sum(int(np.asarray(b.masks).sum()) for b in batches)
+    total_live = sum(int((np.asarray(b.col_index) >= 0).sum()) for b in batches)
 
     one = build_multilane_plan(batches, 1)
     col, gid, drow, masks = (
@@ -563,7 +615,26 @@ def test_plan_builders_fuzz_invariants(data_obj):
         if v
     )
     assert plan_units == units  # disjoint + complete partition
-    assert int(np.asarray(masks).sum()) == int(plan.lane_plan.lane_load.sum())
+    assert int(np.asarray(plan.masks).sum()) == total_edges
+    live = plan.na_slots()["live"]
+    assert sum(live) == total_live == int(plan.lane_plan.lane_load.sum())
+    np.testing.assert_array_equal(plan.lane_plan.lane_load, live)
+
+
+@settings(max_examples=10, deadline=None)
+@given(st.data())
+def test_plan_lane_live_slots_within_threshold_bound(data_obj):
+    """The scheduler's bound, read from the built plan: no lane holds more
+    live slots than the home-lane threshold, ceil(total / L), plus the
+    largest unit's live slots (an overflow unit goes to the least-loaded
+    lane, which is at most the mean before it lands), for 1-4 lanes."""
+    batches, _ = _draw_batches(data_obj, with_degenerates=data_obj.draw(st.booleans()))
+    unit_live = np.concatenate([(np.asarray(b.col_index) >= 0).sum(axis=1) for b in batches])
+    total, biggest = int(unit_live.sum()), int(unit_live.max())
+    for lanes in range(1, 5):
+        live = build_multilane_plan(batches, lanes).na_slots()["live"]
+        assert sum(live) == total
+        assert max(live) <= -(-total // lanes) + biggest, (lanes, live)
 
 
 @settings(max_examples=6, deadline=None)
